@@ -217,7 +217,7 @@ register_preset(
         # (train/sparse_embed.py): gradients w.r.t. gathered rows and
         # scatter updates of touched rows ONLY — the dense [F, V, D]
         # cotangent and full-table optimizer sweep (the step's
-        # dominant HBM traffic, BASELINE.md roofline) never
+        # dominant HBM traffic) never
         # materialize. Numerically IDENTICAL trajectory to the dense
         # recsys-adamw it replaces (tests/test_sparse_embed.py pins
         # leaf-for-leaf equality), measured 8.9x step time on CPU at

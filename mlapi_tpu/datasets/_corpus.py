@@ -16,7 +16,11 @@ from __future__ import annotations
 from pathlib import Path
 
 # The corpus files, in repo layout. The frozen snapshot stores each at
-# the top level (flat); resolve_doc() tries both.
+# the top level (flat); resolve_doc() tries both. The snapshot's
+# manifest covers exactly these four names, so the list stays as it
+# is; the repo root no longer holds a ``BASELINE.md``, and
+# ``root="live"`` simply has no such class/file (both loaders skip an
+# absent file).
 DOC_SOURCES = (
     "README.md",
     "SURVEY.md",

@@ -8,7 +8,7 @@ incomparable to anything. This dataset exists to anchor those model
 families against real data anyway: 1,797 genuine 8x8 grayscale digit
 scans (UCI ML hand-written digits, shipped inside scikit-learn — zero
 network), same 10-class problem shape, run through the SAME linear /
-MLP models and train loop. Published in ``BASELINE.md``.
+MLP models and train loop.
 """
 
 from __future__ import annotations

@@ -13,8 +13,8 @@ in vocabulary and register), the data is 100% real, and the task shape
 is exactly SST-2's (short text → class id).
 
 The residual gap to real SST-2 — pretrained weights + the actual GLUE
-labels — is documented in BASELINE.md; the ``--from-hf`` train path
-closes it the moment a local HF checkpoint appears.
+labels — is what the ``--from-hf`` train path
+closes the moment a local HF checkpoint appears.
 """
 
 from __future__ import annotations

@@ -38,6 +38,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from mlapi_tpu.models import register_model
+from mlapi_tpu.utils.platform import pallas_interpret
 
 BERT_PRESETS = {
     # name: (vocab, hidden, layers, heads, intermediate, max_positions)
@@ -183,13 +184,13 @@ class BertClassifier:
                     seq_axis=self.seq_axis, head_axis="model",
                 )
             elif self.attention_impl == "flash":
-                from mlapi_tpu.ops.pallas import flash_attention
+                from mlapi_tpu.ops.pallas import flash_attention_on_mesh
 
-                # Interpreter off the TPU: correctness-testable
-                # anywhere, compiled Mosaic kernel on the real chip.
-                ctx = flash_attention(
-                    q, k, v, key_mask,
-                    interpret=jax.default_backend() != "tpu",
+                # Interpreter on the CPU backend only (tests); the
+                # compiled Mosaic kernel everywhere else.
+                ctx = flash_attention_on_mesh(
+                    self.mesh, q, k, v, key_mask,
+                    interpret=pallas_interpret(),
                 )
             else:
                 ctx = full_attention(q, k, v, key_mask)

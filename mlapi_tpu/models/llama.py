@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 
 from mlapi_tpu.models import register_model
+from mlapi_tpu.utils.platform import pallas_interpret
 
 
 def _rms_norm(x, scale, eps: float = 1e-6):
@@ -246,14 +247,14 @@ class LlamaLM:
         positions = jnp.broadcast_to(jnp.arange(l)[None], (b, l))
 
         if self.attention_impl == "flash":
-            from mlapi_tpu.ops.pallas import flash_attention
+            from mlapi_tpu.ops.pallas import flash_attention_on_mesh
 
             def attend(q, k, v):
                 # The kernel is GQA-native: raw kv heads go straight
                 # in, no repeated K/V tensor in HBM.
-                return flash_attention(
-                    q, k, v, causal=True,
-                    interpret=jax.default_backend() != "tpu",
+                return flash_attention_on_mesh(
+                    self.mesh, q, k, v, causal=True,
+                    interpret=pallas_interpret(),
                 )
         elif self.attention_impl == "ring":
             from mlapi_tpu.ops import ring_self_attention

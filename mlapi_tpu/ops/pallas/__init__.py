@@ -31,6 +31,7 @@ from mlapi_tpu.ops.pallas.decode_attention import (
 )
 from mlapi_tpu.ops.pallas.flash_attention import (
     flash_attention,
+    flash_attention_on_mesh,
     flash_attention_with_lse,
 )
 
@@ -44,5 +45,6 @@ __all__ = [
     "paged_extend_attention",
     "paged_extend_attention_tp",
     "flash_attention",
+    "flash_attention_on_mesh",
     "flash_attention_with_lse",
 ]

@@ -75,17 +75,22 @@ KV-width read) covers EVERY token the server processes, not just
 decode steps — the einsum extend path materialized a full-precision,
 query-head-width cache operand per chunk.
 
-``interpret=True`` runs the Pallas interpreter (CPU CI). In interpret
-mode the grid lowers to plain traced JAX, so the kernel composes with
-GSPMD-partitioned decode programs on virtual meshes — that is what
-the multichip dry run proves. The COMPILED kernel under a model-axis
-mesh is NOT yet hardware-validated: a compiled ``pallas_call`` is an
-opaque custom call to GSPMD, which may all-gather head-sharded cache
-operands around it instead of running the kernel per shard (negating
-the byte saving) — verifying that, and adding a ``shard_map`` wrapper
-if needed, is an open item for the next TPU window (ROADMAP).
-Single-chip TPU serving — where the bandwidth claim lives — needs no
-partitioning.
+``interpret=True`` runs the Pallas interpreter (CPU CI); who sets it
+is ``utils.platform.pallas_interpret``. The COMPILED kernels are
+lowered by Mosaic in tier-1 for a described v5e at GPT-2-small
+geometry (``tests/test_tpu_compile.py``) and checked against a
+float32 oracle on the chip (``tools/chip_kernels.py``). Under a
+model-axis mesh a compiled ``pallas_call`` is an opaque custom call
+to GSPMD, so the ``*_tp`` wrappers run it under ``shard_map`` on each
+shard's local heads — the compiled program holds the kernel per shard
+and no all-gather of the head-sharded cache (same test file).
+
+Layouts Mosaic dictated (each was refused in the obvious form): the
+key mask rides as one ``(U, block)`` tile per k-tile
+(:func:`_tile_mask`); int8 scales ride as ``(block, KVH)`` tiles
+(:func:`_squeeze_scale`); a KV head's ``[block, D]`` tile is read
+straight off the ref, never sliced out of a loaded
+``[block, KVH, D]`` value (:func:`_head_tiles`).
 """
 
 from __future__ import annotations
@@ -99,6 +104,46 @@ from jax.experimental import pallas as pl
 # Same finite large-negative as the sibling kernels (a kernel may not
 # capture traced constants; -inf breaks the masked-row algebra).
 _NEG = -1e30
+
+
+def _head_tiles(k_ref, ks_ref, v_ref, vs_ref, dtype):
+    """``j -> (k_j, v_j)``: one KV head's ``[block_k, D]`` tiles out of
+    the ``(1, block_k, KVH, D)`` blocks the BlockSpec copies brought
+    to VMEM. The int8 path dequantizes HERE, per head, in registers,
+    with ``kv_dequantize``'s exact arithmetic (convert to the compute
+    dtype, multiply by the per-(token, head) scale) — the
+    full-precision tile never exists in HBM. Scales arrive as
+    ``(1, block_k, KVH)`` tiles (see :func:`_squeeze_scale`); head
+    ``j``'s column broadcasts along the lanes of its ``[block_k, D]``
+    payload."""
+    # One head at a time straight off the refs: the whole
+    # ``[block_k, KVH, D]`` tile as a VALUE costs a padded copy
+    # (KVH=12 pads to the 16/32-row sublane tile, D=64 to 128 lanes).
+    if ks_ref is None:
+        return lambda j: (k_ref[0, :, j, :], v_ref[0, :, j, :])
+
+    def head(j):
+        return (
+            k_ref[0, :, j, :].astype(dtype)
+            * ks_ref[0, :, j:j + 1].astype(dtype),
+            v_ref[0, :, j, :].astype(dtype)
+            * vs_ref[0, :, j:j + 1].astype(dtype),
+        )
+
+    return head
+
+
+def _squeeze_scale(scale):
+    """``f32[..., KVH, 1]`` (the stored int8-cache scale format) ->
+    ``[..., KVH]``. A block with a minor dimension of 1 is padded to a
+    full 128-lane tile per (token, head): at ``block_k=512`` the four
+    double-buffered scale tiles alone asked Mosaic for 16 MB of VMEM
+    ("Scoped allocation with size 27.53M and limit 16.00M") and every
+    tile DMA moved ~10x the int8 payload it scales. With KVH minor a
+    scale tile is ``block_k x 128`` lanes. (The STORED format still
+    pads in HBM; changing it is a cache-format change, not a kernel
+    repair.)"""
+    return scale[..., 0]
 
 
 def _decode_kernel(
@@ -117,7 +162,7 @@ def _decode_kernel(
     else:
         k_ref, v_ref, mask_ref, acc_ref, m_ref, l_ref = refs
         ks_ref = vs_ref = None
-    keep = mask_ref[0, 0]  # [block_k]
+    keep = mask_ref[0, 0, 0]  # [block_k]
     # Split-K tile skipping: a tile with no valid key (every slot
     # beyond pos, or inside a pad hole spanning the tile) contributes
     # the identity triple; the dots are skipped.
@@ -132,17 +177,7 @@ def _decode_kernel(
     @pl.when(live)
     def _step():
         q = q_ref[0, 0]  # [H, D]
-        if quantized:
-            # The int8 tile path: payload + scales were DMA'd to VMEM
-            # by the BlockSpec copies; dequantize in registers with
-            # kv_dequantize's exact arithmetic (convert to the compute
-            # dtype, broadcast-multiply by the per-(token, head)
-            # scale) — the full-precision tile never exists in HBM.
-            k = k_ref[0].astype(q.dtype) * ks_ref[0].astype(q.dtype)
-            v = v_ref[0].astype(q.dtype) * vs_ref[0].astype(q.dtype)
-        else:
-            k = k_ref[0]  # [block_k, KVH, D]
-            v = v_ref[0]
+        head = _head_tiles(k_ref, ks_ref, v_ref, vs_ref, q.dtype)
         nkeep = (1.0 - keep) * _NEG  # [block_k]
 
         # Per-KV-head 2D dots (kv_heads/group are static: the loop
@@ -151,9 +186,10 @@ def _decode_kernel(
         # shared with every attention impl in ops/.
         for j in range(kv_heads):
             rows = slice(j * group, (j + 1) * group)
+            kj, vj = head(j)  # [block_k, D] each
             s = (
                 jax.lax.dot_general(
-                    q[rows], k[:, j, :],
+                    q[rows], kj,
                     dimension_numbers=(((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32,
                 )
@@ -166,7 +202,7 @@ def _decode_kernel(
             p = jnp.exp(s - m) * keep[None, :]
             l = jnp.sum(p, axis=-1, keepdims=True)
             acc = jax.lax.dot_general(
-                p.astype(v.dtype), v[:, j, :],
+                p.astype(vj.dtype), vj,
                 dimension_numbers=(((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )  # [group, D]
@@ -195,7 +231,7 @@ def _extend_kernel(
     else:
         k_ref, v_ref, mask_ref, acc_ref, m_ref, l_ref = refs
         ks_ref = vs_ref = None
-    keep = mask_ref[0]  # [U, block_k]
+    keep = mask_ref[0, 0]  # [U, block_k]
     # A tile dead for EVERY span position skips its dots (leading
     # tiles of a mostly-empty cache, pad holes spanning the tile).
     live = jnp.any(keep > 0)
@@ -209,12 +245,7 @@ def _extend_kernel(
     @pl.when(live)
     def _step():
         q = q_ref[0]  # [U, H, D]
-        if quantized:
-            k = k_ref[0].astype(q.dtype) * ks_ref[0].astype(q.dtype)
-            v = v_ref[0].astype(q.dtype) * vs_ref[0].astype(q.dtype)
-        else:
-            k = k_ref[0]  # [block_k, KVH, D]
-            v = v_ref[0]
+        head = _head_tiles(k_ref, ks_ref, v_ref, vs_ref, q.dtype)
         # Per-row mask penalties, repeated group-wise to match the
         # u-major [U * group] row layout of each KV head's dot.
         nkeep = jnp.repeat((1.0 - keep) * _NEG, group, axis=0)
@@ -224,9 +255,10 @@ def _extend_kernel(
             qj = q[:, j * group:(j + 1) * group, :].reshape(
                 u * group, -1
             )  # [U*group, D], row = u*group + g
+            kj, vj = head(j)  # [block_k, D] each
             s = (
                 jax.lax.dot_general(
-                    qj, k[:, j, :],
+                    qj, kj,
                     dimension_numbers=(((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32,
                 )
@@ -240,7 +272,7 @@ def _extend_kernel(
             p = jnp.exp(s - m) * keep_g
             l = jnp.sum(p, axis=-1, keepdims=True)
             acc = jax.lax.dot_general(
-                p.astype(v.dtype), v[:, j, :],
+                p.astype(vj.dtype), vj,
                 dimension_numbers=(((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )  # [U*group, D]
@@ -266,6 +298,20 @@ def _fit_block(requested: int, length: int) -> int:
     if b < 8 and b < length:
         return length
     return b
+
+
+def _tile_mask(mask, n_tiles: int, block: int):
+    """``[B, U, n_tiles * block]`` key mask -> f32 ``[B, n_tiles, U,
+    block]``, one k-tile's mask per leading index. Mosaic wants a
+    block's last two dims divisible by (8, 128) or EQUAL to the
+    array's: tiling the key axis out of the minor dimension makes the
+    ``(U, block)`` mask block equal to the array's last two dims at
+    any tile width — a 16-token page included — where a ``block``-wide
+    window of the ``[.., L]`` row is refused below 128 lanes. The
+    transpose moves ``B*U*L`` floats, noise next to the cache read."""
+    b, u, _ = mask.shape
+    tiled = mask.astype(jnp.float32).reshape(b, u, n_tiles, block)
+    return tiled.transpose(0, 2, 1, 3)
 
 
 def _unpack(x):
@@ -348,12 +394,12 @@ def decode_attention(
     bk = _fit_block(block_k, lk)
     nk = lk // bk
 
-    mask3 = mask.astype(jnp.float32)[:, None, :]  # [B, 1, L]
+    mask4 = _tile_mask(mask[:, None, :], nk, bk)  # [B, nk, 1, bk]
 
     q_spec = pl.BlockSpec((1, 1, h, d), lambda bi, ki: (bi, 0, 0, 0))
     kv_spec = pl.BlockSpec((1, bk, kvh, d), lambda bi, ki: (bi, ki, 0, 0))
-    sc_spec = pl.BlockSpec((1, bk, kvh, 1), lambda bi, ki: (bi, ki, 0, 0))
-    mask_spec = pl.BlockSpec((1, 1, bk), lambda bi, ki: (bi, 0, ki))
+    sc_spec = pl.BlockSpec((1, bk, kvh), lambda bi, ki: (bi, ki, 0))
+    mask_spec = pl.BlockSpec((1, 1, 1, bk), lambda bi, ki: (bi, ki, 0, 0))
     part_spec = pl.BlockSpec((1, 1, h, d), lambda bi, ki: (bi, ki, 0, 0))
     row_spec = pl.BlockSpec((1, 1, h, 1), lambda bi, ki: (bi, ki, 0, 0))
 
@@ -361,10 +407,12 @@ def decode_attention(
     # signature (and its BlockSpec copies) carries exactly what the
     # cache format stores.
     if quantized:
-        operands = (q, kq, ks, vq, vs, mask3)
+        operands = (
+            q, kq, _squeeze_scale(ks), vq, _squeeze_scale(vs), mask4
+        )
         in_specs = [q_spec, kv_spec, sc_spec, kv_spec, sc_spec, mask_spec]
     else:
-        operands = (q, kq, vq, mask3)
+        operands = (q, kq, vq, mask4)
         in_specs = [q_spec, kv_spec, kv_spec, mask_spec]
 
     acc, m, l = pl.pallas_call(
@@ -476,20 +524,22 @@ def extend_attention(
     nk = lk // bk
     rows = kvh * u * group  # the [KVH, U, group]-flat partial layout
 
-    maskf = mask.astype(jnp.float32)  # [B, U, L]
+    mask4 = _tile_mask(mask, nk, bk)  # [B, nk, U, bk]
 
     q_spec = pl.BlockSpec((1, u, h, d), lambda bi, ki: (bi, 0, 0, 0))
     kv_spec = pl.BlockSpec((1, bk, kvh, d), lambda bi, ki: (bi, ki, 0, 0))
-    sc_spec = pl.BlockSpec((1, bk, kvh, 1), lambda bi, ki: (bi, ki, 0, 0))
-    mask_spec = pl.BlockSpec((1, u, bk), lambda bi, ki: (bi, 0, ki))
+    sc_spec = pl.BlockSpec((1, bk, kvh), lambda bi, ki: (bi, ki, 0))
+    mask_spec = pl.BlockSpec((1, 1, u, bk), lambda bi, ki: (bi, ki, 0, 0))
     part_spec = pl.BlockSpec((1, 1, rows, d), lambda bi, ki: (bi, ki, 0, 0))
     row_spec = pl.BlockSpec((1, 1, rows, 1), lambda bi, ki: (bi, ki, 0, 0))
 
     if quantized:
-        operands = (q, kq, ks, vq, vs, maskf)
+        operands = (
+            q, kq, _squeeze_scale(ks), vq, _squeeze_scale(vs), mask4
+        )
         in_specs = [q_spec, kv_spec, sc_spec, kv_spec, sc_spec, mask_spec]
     else:
-        operands = (q, kq, vq, maskf)
+        operands = (q, kq, vq, mask4)
         in_specs = [q_spec, kv_spec, kv_spec, mask_spec]
 
     acc, m, l = pl.pallas_call(
@@ -595,7 +645,7 @@ def paged_decode_attention(
     group = h // kvh
     scale = (1.0 / d**0.5) if scale is None else scale
 
-    mask3 = mask.astype(jnp.float32)[:, None, :]  # [B, 1, NP*page]
+    mask4 = _tile_mask(mask[:, None, :], np_tiles, page)  # [B, NP, 1, page]
 
     q_spec = pl.BlockSpec((1, 1, h, d), lambda bi, ki, t: (bi, 0, 0, 0))
     # THE page-table indirection: tile ki of row bi is pool page
@@ -605,17 +655,21 @@ def paged_decode_attention(
         (1, page, kvh, d), lambda bi, ki, t: (t[bi, ki], 0, 0, 0)
     )
     sc_spec = pl.BlockSpec(
-        (1, page, kvh, 1), lambda bi, ki, t: (t[bi, ki], 0, 0, 0)
+        (1, page, kvh), lambda bi, ki, t: (t[bi, ki], 0, 0)
     )
-    mask_spec = pl.BlockSpec((1, 1, page), lambda bi, ki, t: (bi, 0, ki))
+    mask_spec = pl.BlockSpec(
+        (1, 1, 1, page), lambda bi, ki, t: (bi, ki, 0, 0)
+    )
     part_spec = pl.BlockSpec((1, 1, h, d), lambda bi, ki, t: (bi, ki, 0, 0))
     row_spec = pl.BlockSpec((1, 1, h, 1), lambda bi, ki, t: (bi, ki, 0, 0))
 
     if quantized:
-        operands = (kq, ks, vq, vs, mask3)
+        operands = (
+            kq, _squeeze_scale(ks), vq, _squeeze_scale(vs), mask4
+        )
         in_specs = [kv_spec, sc_spec, kv_spec, sc_spec, mask_spec]
     else:
-        operands = (kq, vq, mask3)
+        operands = (kq, vq, mask4)
         in_specs = [kv_spec, kv_spec, mask_spec]
 
     acc, m, l = pl.pallas_call(
@@ -704,16 +758,18 @@ def paged_extend_attention(
     scale = (1.0 / d**0.5) if scale is None else scale
     rows = kvh * u * group
 
-    maskf = mask.astype(jnp.float32)  # [B, U, NP*page]
+    mask4 = _tile_mask(mask, np_tiles, page)  # [B, NP, U, page]
 
     q_spec = pl.BlockSpec((1, u, h, d), lambda bi, ki, t: (bi, 0, 0, 0))
     kv_spec = pl.BlockSpec(
         (1, page, kvh, d), lambda bi, ki, t: (t[bi, ki], 0, 0, 0)
     )
     sc_spec = pl.BlockSpec(
-        (1, page, kvh, 1), lambda bi, ki, t: (t[bi, ki], 0, 0, 0)
+        (1, page, kvh), lambda bi, ki, t: (t[bi, ki], 0, 0)
     )
-    mask_spec = pl.BlockSpec((1, u, page), lambda bi, ki, t: (bi, 0, ki))
+    mask_spec = pl.BlockSpec(
+        (1, 1, u, page), lambda bi, ki, t: (bi, ki, 0, 0)
+    )
     part_spec = pl.BlockSpec(
         (1, 1, rows, d), lambda bi, ki, t: (bi, ki, 0, 0)
     )
@@ -722,10 +778,12 @@ def paged_extend_attention(
     )
 
     if quantized:
-        operands = (kq, ks, vq, vs, maskf)
+        operands = (
+            kq, _squeeze_scale(ks), vq, _squeeze_scale(vs), mask4
+        )
         in_specs = [kv_spec, sc_spec, kv_spec, sc_spec, mask_spec]
     else:
-        operands = (kq, vq, maskf)
+        operands = (kq, vq, mask4)
         in_specs = [kv_spec, kv_spec, mask_spec]
 
     acc, m, l = pl.pallas_call(
@@ -763,18 +821,6 @@ def _head_sharded_call(mesh, fn, q, k, v, head_axis_specs, extras):
     sharding heads is exact: each shard computes its own query-head
     group's full softmax (m/l normalizers are per head) and the
     outputs concatenate back over the head axis."""
-    # jax.shard_map graduated from jax.experimental between releases;
-    # accept either spelling (the experimental checker needs
-    # check_rep=False to admit pallas_call — same note as
-    # ring_attention).
-    if hasattr(jax, "shard_map"):
-        _shard_map = jax.shard_map
-        extra = {}
-    else:
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        extra = {"check_rep": False}
-
     q_spec, kv_spec = head_axis_specs
     rep = jax.sharding.PartitionSpec()
 
@@ -783,13 +829,16 @@ def _head_sharded_call(mesh, fn, q, k, v, head_axis_specs, extras):
             return {name: kv_spec for name in operand}
         return kv_spec
 
-    mapped = _shard_map(
+    # check_vma=False: the kernels' out_shapes carry no varying-axes
+    # type (they are written for the unsharded call), and the Pallas
+    # interpreter the CPU tests run cannot trace under the vma checker.
+    mapped = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(q_spec, tree_spec(k), tree_spec(v),
                   *([rep] * len(extras))),
         out_specs=q_spec,
-        **extra,
+        check_vma=False,
     )
     return mapped(q, k, v, *extras)
 

@@ -242,13 +242,9 @@ def _jnp_flash(q, k, v, mask, causal, scale, window=None):
 
 
 def _vma_of(x):
-    """The varying-manual-axes of ``x``'s aval, or None. jax.typeof
-    (and the vma type system) only exist on newer jax; on releases
-    without it there is no vma checker to satisfy."""
-    typeof = getattr(jax, "typeof", None)
-    if typeof is None:
-        return None
-    return getattr(typeof(x), "vma", None)
+    """The varying-manual-axes of ``x``'s aval (empty outside a
+    vma-checked ``shard_map``)."""
+    return jax.typeof(x).vma
 
 
 def _inside_vma_shard_map(x):
@@ -771,6 +767,44 @@ def flash_attention(
         interpret, window,
     )
     return out
+
+
+def flash_attention_on_mesh(mesh, q, k, v, mask=None, **kwargs):
+    """:func:`flash_attention` for operands that live on ``mesh``
+    (``None``: the plain call). A compiled Mosaic kernel is opaque to
+    GSPMD — the TPU compiler refuses a sharded operand outright
+    ("Mosaic kernels cannot be automatically partitioned. Please wrap
+    the call in a shard_map") — so each device runs the kernel on its
+    own batch rows (the ``data``/``fsdp`` axes) and its own heads (the
+    ``model`` axis). Attention is independent across both, so the
+    shards concatenate exactly. A dimension its axes do not divide (an
+    eval batch of odd size, 12 heads over 8) stays whole on every
+    device."""
+    if mesh is None:
+        return flash_attention(q, k, v, mask, **kwargs)
+    from mlapi_tpu.ops.quant import maybe_dequant_kv
+    from mlapi_tpu.parallel.mesh import batch_shard_axes, fit_spec
+
+    P = jax.sharding.PartitionSpec
+    k = maybe_dequant_kv(k, q.dtype)
+    v = maybe_dequant_kv(v, q.dtype)
+    rows = tuple(a for a in batch_shard_axes(mesh) if a in mesh.axis_names)
+    heads = "model" if "model" in mesh.axis_names else None
+    want = P(rows or None, None, heads, None)
+    q_spec, kv_spec = fit_spec(q.shape, want, mesh), fit_spec(k.shape, want, mesh)
+    if q_spec[2] != kv_spec[2]:  # GQA: the axis must divide both
+        q_spec, kv_spec = (P(s[0], None, None, None) for s in (q_spec, kv_spec))
+    masks = () if mask is None else (mask,)
+
+    def local(q, k, v, *m):
+        return flash_attention(q, k, v, *m, **kwargs)
+
+    # check_vma=False for the reason decode_attention's wrapper gives.
+    return jax.shard_map(
+        local, mesh=mesh,
+        in_specs=(q_spec, kv_spec, kv_spec) + (P(q_spec[0], None),) * len(masks),
+        out_specs=q_spec, check_vma=False,
+    )(q, k, v, *masks)
 
 
 @functools.partial(

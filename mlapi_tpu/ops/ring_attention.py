@@ -31,6 +31,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from mlapi_tpu.ops.attention import NEG
+from mlapi_tpu.utils.platform import pallas_interpret
 
 
 def _varying_like(x, like):
@@ -41,13 +42,8 @@ def _varying_like(x, like):
     type mismatch in jax 0.9's vma checker. ``lax.pcast`` refuses
     axes a value already varies over, so cast only the missing ones.
     """
-    # jax.typeof / vma / lax.pcast exist only on newer jax; on older
-    # releases (no vma checker) the cast is a no-op by construction.
-    typeof = getattr(jax, "typeof", None)
-    if typeof is None or not hasattr(jax.lax, "pcast"):
-        return x
-    want = getattr(typeof(like), "vma", None) or frozenset()
-    have = getattr(typeof(x), "vma", None) or frozenset()
+    want = jax.typeof(like).vma
+    have = jax.typeof(x).vma
     missing = tuple(a for a in want if a not in have)
     if not missing:
         return x
@@ -243,7 +239,7 @@ def _ring_flash_zigzag(q, k, v, mask, *, axis_name, axis_size, scale):
     if mask is None:
         mask = varying(jnp.ones((b, lb), jnp.float32))
     mask = mask.astype(jnp.float32)
-    interpret = jax.default_backend() != "tpu"
+    interpret = pallas_interpret()
     flash = functools.partial(
         flash_attention_with_lse, scale=scale, interpret=interpret
     )
@@ -339,7 +335,7 @@ def _ring_flash(q, k, v, mask, *, axis_name, axis_size, causal, scale):
     if mask is None:
         mask = varying(jnp.ones((b, lb), jnp.float32))
     mask = mask.astype(jnp.float32)
-    interpret = jax.default_backend() != "tpu"
+    interpret = pallas_interpret()
     flash = functools.partial(
         flash_attention_with_lse, scale=scale, interpret=interpret
     )
@@ -465,24 +461,11 @@ def ring_self_attention(
         block_impl=block_impl,
         zigzag=zigzag,
     )
-    # jax.shard_map graduated from jax.experimental between releases;
-    # accept either spelling so the SP path runs on both. The old
-    # experimental checker has no replication rule for pallas_call
-    # (the vma type system that replaced it handles this), so it
-    # needs check_rep=False to admit the flash block kernels.
-    if hasattr(jax, "shard_map"):
-        _shard_map = jax.shard_map
-        extra = {}
-    else:
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        extra = {"check_rep": False}
-    mapped = _shard_map(
+    mapped = jax.shard_map(
         inner,
         mesh=mesh,
         in_specs=(qkv_spec, qkv_spec, qkv_spec, mask_spec),
         out_specs=qkv_spec,
-        **extra,
     )
     if mask is None:
         mask = jnp.ones(q.shape[:2], jnp.float32)

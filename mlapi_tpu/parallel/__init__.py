@@ -15,6 +15,8 @@ from mlapi_tpu.parallel.mesh import (  # noqa: F401
     batch_shard_axes,
     batch_shard_size,
     create_mesh,
+    mesh_for_config,
+    model_on_mesh,
     params_for_model,
     place_params,
     place_train_state,

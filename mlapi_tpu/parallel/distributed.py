@@ -41,15 +41,7 @@ def initialize_from_env() -> bool:
     """
     import jax
 
-    # jax.distributed.is_initialized() only exists on newer jax; on
-    # older releases probe the client handle the same check reads.
-    if hasattr(jax.distributed, "is_initialized"):
-        initialized = jax.distributed.is_initialized()
-    else:
-        from jax._src import distributed as _dist
-
-        initialized = _dist.global_state.client is not None
-    if initialized:
+    if jax.distributed.is_initialized():
         return True
 
     coordinator = os.environ.get("MLAPI_TPU_COORDINATOR")

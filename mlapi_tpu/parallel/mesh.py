@@ -40,6 +40,11 @@ def create_mesh(
     memory by the axis size at equal math.
     """
     devices = list(jax.devices() if devices is None else devices)
+    if shape is not None and int(np.prod(shape)) < len(devices):
+        # An explicit shape smaller than the host takes the first
+        # devices (e.g. a (1, 4) TP mesh on an 8-device host): the
+        # deployment decides the slice, not the host size.
+        devices = devices[: int(np.prod(shape))]
     n = len(devices)
     if axis_names is None:
         axis_names = (
@@ -55,10 +60,71 @@ def create_mesh(
     return Mesh(mesh_devices, axis_names)
 
 
+def mesh_for_config(shape, *, devices=None) -> Mesh | None:
+    """The mesh a training config's ``mesh_shape`` gets on this host.
+
+    ``None`` ⇒ no mesh. A shape that fits ⇒ that mesh. A shape that
+    does NOT fit: on ONE visible device the run is unsharded (the
+    ladder presets name pod-slice meshes; one chip is the degenerate
+    slice), but with several devices visible it is an error — quietly
+    training on the first device would leave the rest idle."""
+    if shape is None:
+        return None
+    devices = list(jax.devices() if devices is None else devices)
+    need = int(np.prod(shape))
+    if need <= len(devices):
+        return create_mesh(tuple(shape), devices=devices)
+    if len(devices) == 1:
+        return None
+    raise ValueError(
+        f"mesh {tuple(shape)} needs {need} devices but {len(devices)} "
+        "are visible: pass --mesh-shape with a shape that fits (e.g. "
+        f"'{len(devices)},1')"
+    )
+
+
+def model_on_mesh(model, mesh):
+    """``model`` with ``mesh`` pinned on it when its attention runs as
+    a Pallas kernel (``attention_impl`` / ``decode_attn_impl`` ==
+    ``"flash"``): GSPMD cannot partition the opaque kernel, so the
+    model wraps it in ``shard_map`` over the mesh it is told about
+    (``flash_attention_on_mesh``, the ``*_tp`` cache-read wrappers).
+    The field already exists (ring attention uses it) and program
+    factories key on it for free. Models that are not dataclasses with
+    a ``mesh`` field (wrappers) come back unchanged."""
+    import dataclasses
+
+    if mesh is None or getattr(model, "mesh", None) is not None or "flash" not in (
+        getattr(model, "attention_impl", None),
+        getattr(model, "decode_attn_impl", None),
+    ):
+        return model
+    try:
+        return dataclasses.replace(model, mesh=mesh)
+    except TypeError:
+        return model
+
+
 def replicate_for_mesh(pytree, mesh: Mesh):
     """Fully replicate every leaf across the mesh (params, opt state)."""
     sharding = NamedSharding(mesh, P())
     return jax.device_put(pytree, sharding)
+
+
+def fit_spec(shape, spec, mesh: Mesh) -> P:
+    """``spec`` with every axis dropped whose mesh size does not
+    divide its dimension. Published vocabularies are not round
+    (bert-base 30522, gpt2 50257): on a 4-way model axis their
+    vocab-sharded tables stay whole on every device instead of
+    failing placement; GSPMD partitions the rest as declared."""
+    if spec is None:
+        return P()
+    axes = []
+    for dim, axis in zip(shape, tuple(spec)):
+        names = axis if isinstance(axis, tuple) else (axis,)
+        size = int(np.prod([mesh.shape[a] for a in names if a]))
+        axes.append(axis if dim % size == 0 else None)
+    return P(*axes)
 
 
 def place_params(params, mesh: Mesh, spec_tree=None):
@@ -87,7 +153,7 @@ def place_params(params, mesh: Mesh, spec_tree=None):
     def put(leaf, spec):
         if _is_quant_leaf(leaf):
             q, scale = leaf["q"], leaf["scale"]
-            full = tuple(spec) if spec is not None else ()
+            full = tuple(fit_spec(q.shape, spec, mesh))
             full = full + (None,) * (q.ndim - len(full))
             sspec = P(
                 *((None,) * (scale.ndim - 1) + (full[q.ndim - 1],))
@@ -97,7 +163,7 @@ def place_params(params, mesh: Mesh, spec_tree=None):
                 "scale": jax.device_put(scale, NamedSharding(mesh, sspec)),
             }
         return jax.device_put(
-            leaf, NamedSharding(mesh, spec if spec is not None else P())
+            leaf, NamedSharding(mesh, fit_spec(leaf.shape, spec, mesh))
         )
 
     return jax.tree.map(put, params, spec_tree, is_leaf=_is_quant_leaf)

@@ -515,9 +515,13 @@ def _supervise_router(ckpt: str | None, args) -> int:
 
 
 def main(argv=None) -> None:
-    from mlapi_tpu.utils.platform import apply_platform_override
+    from mlapi_tpu.utils.platform import (
+        apply_platform_override,
+        enable_compile_cache,
+    )
 
     apply_platform_override()
+    enable_compile_cache()
     parser = argparse.ArgumentParser("mlapi_tpu.serving")
     parser.add_argument("--checkpoint", help="committed checkpoint dir")
     parser.add_argument(
@@ -1011,9 +1015,8 @@ def main(argv=None) -> None:
                 f"{len(devices)} visible"
             )
         # A shape smaller than the host's device count serves on the
-        # first `need` devices (e.g. a (1,4) TP mesh on an 8-device
-        # host) — the deployment decides the slice, not the host size.
-        mesh = create_mesh(shape, devices=devices[:need])
+        # first `need` devices (create_mesh's rule).
+        mesh = create_mesh(shape)
     engine = InferenceEngine.from_checkpoint(
         ckpt, quantize=args.quantize,
         kv_quant=args.kv_quant,

@@ -1055,6 +1055,7 @@ def _install_common(app: App, engine, registry: MetricsRegistry, batcher) -> Non
             "classes": list(engine.vocab.labels),
             "checkpoint": engine.meta,
             "backend": jax.default_backend(),
+            "device_kind": jax.devices()[0].device_kind,
             "device_count": jax.device_count(),
             # Which worker process answered — observability for
             # SO_REUSEPORT multi-worker serving (and the multiworker

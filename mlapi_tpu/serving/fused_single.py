@@ -17,7 +17,7 @@ generation to one fused-chunk dispatch
 
 The retired whole-generation serving paths (``try_run`` /
 ``try_run_batch`` and their warm grids) are measured against this
-fold in ``bench.py::_sched_report`` (BENCH_r16.json):
+fold in ``bench.py::_sched_report``:
 ``generate_tier_fn`` / ``fused_spec_fn`` remain available as LIBRARY
 entry points (``ops/speculative.py``, ``models/gpt.py``) but the
 serving engine no longer routes requests to them.
@@ -97,7 +97,7 @@ class FusedSinglePath:
         of a generation never dispatches (and never page-allocates)
         wider than it can use, and the program count stays
         logarithmic. Falls back to the plain chunk (returns 0) while
-        a streaming row is live, and in strict (tunnel) mode for any
+        a streaming row is live, and in strict (high-RTT) mode for any
         (batch width, cache length, width) shape not proven compiled
         — those widths compile on demand only where a compile is
         cheap."""

@@ -41,8 +41,10 @@ def run(
     from mlapi_tpu.checkpoint import save_checkpoint
     from mlapi_tpu.datasets import get_dataset
     from mlapi_tpu.models import get_model
-    from mlapi_tpu.parallel import create_mesh, initialize_from_env
+    from mlapi_tpu.parallel import initialize_from_env, mesh_for_config
     from mlapi_tpu.train import fit
+    from mlapi_tpu.train.bench import bytes_per_device
+    from mlapi_tpu.utils.platform import device_report
 
     initialize_from_env()  # multi-host no-op on a single host
 
@@ -147,20 +149,7 @@ def run(
                 "tokenizer and model vocab_size disagree"
             )
 
-    mesh = None
-    if cfg.mesh_shape is not None:
-        n_need = 1
-        for s in cfg.mesh_shape:
-            n_need *= s
-        if n_need <= jax.device_count():
-            mesh = create_mesh(cfg.mesh_shape)
-        else:
-            _log.warning(
-                "config wants mesh %s but only %d device(s) visible; "
-                "running unsharded",
-                cfg.mesh_shape,
-                jax.device_count(),
-            )
+    mesh = mesh_for_config(cfg.mesh_shape)
 
     train_state_dir = cfg.checkpoint_dir or (f"{out}_train_state" if out else None)
     if save_every and not train_state_dir:
@@ -225,17 +214,25 @@ def run(
         "name": cfg.name,
         "steps": result.steps,
         "wall_seconds": result.wall_seconds,
+        "first_loss": result.first_loss,
         "final_loss": result.final_loss,
         "test_accuracy": result.test_accuracy,
         "dataset_source": splits.source,
         "checkpoint": out,
+        "mesh": list(mesh.devices.shape) if mesh is not None else None,
+        "param_bytes_per_device": bytes_per_device(result.params),
+        "device": device_report(),
     }
 
 
 def main(argv=None) -> None:
-    from mlapi_tpu.utils.platform import apply_platform_override
+    from mlapi_tpu.utils.platform import (
+        apply_platform_override,
+        enable_compile_cache,
+    )
 
     apply_platform_override()
+    enable_compile_cache()
     parser = argparse.ArgumentParser("mlapi_tpu.train")
     group = parser.add_mutually_exclusive_group()
     group.add_argument(

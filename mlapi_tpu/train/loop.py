@@ -37,6 +37,9 @@ class TrainResult:
     steps: int
     wall_seconds: float
     history: list[dict] = field(default_factory=list)
+    # Loss of the first step this call ran (before any update it made
+    # took effect) — with final_loss, "did it learn at all".
+    first_loss: float | None = None
 
 
 def make_train_step(
@@ -431,8 +434,13 @@ def fit(
     ``profile_dir`` wraps the whole loop in a ``jax.profiler.trace``
     (view with TensorBoard/XProf).
     """
-    from mlapi_tpu.parallel import params_for_model, shard_batch_for_mesh
+    from mlapi_tpu.parallel import (
+        model_on_mesh,
+        params_for_model,
+        shard_batch_for_mesh,
+    )
 
+    model = model_on_mesh(model, mesh)
     if task == "auto":
         # Prefer the dataset's explicit marker (extras["task"], set by
         # LM loaders); fall back to the label-shape heuristic.
@@ -624,6 +632,7 @@ def fit(
     t0 = time.perf_counter()
     history: list[dict] = []
     loss = float("nan")
+    first_loss = None
     try:
         with profiler_cm:
             for i in range(start_step, steps):
@@ -631,6 +640,8 @@ def fit(
                 if mesh is not None:
                     x, y = shard_batch_for_mesh((x, y), mesh)
                 params, opt_state, loss = step_fn(params, opt_state, x, y)
+                if first_loss is None:
+                    first_loss = loss  # device scalar; read after the loop
                 if eval_every and (i + 1) % eval_every == 0:
                     if not np.isfinite(float(loss)):
                         raise FloatingPointError(
@@ -696,4 +707,5 @@ def fit(
         steps=steps,
         wall_seconds=wall,
         history=history,
+        first_loss=None if first_loss is None else float(first_loss),
     )

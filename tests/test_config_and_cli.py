@@ -65,15 +65,22 @@ def test_train_cli_to_serving_engine(tmp_path):
     assert manifest["config"]["train_config"]["name"] == "iris-linear"
 
 
-def test_train_cli_mesh_fallback_when_devices_missing(tmp_path):
-    """A config demanding more devices than visible degrades to
-    unsharded with a warning instead of crashing (mesh wants 8x1;
-    virtual CPU has 8 so force an impossible shape)."""
+def test_train_cli_mesh_that_does_not_fit(tmp_path):
+    """A config demanding more devices than visible: on ONE device
+    the run is unsharded (the presets name pod-slice meshes), but
+    with several visible it is an error naming --mesh-shape — never a
+    quiet run on the first device with the rest idle."""
+    import jax
+
+    from mlapi_tpu.parallel import mesh_for_config
+
     cfg = dataclasses.replace(
         get_preset("iris-linear"), mesh_shape=(64, 1), steps=50
     )
-    summary = train_run(cfg, None)
-    assert summary["test_accuracy"] is not None
+    with pytest.raises(ValueError, match="--mesh-shape"):
+        train_run(cfg, None)
+    assert mesh_for_config((64, 1), devices=jax.devices()[:1]) is None
+    assert mesh_for_config((2, 2)).devices.shape == (2, 2)
 
 
 def test_train_bench_reports_throughput():

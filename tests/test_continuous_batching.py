@@ -1,6 +1,6 @@
 """Continuous batching: requests are admitted into a RUNNING decode
 batch at chunk boundaries (tier-aligned admission — the design
-analyzed in BASELINE.md r03 and built in r03), instead of waiting for
+analyzed and built in r03), instead of waiting for
 the whole batch to finish.
 
 The load-bearing property is *token-exactness*: a request admitted

@@ -162,7 +162,9 @@ def test_trajectory_matches_plain_dp_digits_mlp(mesh_fsdp8):
     r_fsdp = fit(get_model("mlp", **MLP_KW), splits,
                  mesh=mesh_fsdp8, steps=2, **kw)
     for h_dp, h_f in zip(r_dp.history, r_fsdp.history):
-        assert h_dp["loss"] == h_f["loss"]  # bit-exact
+        # Reduce-scatter sums in a different order than all-reduce:
+        # equal to float32 rounding (bit-exact under older XLA:CPU).
+        assert h_dp["loss"] == pytest.approx(h_f["loss"], rel=1e-5)
 
     kw = dict(
         steps=100, batch_size=64, learning_rate=1e-3,
@@ -187,7 +189,7 @@ def test_trajectory_matches_plain_dp_small_bert(mesh_fsdp8):
                mesh=create_mesh((8, 1)), **kw)
     r_fsdp = fit(get_model("bert_classifier", **TINY_BERT), splits,
                  mesh=mesh_fsdp8, **kw)
-    assert f"{r_dp.final_loss:.6f}" == f"{r_fsdp.final_loss:.6f}"
+    assert r_dp.final_loss == pytest.approx(r_fsdp.final_loss, abs=5e-6)
 
 
 def test_checkpoint_roundtrip_resume_exact_2x2x2(mesh_2x2x2, tmp_path):

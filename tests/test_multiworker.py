@@ -1,7 +1,7 @@
 """SO_REUSEPORT multi-worker serving: ``--workers N`` spawns N fresh
 server processes sharing one listening port, kernel-balanced per
 connection — the CPU-attach scale-out past the single asyncio loop's
-~one-core ceiling (BASELINE.md known-limitations, built in r03).
+~one-core ceiling (built in r03).
 
 Integration test: real subprocesses, real sockets, real HTTP.
 """

@@ -64,7 +64,11 @@ CFG = dict(
 # Long-context variant for the chunked-prefill interleaving tests: a
 # 200-token prompt rounds to a [256]-wide bucket (two 128-wide chunks)
 # and still leaves decode room inside the window.
-LONG_CFG = dict(CFG, max_positions=320)
+# Wide enough that the interleave drill's 200-char joiner (bucket 256)
+# is window-compatible with the 130-token stream it joins
+# (``_compatible``: 256 + 130 <= max_positions); at 320 the collector
+# lanes it as a batch of its own and nothing interleaves.
+LONG_CFG = dict(CFG, max_positions=512)
 
 
 def _model(kind="gpt_lm", kv_quant="none", impl="einsum", cfg=CFG):
@@ -459,7 +463,7 @@ async def test_batched_spec_paged_realign(spec_models, page, counter):
 
 def test_paged_spec_strict_admit_engages(spec_models):
     """FORMER DECLINE PIN, now a passing end-to-end test (r11): in
-    strict (tunnel) mode the spec warm grid compiles POOL-SHAPED
+    strict (high-RTT) mode the spec warm grid compiles POOL-SHAPED
     verify/realign programs for paged engines (``SpecPhase.warm``
     branches on ``eng.pool``), so paged batches speculate without a
     mid-batch compile — and an engine whose paged shapes were NOT

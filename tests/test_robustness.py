@@ -32,7 +32,11 @@ import pytest
 
 from mlapi_tpu.models import get_model
 from mlapi_tpu.serving import build_app, faults
-from mlapi_tpu.serving.scoring import MicroBatcher, OverloadedError
+from mlapi_tpu.serving.scoring import (
+    MicroBatcher,
+    OverloadedError,
+    ScorePath,
+)
 from mlapi_tpu.serving.engine import TextGenerationEngine, _SyncSink
 from mlapi_tpu.serving.paged_pool import PagePoolExhausted
 from mlapi_tpu.serving.requests import DeadlineExceeded, DrainCancelled
@@ -97,12 +101,18 @@ def _pool_baseline(eng) -> None:
     assert int(ref[1:].sum()) == 0, np.nonzero(ref[1:])
 
 
+def _busy(eng) -> bool:
+    """A batch is in flight: on a scheduler lane (the default
+    execution model) or in the serial ``_run_batch`` wrapper."""
+    return eng.sched_batches_live > 0 or eng._running is not None
+
+
 async def _settle(eng, timeout=5.0) -> None:
     """Wait for the decode thread to finish its current batch (page
     cleanup runs in the batch's finally)."""
     loop = asyncio.get_running_loop()
     deadline = loop.time() + timeout
-    while eng._running is not None and loop.time() < deadline:
+    while _busy(eng) and loop.time() < deadline:
         await asyncio.sleep(0.02)
 
 
@@ -136,19 +146,19 @@ def test_default_deadline_applies_when_request_names_none():
 
 
 def test_deadlined_request_declines_fused_fast_path():
-    """One fused run is one uninterruptible device program with no
-    boundary to check a deadline at — a deadlined solo request must
-    decode CHUNKED (where every boundary enforces the budget), not
-    return 200 with the full completion long after the budget passed.
-    The emitted stream is byte-identical either way (pinned r04), so
-    the decline is invisible in the response."""
+    """Fused generation is tier-wide decode CHUNKS, each one typed unit
+    with a boundary where the deadline is enforced (the
+    whole-generation program, which had no boundary and had to
+    decline deadlined requests, left serving with the r20 fold). So a
+    deadlined solo request rides the fused widths like its
+    deadline-less twin, and the stream is byte-identical."""
     eng = _engine(fused_single=True)
     ref = eng.generate_text("hello", max_new_tokens=6)
     assert eng.fused_calls == 1  # deadline-less solo unary runs fused
     out = eng.generate_text(
         "hello", max_new_tokens=6, deadline_ms=60_000
     )
-    assert eng.fused_calls == 1  # the deadlined twin declined it
+    assert eng.fused_calls == 2  # no per-path decline gate is left
     assert out["token_ids"] == ref["token_ids"]
 
 
@@ -514,8 +524,11 @@ async def test_drain_e2e_healthz_and_shed_over_http():
                       "stream": True},
             )
         )
-        # Wait until the stream is actually decoding.
-        while eng._running is None:
+        # Wait until the stream is actually decoding (bounded: a
+        # stream that already finished must not spin this forever).
+        t_end = asyncio.get_running_loop().time() + 30.0
+        while not _busy(eng) and not stream_task.done():
+            assert asyncio.get_running_loop().time() < t_end
             await asyncio.sleep(0.01)
         shutdown = asyncio.create_task(app.shutdown())
         while not eng.draining:
@@ -739,6 +752,35 @@ async def _matrix_traffic(eng, tier_leg: bool = False) -> list:
     return outcomes
 
 
+class _ScoreStub:
+    """A scoring engine whose label is its row's first feature."""
+
+    max_batch = 16
+
+    def predict_labels(self, batch):
+        return [str(float(r[0])) for r in batch], np.full(len(batch), 0.5)
+
+
+async def _score_traffic(eng) -> list:
+    """The scoring leg: a co-resident ``ScorePath`` dispatching its
+    micro-batch as a typed ``score`` unit on this engine's scheduler —
+    the only traffic that crosses ``score_dispatch``. Outcomes are
+    shaped like streams': (result, error-or-None)."""
+    sp = ScorePath(_ScoreStub(), model_id="clf", max_wait_ms=0.0,
+                   sched_source=lambda: eng.sched)
+    await sp.start()
+    try:
+        res = await asyncio.wait_for(
+            asyncio.gather(*[sp.submit(np.full(4, float(i)))
+                             for i in range(3)], return_exceptions=True),
+            30,
+        )
+    finally:
+        await sp.stop()
+    return [([], r) if isinstance(r, Exception) else ([r[0]], None)
+            for r in res]
+
+
 # The engine-lifecycle seams: this matrix drives ENGINE traffic, so
 # the router↔replica hop (`router_forward`, which only a router in
 # front of replica servers crosses) has its own matrix —
@@ -787,6 +829,8 @@ async def test_fault_matrix_conservation(point, action):
         outcomes = await _matrix_traffic(
             eng, tier_leg=point.startswith("tier_")
         )
+        if point == "score_dispatch":
+            outcomes += await _score_traffic(eng)
         if action == "delay=0.02":
             # Delays slow, never break: every stream must COMPLETE.
             for toks, err in outcomes:

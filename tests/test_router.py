@@ -213,7 +213,7 @@ async def test_warm_peer_hint_is_hrw_head_on_every_non_preferred_forward():
     pref = _preferred(router, key)
     seen = []
 
-    async def fake_attempt(r, request, warm_peer=None):
+    async def fake_attempt(r, request, warm_peer=None, extra=None):
         seen.append((r, warm_peer))
         return json_response({}, 200)
 
@@ -246,7 +246,7 @@ async def test_warm_peer_hint_survives_failover_hop():
     pref = _preferred(router, key)
     calls = []
 
-    async def fake_attempt(r, request, warm_peer=None):
+    async def fake_attempt(r, request, warm_peer=None, extra=None):
         calls.append((r, warm_peer))
         if len(calls) == 1:
             raise _SubmitError("injected pre-submit", retryable=True)

@@ -17,7 +17,7 @@ path left to diverge. This module pins the fold's contract:
   requests ride fused chunks (both formerly fell back / declined);
 - streams pin the plain chunk (incremental delivery), including a
   streaming JOINER admitted mid-generation into a fused lane;
-- strict (tunnel) mode takes fused widths only for shapes the warm
+- strict (high-RTT) mode takes fused widths only for shapes the warm
   grid proved compiled, and the warm grid records at the dispatch
   site so the two can never disagree.
 
@@ -123,7 +123,7 @@ def test_over_cap_budget_rides_widest_tier(gpt_params):
 
 def test_strict_mode_requires_warmed_fused_shape(gpt_params):
     eng = _engine(gpt_params)
-    eng._strict_admit = True             # tunnel discipline, no warmup
+    eng._strict_admit = True             # strict discipline, no warmup
     eng.generate_text(PROMPT, max_new_tokens=32)
     assert eng.fused_calls == 0          # unwarmed shape -> plain chunks
     eng._strict_admit = False

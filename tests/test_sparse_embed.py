@@ -4,7 +4,7 @@ recsys path it replaces — same rowwise-AdaGrad math per unique row,
 duplicate ids aggregated exactly like gather autodiff does, untouched
 rows bit-frozen — while never materializing the dense table cotangent
 or the full-table optimizer sweep (the criteo step's dominant HBM
-traffic, BASELINE.md roofline)."""
+traffic)."""
 
 import jax
 import jax.numpy as jnp
@@ -163,8 +163,7 @@ def test_guards_are_loud(model):
 def test_sparse_matches_dense_on_tpu(model, batch):
     """The sparse scatter pipeline on REAL Mosaic lowering: TPU
     scatter/segment-sum must reproduce the dense trajectory exactly
-    like the CPU run does (this is the alive-window harvest's
-    on-chip check for the r05 flagship)."""
+    like the CPU run does (the on-chip check for the r05 flagship)."""
     x, y = batch
     p0 = model.init(jax.random.key(0))
     dense_p, dense_loss = _run_dense(model, p0, x, y, 3, 3e-3)

@@ -1,0 +1,211 @@
+"""The main path's Pallas kernels, handed to the TPU's own compiler
+for a DESCRIBED v5e (``jax.experimental.topologies``): nothing runs
+and no chip is attached, but what Mosaic refuses on the chip — a
+block whose minor dims break the tiling, too much VMEM, a kernel
+GSPMD cannot partition — is refused here, at BERT-base and
+GPT-2-small geometry, before any chip time is spent.
+
+This is the ONE file that touches libtpu in tier-1. The topology is
+described inside a module-scoped, non-autouse fixture, never at
+import, in a ``skipif``, in ``parametrize`` or in ``conftest.py``:
+one process at a time may load the library, every xdist worker
+imports every test file, and only the worker that is dealt this file
+may reach for it. A compile against a described device is not a chip
+run and proves nothing about results or speed — ``chip_smoke.py``
+does that on the chip.
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from mlapi_tpu.ops.pallas import (
+    decode_attention,
+    decode_attention_tp,
+    extend_attention,
+    flash_attention,
+    flash_attention_on_mesh,
+    paged_decode_attention,
+    paged_extend_attention,
+)
+
+# BERT-base: 12 heads x 64, the sst2-bert preset's batch and length.
+BERT_B, BERT_L, HEADS, HEAD_DIM = 32, 128, 12, 64
+# GPT-2 small serving: 12 KV heads x 64, 1024 positions, 16-token
+# pages (what the verify skill and chip_smoke drive), a 16-token
+# extend span, 4 rows.
+GEN_B, GEN_L, PAGE, SPAN = 4, 1024, 16, 16
+POOL_PAGES = GEN_B * GEN_L // PAGE + 1
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — any failure means "cannot"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh(topo):
+    """(data=1, model=4): the generative-serving TP layout."""
+    return Mesh(np.array(topo.devices).reshape(1, 4), ("data", "model"))
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _no_persistent_cache():
+    """A compile for a described device is written to the persistent
+    cache but can never be read back without a chip; keep these
+    compiles out of it (and silent)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def _shape(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _cache(shape, fmt, sharding, scale_sharding=None):
+    """A KV operand in one of the two stored formats."""
+    if fmt == "int8":
+        return {
+            "q": _shape(shape, jnp.int8, sharding),
+            "scale": _shape(
+                shape[:-1] + (1,), jnp.float32, scale_sharding or sharding
+            ),
+        }
+    return _shape(shape, jnp.bfloat16, sharding)
+
+
+def _compile(fn, *args):
+    return jax.jit(fn).lower(*args).compile()
+
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+def test_flash_attention_bert_base(one_chip, direction):
+    """The sst2-bert preset's attention: forward, and the custom-VJP
+    backward kernels, at batch 32 x 128 tokens."""
+    qkv = _shape((BERT_B, BERT_L, HEADS, HEAD_DIM), jnp.bfloat16, one_chip)
+    mask = _shape((BERT_B, BERT_L), jnp.float32, one_chip)
+
+    def fwd(q, k, v, m):
+        return flash_attention(q, k, v, m, interpret=False)
+
+    if direction == "forward":
+        fn = fwd
+    else:
+        def fn(q, k, v, m):
+            loss = lambda q, k, v: jnp.sum(  # noqa: E731
+                fwd(q, k, v, m).astype(jnp.float32) ** 2
+            )
+            return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    txt = _compile(fn, qkv, qkv, qkv, mask).as_text()
+    assert "tpu_custom_call" in txt
+
+
+@pytest.mark.parametrize("shape", [(4, 1), (1, 4)])
+def test_flash_attention_on_mesh_bert_base(topo, shape):
+    """The same attention on four chips, data- and tensor-parallel:
+    sharded operands reach the kernel only through ``shard_map``
+    (handed to GSPMD, Mosaic answers "cannot be automatically
+    partitioned"), forward and backward, with no all-gather put
+    around it."""
+    mesh4 = Mesh(np.array(topo.devices).reshape(shape), ("data", "model"))
+    on = NamedSharding(mesh4, P("data", None, "model", None))
+    qkv = _shape((BERT_B, BERT_L, HEADS, HEAD_DIM), jnp.bfloat16, on)
+    mask = _shape((BERT_B, BERT_L), jnp.float32,
+                  NamedSharding(mesh4, P("data", None)))
+
+    def fn(q, k, v, m):
+        loss = lambda q, k, v: jnp.sum(  # noqa: E731
+            flash_attention_on_mesh(
+                mesh4, q, k, v, m, interpret=False
+            ).astype(jnp.float32) ** 2
+        )
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    txt = _compile(fn, qkv, qkv, qkv, mask).as_text()
+    assert "tpu_custom_call" in txt
+    assert "all-gather" not in txt
+
+
+@pytest.mark.parametrize("fmt", ["bf16", "int8"])
+@pytest.mark.parametrize(
+    "kernel", ["decode", "extend", "paged_decode", "paged_extend"]
+)
+def test_cache_read_kernels_gpt2_small(one_chip, kernel, fmt):
+    """The four cache-read kernels x both stored formats. The paged
+    pair's k-tile IS the 16-token page (below the 128-lane width, and
+    below int8's 32-row sublane tile): the layouts Mosaic must take."""
+    u = 1 if "decode" in kernel else SPAN
+    q = _shape((GEN_B, u, HEADS, HEAD_DIM), jnp.bfloat16, one_chip)
+    mask_shape = (GEN_B, GEN_L) if u == 1 else (GEN_B, u, GEN_L)
+    mask = _shape(mask_shape, jnp.float32, one_chip)
+    if kernel.startswith("paged"):
+        pool = _cache((POOL_PAGES, PAGE, HEADS, HEAD_DIM), fmt, one_chip)
+        table = _shape((GEN_B, GEN_L // PAGE), jnp.int32, one_chip)
+        fn = paged_decode_attention if u == 1 else paged_extend_attention
+        compiled = _compile(
+            functools.partial(fn, interpret=False), q, pool, pool, table, mask
+        )
+    else:
+        cache = _cache((GEN_B, GEN_L, HEADS, HEAD_DIM), fmt, one_chip)
+        fn = decode_attention if u == 1 else extend_attention
+        compiled = _compile(
+            functools.partial(fn, interpret=False), q, cache, cache, mask
+        )
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("fmt", ["bf16", "int8"])
+def test_decode_attention_tp_runs_per_shard(mesh, fmt):
+    """The open question of ROADMAP S3: under a model-axis mesh, does
+    the compiled kernel run PER SHARD on its local heads, or does
+    GSPMD all-gather the head-sharded cache around the opaque custom
+    call? ``decode_attention_tp``'s shard_map must leave the kernel in
+    the program with no all-gather of a cache-sized operand."""
+    heads = NamedSharding(mesh, P(None, None, "model", None))
+    rep = NamedSharding(mesh, P())
+    q = _shape((GEN_B, 1, HEADS, HEAD_DIM), jnp.bfloat16, heads)
+    cache = _cache((GEN_B, GEN_L, HEADS, HEAD_DIM), fmt, heads)
+    mask = _shape((GEN_B, GEN_L), jnp.float32, rep)
+
+    compiled = _compile(
+        lambda q, k, v, m: decode_attention_tp(
+            mesh, q, k, v, m, interpret=False
+        ),
+        q, cache, cache, mask,
+    )
+    txt = compiled.as_text()
+    assert "tpu_custom_call" in txt
+    # Per-shard operands: 12 heads over 4 = 3 local KV heads.
+    local = f"[{GEN_B},{GEN_L},{HEADS // 4},{HEAD_DIM}]"
+    assert local in txt.replace(" ", ""), "kernel does not see local heads"
+    gathers = [
+        line for line in txt.splitlines()
+        if "all-gather" in line and f"{GEN_L}" in line
+    ]
+    assert not gathers, gathers
