@@ -1092,12 +1092,8 @@ def _install_common(app: App, engine, registry: MetricsRegistry, batcher) -> Non
             snap["counters"]["generate.batch_calls"] = engine.batch_calls
             snap["counters"]["generate.chunk_calls"] = engine.chunk_calls
             snap["counters"]["generate.rejected"] = engine.rejected
-            snap["counters"]["generate.cancelled_batches"] = (
-                engine.cancelled_batches
-            )
             snap["counters"]["generate.compactions"] = engine.compactions
             snap["counters"]["generate.admitted"] = engine.admitted
-            snap["counters"]["generate.growths"] = engine.growths
             snap["counters"]["generate.prefix_hits"] = engine.prefix_hits
             snap["counters"]["generate.prefix_misses"] = (
                 engine.prefix_misses
@@ -1228,6 +1224,40 @@ def _install_common(app: App, engine, registry: MetricsRegistry, batcher) -> Non
             snap["counters"]["generate.brownout_tenant_clamped"] = (
                 engine.brownout_tenant_clamped
             )
+            # The program's own clock (utils/metrics.span), as sums of
+            # microseconds (_us) and of spans (_n): a request's life
+            # (submit → the scheduler's claim → first token), the
+            # dispatch thread's time by unit kind, its wait for the
+            # device at each token readback, and its time with nothing
+            # to dispatch. Over a window, readback_wait_us + sched_idle_us
+            # + the sched_unit_*_us not spent in readbacks is the
+            # thread's wall time.
+            sums = engine.latency.sums.snapshot()["counters"]
+            for name in (
+                "generate.queue_wait_us", "generate.queue_wait_n",
+                "generate.prefill_wait_us", "generate.prefill_wait_n",
+                "generate.readback_wait_us", "generate.readback_wait_n",
+                "generate.sched_idle_us", "generate.sched_idle_n",
+                "generate.sched_unit_prefill_us",
+                "generate.sched_unit_prefill_n",
+                "generate.sched_unit_decode_us",
+                "generate.sched_unit_decode_n",
+                "generate.sched_unit_spec_us",
+                "generate.sched_unit_spec_n",
+                "generate.sched_unit_admit_us",
+                "generate.sched_unit_admit_n",
+                "generate.sched_unit_compact_us",
+                "generate.sched_unit_compact_n",
+                "generate.sched_unit_score_us",
+                "generate.sched_unit_score_n",
+                # a lane's last turn: final drain and cleanup (also
+                # where a unit that raised is counted)
+                "generate.sched_unit_retire_us",
+                "generate.sched_unit_retire_n",
+            ):
+                snap["counters"][name] = sums.get(
+                    name.removeprefix("generate."), 0
+                )
             snap.setdefault("gauges", {})
             snap["gauges"]["generate.sched_queue_depth"] = (
                 engine.sched_queue_depth

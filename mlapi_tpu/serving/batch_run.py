@@ -57,6 +57,8 @@ waiter kept at the wrapper level.
 
 from __future__ import annotations
 
+import time
+
 import jax.numpy as jnp
 import numpy as np
 
@@ -103,6 +105,10 @@ class BatchRun:
         # by the chunk count (a 20-chunk suppressed stream is one
         # blocked engagement, not twenty).
         self._spec_supp_counted = False
+        # Joiners this lane claimed since the scheduler last looked
+        # (their ``rid``): the scheduler names them on its span of
+        # the unit that admitted them, and empties the list.
+        self.claimed: list = []
 
         self.bucket = max(len(r.row) for r in reqs)
         n_new_max = max(r.n_new for r in reqs)
@@ -235,7 +241,7 @@ class BatchRun:
                     int(np.asarray(self._first)[0]),
                     self.bucket, reqs[0].used,
                 )
-            self.chain = DispatchChain(self._deliver)
+            self.chain = DispatchChain(self._deliver, eng.latency.sums)
         except BaseException:
             if self._push is not None:
                 # A failed formation must not leave the handler
@@ -1113,6 +1119,13 @@ class BatchRun:
             except ValueError:
                 pass
 
+    def _claim(self, cand) -> None:
+        """``cand`` is committed to this lane: the stamp that splits
+        its TTFT into queue wait and prefill wait (a joiner handed
+        back later is stamped again by whoever claims it next)."""
+        cand.t_claim = time.perf_counter()
+        self.claimed.append(cand.rid)
+
     def _deliver(self, toks_host, got, plive):
         self.tok = toks_host[:, -1].copy()
         for i in plive:
@@ -1336,6 +1349,7 @@ class BatchRun:
             # error to every member of ``reqs``) cannot also re-serve
             # an already-admitted joiner from ``_admit``.
             self._unstage(cand)
+            self._claim(cand)
             if grow:
                 free = self._grow()
             row = free[0]
@@ -1633,6 +1647,7 @@ class BatchRun:
             return False
         ptab[0, lo_tile:hi_tile] = pages
         self._unstage(cand)
+        self._claim(cand)
         self._pf = {
             "cand": cand, "row": row, "ptab": ptab, "A": A,
             "off": A - bkt, "cp": cp, "skip": (bkt - bkt_eff) // cp,

@@ -22,12 +22,17 @@ from __future__ import annotations
 
 import numpy as np
 
+from mlapi_tpu.utils.metrics import span
+
 
 class DispatchChain:
-    def __init__(self, deliver):
+    def __init__(self, deliver, sums):
         # deliver(toks_host [B, size], size, live_indices): push the
         # drained chunk to its requests and update the host mirrors.
         self._deliver = deliver
+        # Where the readback wait is summed (the engine's
+        # ``LatencyStats.sums``).
+        self._sums = sums
         self._inflight: list = []  # (toks_dev [B, size], size, live)
         self.tok_dev = None        # device-resident feedback token
 
@@ -62,7 +67,13 @@ class DispatchChain:
             except AttributeError:
                 pass
         for toks_dev, got, plive in take:
-            self._deliver(np.asarray(toks_dev), got, plive)
+            # The host blocks HERE until the device has produced the
+            # chunk: the dispatch thread's wait for the device (the
+            # delivery that follows is host work, outside the span).
+            with span("sched.readback", "readback_wait",
+                      registry=self._sums, chunks=len(take)):
+                toks_host = np.asarray(toks_dev)
+            self._deliver(toks_host, got, plive)
 
     def invalidate(self) -> None:
         """Batch state is about to change under the chain: deliver

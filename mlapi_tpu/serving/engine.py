@@ -2503,6 +2503,7 @@ class TextGenerationEngine:
             led.enter(tenant)
             req.on_done = lambda t=tenant: led.exit(t)
         self.requests += 1
+        req.rid = self.requests
         return req
 
     # -- synchronous single-shot (tests, bench, CLI) -----------------------

@@ -18,6 +18,7 @@ import threading
 import time
 
 from mlapi_tpu.serving import faults
+from mlapi_tpu.utils.metrics import MetricsRegistry
 
 
 class DeadlineExceeded(Exception):
@@ -58,12 +59,19 @@ class LatencyStats:
     chunk gap. One instance per engine; ``/metrics`` and the bench
     read :meth:`summary`. Thread-safe (pushes come from the decode
     thread, scrapes from the event loop); bounded so a long-lived
-    server's memory stays flat."""
+    server's memory stays flat.
+
+    ``sums`` holds the engine's elapsed-time sums (``<counter>_us`` /
+    ``<counter>_n`` pairs, ``utils.metrics.span``'s currency): the
+    request-life waits recorded here at first delivery, and the
+    dispatch thread's ``sched_unit_<kind>``, ``readback_wait`` and
+    ``sched_idle`` spans. ``/metrics`` exports them as ``generate.*``."""
 
     def __init__(self, cap: int = 2048):
         self._ttft_ms: collections.deque = collections.deque(maxlen=cap)
         self._itl_ms: collections.deque = collections.deque(maxlen=cap)
         self._lock = threading.Lock()
+        self.sums = MetricsRegistry()
 
     def record_first(self, ms: float) -> None:
         with self._lock:
@@ -108,6 +116,13 @@ def _record_push(sink, item) -> None:
     n = len(item.get("token_ids", ())) or 1
     if sink.t_last is None:
         sink.stats.record_first((now - sink.t0) * 1e3)
+        if sink.t_claim is not None:
+            # The request's life in two waits, on the stamps it
+            # carries: submit → the scheduler's claim, claim → first
+            # token (formation or admission, prefill, readback).
+            sums = sink.stats.sums
+            sums.add_elapsed("queue_wait", int((sink.t_claim - sink.t0) * 1e9))
+            sums.add_elapsed("prefill_wait", int((now - sink.t_claim) * 1e9))
     else:
         sink.stats.record_gap((now - sink.t_last) * 1e3 / n)
     sink.t_last = now
@@ -122,9 +137,9 @@ class GenRequest:
         "row", "used", "n_new", "temperature", "seed", "queue", "loop",
         "cancelled", "top_k", "top_p", "stream",
         "prefix_fp", "prefix_kv", "prefix_len", "prefix_lo",
-        "prompt_tokens", "stats", "t0", "t_last", "deadline",
+        "prompt_tokens", "stats", "t0", "t_claim", "t_last", "deadline",
         "push_to", "pushed", "staged", "adapter", "tenant",
-        "on_done", "_done_fired",
+        "on_done", "_done_fired", "rid",
     )
 
     def __init__(self, row, used, n_new, temperature, seed, loop,
@@ -202,7 +217,15 @@ class GenRequest:
         # and inter-token samples recorded as chunks are pushed.
         self.stats = stats
         self.t0 = time.perf_counter()
+        # Stamped (``perf_counter``) each time the dispatch thread
+        # claims the request for device work: a lane's formation or a
+        # live lane's admission. The last claim before the first token
+        # splits TTFT into queue wait and prefill wait.
+        self.t_claim: float | None = None
         self.t_last: float | None = None
+        # The engine's ordinal of this request (``engine.submit``);
+        # rides the ``sched.unit`` span that first serves it.
+        self.rid = 0
         # Absolute expiry on the ``t0`` clock (``perf_counter``):
         # every dispatch boundary the scheduler owns checks it via
         # ``engine._expire_if_due`` and cancels the row exactly like a
@@ -277,6 +300,7 @@ class _SyncSink:
         self.prefix_len, self.prefix_lo = req.prefix_len, req.prefix_lo
         self.stream = req.stream
         self.stats, self.t0, self.t_last = req.stats, req.t0, None
+        self.t_claim, self.rid = None, req.rid
         self.deadline = req.deadline
         self.push_to, self.pushed = req.push_to, req.pushed
         self.adapter = req.adapter

@@ -102,6 +102,7 @@ import time
 
 from mlapi_tpu.serving import faults
 from mlapi_tpu.utils.logging import get_logger
+from mlapi_tpu.utils.metrics import span
 
 _log = get_logger("serving.scheduler")
 
@@ -253,10 +254,14 @@ class UnitScheduler:
         # a window of picks instead of sorting per dispatched unit.
         self._summary_cache = None
         self._summary_seq = -1000
-        # Bounded unit trace (lane_id, kind) — the counters-derived
-        # interleaving evidence the tests (and post-mortems) read;
-        # never wall-clock.
+        # Bounded unit trace (lane_id, kind, start_ns, end_ns) — the
+        # in-memory log of the ``sched.unit`` spans: the interleaving
+        # evidence the tests (and post-mortems) read from its first
+        # two fields, and each unit's ``perf_counter_ns`` interval.
         self.trace: collections.deque = collections.deque(maxlen=2048)
+        # Where the dispatch thread's spans are summed (``/metrics``
+        # exports them as ``generate.sched_unit_<kind>_us`` etc.).
+        self._sums = eng.latency.sums
         self._thread = threading.Thread(
             target=self._loop, name="unitsched", daemon=True
         )
@@ -380,7 +385,9 @@ class UnitScheduler:
                     and not self._pending
                     and not self._score
                 ):
-                    self._work.wait(timeout=0.1)
+                    with span("sched.idle", "sched_idle",
+                              registry=self._sums):
+                        self._work.wait(timeout=0.1)
                 if self._stopped:
                     break
             try:
@@ -396,7 +403,9 @@ class UnitScheduler:
                     elif not started:
                         # Pending work blocked on the page budget with
                         # every lane idle-free: wait for a release tick.
-                        time.sleep(0.002)
+                        with span("sched.idle", "sched_idle",
+                                  registry=self._sums):
+                            time.sleep(0.002)
             except BaseException:  # noqa: BLE001 — scheduler must survive
                 _log.exception("unit scheduler internal error")
                 time.sleep(0.01)
@@ -574,8 +583,11 @@ class UnitScheduler:
         with self._lock:
             n_live = len(self._lanes)
         try:
-            faults.fire("sched_unit")
-            su.fn()
+            with span("sched.unit", "sched_unit_score",
+                      registry=self._sums, kind="score", lane=0,
+                      rows=su.n_rows) as sp:
+                faults.fire("sched_unit")
+                su.fn()
         except BaseException as e:  # noqa: BLE001 — unit-scoped failure
             _log.error("score unit of %d rows failed: %s", su.n_rows, e)
             try:
@@ -583,7 +595,7 @@ class UnitScheduler:
             except BaseException:
                 _log.exception("score-unit fail delivery failed")
         eng.sched_units_score += 1
-        self.trace.append((0, "score"))
+        self._log_unit(0, "score", sp)
         # Score units count as one extra live party: consecutive
         # score dispatches while lanes wait (and vice versa) feed the
         # same streak gauge.
@@ -863,9 +875,19 @@ class UnitScheduler:
         unit remains. Failures deliver to every waiter, scoped to
         this group — other lanes stream on."""
         eng, reqs = self.eng, g.reqs
+        now = time.perf_counter()
+        for r in reqs:
+            r.t_claim = now  # queue wait ends, prefill wait begins
         try:
-            faults.fire("sched_unit")
-            run = eng._form_batch(reqs, admit=True)
+            # ``_lane_seq`` moves on this thread only, so the id the
+            # lane will get is known before it exists.
+            with span("sched.unit", registry=self._sums, kind="prefill",
+                      lane=self._lane_seq + 1, rows=len(reqs),
+                      rid=",".join(str(r.rid) for r in reqs)) as sp:
+                faults.fire("sched_unit")
+                run = eng._form_batch(reqs, admit=True)
+                if run is not None:
+                    sp.counter = "sched_unit_prefill"
             if run is None:
                 return  # everyone expired before formation
         except BaseException as e:  # noqa: BLE001 — delivered to waiters
@@ -902,10 +924,15 @@ class UnitScheduler:
             )
             self._lanes.append(lane)
             live = len(self._lanes)
-        self.trace.append((lane.lane_id, "prefill"))
+        self._log_unit(lane.lane_id, "prefill", sp)
         self._note_dispatch(lane.lane_id, live)
         if live > eng.sched_batches_live_max:
             eng.sched_batches_live_max = live
+
+    def _log_unit(self, lane_id: int, kind: str, sp) -> None:
+        self.trace.append(
+            (lane_id, kind, sp.start_ns, sp.start_ns + sp.elapsed_ns)
+        )
 
     def _note_dispatch(self, lane_id: int, n_live: int) -> None:
         """Head-of-line accounting, counters not wall-clock: the
@@ -966,7 +993,24 @@ class UnitScheduler:
             # poison every surviving lane).
             self._rebind_pool(lane)
             faults.fire("sched_unit")
-            kind = next(lane.gen)
+            # The kind is the generator's to say, after the work: the
+            # span learns its counter and its ``kind`` on the way out.
+            # The last ``next`` (StopIteration: the final drain and
+            # the cleanup) is no unit of UNIT_KINDS; its time counts
+            # as ``retire`` so the thread's account has no hole.
+            with span("sched.unit", "sched_unit_retire",
+                      registry=self._sums, lane=lane.lane_id,
+                      rows=len(run.reqs)) as sp:
+                try:
+                    kind = next(lane.gen)
+                except StopIteration:
+                    sp.set(kind="retire")
+                    raise
+                sp.counter = f"sched_unit_{kind}"
+                sp.set(kind=kind)
+                if run.claimed:
+                    sp.set(rid=",".join(map(str, run.claimed)))
+                    run.claimed.clear()
         except StopIteration:
             done = True
         except BaseException as e:  # noqa: BLE001 — lane-scoped failure
@@ -1005,7 +1049,7 @@ class UnitScheduler:
         if kind is not None:
             counter = f"sched_units_{kind}"
             setattr(eng, counter, getattr(eng, counter) + 1)
-            self.trace.append((lane.lane_id, kind))
+            self._log_unit(lane.lane_id, kind, sp)
             with self._lock:
                 n_live = len(self._lanes)
             self._note_dispatch(lane.lane_id, n_live)

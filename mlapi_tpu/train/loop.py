@@ -17,7 +17,6 @@ we fold that into ``weight_decay`` on the mean loss).
 
 from __future__ import annotations
 
-import contextlib
 import functools
 import time
 from dataclasses import dataclass, field
@@ -27,6 +26,17 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
+
+from mlapi_tpu.utils.logging import get_logger
+from mlapi_tpu.utils.metrics import REGISTRY, span
+
+_log = get_logger("train.loop")
+
+# ``fit(profile_dir=)`` traces this many steps, starting this many
+# steps after the first one the call runs: never the compile, and a
+# trace small enough to read.
+PROFILE_SKIP_STEPS = 5
+PROFILE_STEPS = 20
 
 
 @dataclass
@@ -40,6 +50,34 @@ class TrainResult:
     # Loss of the first step this call ran (before any update it made
     # took effect) — with final_loss, "did it learn at all".
     first_loss: float | None = None
+    # The host's milliseconds a step by part, from this call's share
+    # of the registry's ``fit.*`` sums: ``batch`` (``batch_at`` plus
+    # the mesh placement), ``dispatch`` (the ``step_fn`` call, which
+    # holds whatever the runtime makes the host wait for the device),
+    # ``sync`` (every ``float(loss)`` the loop read).
+    host_ms_per_step: dict = field(default_factory=dict)
+
+
+def _host_ms_per_step(before: dict) -> dict:
+    """The registry's ``fit.*`` sums since the snapshot ``before``, as
+    milliseconds a step (``{}`` when no step ran)."""
+    now = REGISTRY.snapshot()["counters"]
+    ran = now.get("fit.step_n", 0) - before.get("fit.step_n", 0)
+    return {
+        part: (
+            now.get(f"fit.{part}_us", 0) - before.get(f"fit.{part}_us", 0)
+        ) / 1e3 / ran
+        for part in ("batch", "dispatch", "sync")
+    } if ran else {}
+
+
+def _stop_trace(loss) -> None:
+    """End ``fit(profile_dir=)``'s trace once the device has finished
+    the traced steps (the last loss is ready)."""
+    try:
+        jax.block_until_ready(loss)
+    finally:
+        jax.profiler.stop_trace()
 
 
 def make_train_step(
@@ -431,8 +469,20 @@ def fit(
     the tensorstore I/O; at most one save is in flight, and a failed
     save surfaces on the next save point (or at the end of the run).
 
-    ``profile_dir`` wraps the whole loop in a ``jax.profiler.trace``
-    (view with TensorBoard/XProf).
+    ``profile_dir`` takes a ``jax.profiler`` trace of
+    ``PROFILE_STEPS`` (20) steps, starting ``PROFILE_SKIP_STEPS`` (5)
+    steps after the first step this call runs, so never the compile;
+    the device is drained before the trace starts and before it stops,
+    so it holds exactly those steps' executions. A shorter run traces
+    what is left after the skipped steps (view with TensorBoard/XProf,
+    or read with ``jax.profiler.ProfileData``).
+
+    The loop's own clock: every step runs inside ``span("fit.step",
+    step_num=i)`` with ``fit.batch``, ``fit.dispatch``, ``fit.sync``,
+    ``fit.eval`` and ``fit.checkpoint`` nested where that work happens
+    (``utils/metrics.py``): host spans in any profiler trace, and
+    ``<name>_us`` / ``<name>_n`` sums in the process-wide ``REGISTRY``
+    always, kept when the loop leaves by an exception.
     """
     from mlapi_tpu.parallel import (
         model_on_mesh,
@@ -625,31 +675,51 @@ def fit(
         idx = np.random.default_rng((seed, i)).choice(n, size=batch_size, replace=False)
         return x_all[idx], y_all[idx]
 
-    profiler_cm = (
-        jax.profiler.trace(profile_dir) if profile_dir
-        else contextlib.nullcontext()
-    )
+    def read_loss() -> float:
+        """The loss on the host, inside the loop: where a step waits
+        for the device."""
+        with span("fit.sync", "fit.sync"):
+            return float(loss)
+
+    sums0 = REGISTRY.snapshot()["counters"]
+    trace_from = start_step + PROFILE_SKIP_STEPS if profile_dir else -1
+    tracing = False
+    if profile_dir and steps <= trace_from:
+        _log.warning(
+            "profile_dir: nothing traced, the run ends within the %d "
+            "skipped steps", PROFILE_SKIP_STEPS,
+        )
     t0 = time.perf_counter()
     history: list[dict] = []
     loss = float("nan")
     first_loss = None
     try:
-        with profiler_cm:
-            for i in range(start_step, steps):
-                x, y = batch_at(i)
-                if mesh is not None:
-                    x, y = shard_batch_for_mesh((x, y), mesh)
-                params, opt_state, loss = step_fn(params, opt_state, x, y)
+        for i in range(start_step, steps):
+            if i == trace_from:
+                jax.block_until_ready(loss)  # earlier steps stay out
+                jax.profiler.start_trace(profile_dir)
+                tracing = True
+            with span("fit.step", "fit.step", step_num=i):
+                with span("fit.batch", "fit.batch"):
+                    x, y = batch_at(i)
+                    if mesh is not None:
+                        x, y = shard_batch_for_mesh((x, y), mesh)
+                with span("fit.dispatch", "fit.dispatch"):
+                    params, opt_state, loss = step_fn(
+                        params, opt_state, x, y
+                    )
                 if first_loss is None:
                     first_loss = loss  # device scalar; read after the loop
                 if eval_every and (i + 1) % eval_every == 0:
-                    if not np.isfinite(float(loss)):
+                    loss_now = read_loss()
+                    if not np.isfinite(loss_now):
                         raise FloatingPointError(
-                            f"non-finite loss {float(loss)} at step {i + 1}"
+                            f"non-finite loss {loss_now} at step {i + 1}"
                         )
-                    acc = eval_fn(params)
+                    with span("fit.eval", "fit.eval"):
+                        acc = eval_fn(params)
                     history.append(
-                        {"step": i + 1, "loss": float(loss),
+                        {"step": i + 1, "loss": loss_now,
                          "test_accuracy": acc}
                     )
                 if (
@@ -658,54 +728,74 @@ def fit(
                     and (i + 1) % save_every == 0
                     and (i + 1) < steps
                 ):
-                    if not np.isfinite(float(loss)):
+                    loss_now = read_loss()
+                    if not np.isfinite(loss_now):
                         raise FloatingPointError(
                             f"refusing to checkpoint non-finite loss "
-                            f"{float(loss)} at step {i + 1}"
+                            f"{loss_now} at step {i + 1}"
                         )
                     # The opt_state pytree is stored AS-IS: converting
                     # the top level to a list would strip namedtuple
                     # types (optax.multi_transform's state is one) and
                     # break the restore-side structure match.
                     state = {"params": params, "opt_state": opt_state}
-                    if save_pool is not None:
-                        if pending_save is not None:
-                            pending_save.result()  # one in flight; fail loud
-                        # Host copy NOW (the next step donates these
-                        # device buffers); disk write overlaps training.
-                        host_state = jax.device_get(state)
-                        pending_save = save_pool.submit(
-                            _save_train_state, checkpoint_dir, host_state,
-                            i + 1, run_config, keep_last,
-                        )
-                    else:
-                        _save_train_state(
-                            checkpoint_dir, state, i + 1, run_config,
-                            keep_last,
-                        )
+                    with span("fit.checkpoint", "fit.checkpoint"):
+                        if save_pool is not None:
+                            if pending_save is not None:
+                                # one in flight; fail loud
+                                pending_save.result()
+                            # Host copy NOW (the next step donates these
+                            # device buffers); disk write overlaps
+                            # training.
+                            host_state = jax.device_get(state)
+                            pending_save = save_pool.submit(
+                                _save_train_state, checkpoint_dir,
+                                host_state, i + 1, run_config, keep_last,
+                            )
+                        else:
+                            _save_train_state(
+                                checkpoint_dir, state, i + 1, run_config,
+                                keep_last,
+                            )
+            if tracing and i + 1 == trace_from + PROFILE_STEPS:
+                tracing = False
+                _stop_trace(loss)
     finally:
-        # Join the in-flight save even when the loop raises — a failed
-        # background save must never be silently dropped (if both
-        # failed, the loop's exception stays chained as __context__).
-        if save_pool is not None:
-            try:
-                if pending_save is not None:
-                    pending_save.result()
-            finally:
-                save_pool.shutdown(wait=True)
+        try:
+            if tracing:
+                # A run (or an exception) that ended inside the traced
+                # steps: what ran is in the trace.
+                _stop_trace(loss)
+        finally:
+            # Join the in-flight save even when the loop raises — a
+            # failed background save must never be silently dropped (if
+            # both failed, the loop's exception stays chained as
+            # __context__).
+            if save_pool is not None:
+                try:
+                    if pending_save is not None:
+                        pending_save.result()
+                finally:
+                    save_pool.shutdown(wait=True)
     wall = time.perf_counter() - t0
-    if steps > start_step and not np.isfinite(float(loss)):
+    final_loss = float(loss)  # after the last step: no span of the loop's
+    if steps > start_step and not np.isfinite(final_loss):
         raise FloatingPointError(
-            f"training ended with non-finite loss {float(loss)}"
+            f"training ended with non-finite loss {final_loss}"
         )
 
-    test_acc = eval_fn(params) if len(splits.x_test) else None
+    if len(splits.x_test):
+        with span("fit.eval", "fit.eval"):
+            test_acc = eval_fn(params)
+    else:
+        test_acc = None
     return TrainResult(
         params=params,
-        final_loss=float(loss),
+        final_loss=final_loss,
         test_accuracy=test_acc,
         steps=steps,
         wall_seconds=wall,
         history=history,
         first_loss=None if first_loss is None else float(first_loss),
+        host_ms_per_step=_host_ms_per_step(sums0),
     )

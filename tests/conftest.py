@@ -173,6 +173,8 @@ _CACHE_FAMILIES = {
     # family's prefill/decode programs unchanged (score units change
     # dispatch ORDER, never shapes), and the scoring fast path's
     # padded-shape jit programs are tiny tabular predicts.
+    # + the span-serving module (PR 27): same CFG and shapes once
+    # more — timing the units compiles nothing.
     "paged-family": frozenset({
         "test_serving_fused",
         "test_kv_peer",
@@ -183,6 +185,7 @@ _CACHE_FAMILIES = {
         "test_paged_kv",
         "test_paged_kv_tier",
         "test_scheduler",
+        "test_span_serving",
     }),
 }
 _last_cache_group = [None]

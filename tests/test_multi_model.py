@@ -295,7 +295,7 @@ async def test_score_units_interleave_with_decode(gpt_params):
         assert eng.sched_units_score == 5
         # ... and scoring never starved decode: decode units kept
         # dispatching after the first score unit.
-        kinds = [k for _, k in eng.sched.trace]
+        kinds = [k for _, k, _, _ in eng.sched.trace]
         first_score = kinds.index("score")
         assert "decode" in kinds[first_score + 1:]
         assert eng.sched_units_decode > decode_before

@@ -183,12 +183,19 @@ async def test_two_incompatible_groups_interleave(gpt_params):
                 # the trace must switch lanes mid-stream (an A,B,A
                 # pattern), not run serially.
                 assert eng.sched_batches_live_max == 2
-                lanes = [lane for lane, kind in eng.sched.trace]
+                lanes = [lane for lane, kind, t0, t1 in eng.sched.trace]
                 switches = sum(
                     1 for i in range(1, len(lanes))
                     if lanes[i] != lanes[i - 1]
                 )
                 assert switches >= 2, lanes
+                # The widened entries: each unit's interval on the
+                # dispatch thread's clock, in dispatch order.
+                spans = [(t0, t1) for _, _, t0, t1 in eng.sched.trace]
+                assert all(t0 <= t1 for t0, t1 in spans)
+                assert all(
+                    a[1] <= b[0] for a, b in zip(spans, spans[1:])
+                ), "one dispatch thread: units never overlap"
                 # Unit counters moved for both types of work.
                 assert eng.sched_units_decode >= (
                     _SHORT[1] // eng.chunk + _LONG[1] // eng.chunk
