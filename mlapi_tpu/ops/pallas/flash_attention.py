@@ -11,9 +11,34 @@ probabilities tile-by-tile from the saved log-sum-exp instead of
 storing them. HBM sees Q/K/V/O (+ per-row LSE) only, in both
 directions — no ``[L, L]`` tensor in the compiled HLO.
 
-Grid layout (TPU: the grid is iterated sequentially, last dimension
-innermost; VMEM scratch persists across grid steps, which is what
-carries the online-softmax state between K tiles):
+Two sets of kernels, chosen from the shapes at trace time (no flag):
+
+**One-tile sequences** (both sequences fit one block after
+``_fit_block``: every L up to the 512 default, BERT's and GPT-2's
+training lengths among them). There is no recurrence to carry, so one
+grid step takes ALL the heads of a batch row (``_row_heads``: as many
+as a VMEM budget allows, in whole GQA groups) and does a plain softmax
+per head in registers — grid ``(B, H/hb)``, no scratch. Operands stay
+in the model's own layout: ``[B, L, H, D]`` is read as ``[B, L, H*D]``
+(a bitcast), a head is a static D-wide lane slice, and the wrapper
+transposes nothing. The row statistics (LSE; delta = Σ_d dO·O, made in
+the dq kernel) are ``[B, H, L]`` float32 with L along lanes. Why: on a
+v5e at BERT-base, batch 128 x 128, the streaming kernels below ran
+1,536 grid steps of one ``[128, 64]`` head at 0.64 us each, wrote the
+LSE as ``[B, H, L, 1]`` (a 128-lane tile a number: 100.7 MB for 0.79 MB
+of statistics) and sat between eight full-size layout copies a layer —
+12.5% of the kernels' byte roofline (``PERF.md``, PR 26-28).
+
+- forward ``(B, H/hb)``: scores ``[Lq, Lk]`` a head; the LSE column is
+  laid along lanes off the diagonal.
+- backward dq and dk/dv, each ``(B, H/hb)``: probabilities
+  recomputed TRANSPOSED (``[Lk, Lq]``) so the statistics broadcast from
+  rows and dk/dv are plain products; a kv head's query group sums in
+  registers (GQA-native). Causal and window are a mask inside the tile.
+
+**Longer sequences** stream (TPU: the grid is iterated sequentially,
+last dimension innermost; VMEM scratch persists across grid steps,
+which is what carries the online-softmax state between K tiles):
 
 - forward:   ``(B, H, L/block_q, L/block_k)`` — one q-tile's output
   accumulates across the inner k-steps, written at the last k-step.
@@ -30,15 +55,17 @@ predication), so causal attention does ~half the work.
 Per-program VMEM is a few ``block×block`` f32 tiles (~2-3 MB at the
 default 512/512 blocks — measured 2x faster than 128/128 at L=8192
 on v5e, where the sequential grid's per-step overhead dominates small
-tiles) — inside the ~16 MB budget at any L.
-Longer sequences belong to the sequence-parallel path
+tiles) — inside the ~16 MB budget at any L. These kernels still
+transpose to ``[B, H, L, D]`` and keep their statistics as
+``[B, H, L, 1]`` (the same padding; no cell and no trace measures it).
+Longer sequences still belong to the sequence-parallel path
 (``mlapi_tpu.ops.ring_attention``).
 
 Layout convention matches ``mlapi_tpu.ops.attention``: ``q, k, v``
 are ``[B, L, H, D]``, ``mask`` is binary ``[B, L]`` over keys; fully
 masked query rows return zeros (all three attention impls agree).
-Grouped-query attention is native on the forward: ``k``/``v`` may
-carry ``H / group`` heads and the kv BlockSpec indexes ``hi //
+Grouped-query attention is native in both passes: ``k``/``v`` may
+carry ``H / group`` heads and the kernels index kv head ``h //
 group`` — the repeated K/V tensor never exists in HBM.
 Matmuls run native-dtype inputs with f32 accumulation on the MXU.
 """
@@ -52,6 +79,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from mlapi_tpu.utils.metrics import REGISTRY
 
 # Python float (not a jax scalar — kernels may not capture traced
 # constants); same finite large-negative as mlapi_tpu.ops.attention.NEG.
@@ -263,8 +292,287 @@ def _out_struct(shape, dtype, like):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
+# -- one-tile sequences (module docstring) -------------------------------
+
+# Working set one grid step may claim, and the scoped-VMEM limit handed
+# to Mosaic for it (a v5e core has 128 MiB; the default limit of 16 MiB
+# is a compiler default, not the chip's size).
+_ROW_VMEM_BUDGET = 40 << 20
+_ROW_VMEM_LIMIT = 48 << 20
+
+
+def _row_vmem_bytes(hb, kvb, lq, lk, d, itemsize):
+    """VMEM one grid step of the heaviest one-tile kernel (dk/dv)
+    needs: its double-buffered blocks (q, do; k, v, dk, dv; LSE, delta,
+    mask) plus the float32 score-sized tiles and accumulators that are
+    live while one head is worked on."""
+    blocks = (
+        itemsize * (2 * lq * hb * d + 4 * lk * kvb * d)
+        + 4 * (2 * hb * lq + lk)
+    )
+    return 2 * blocks + 4 * (8 * lq * lk + 2 * lk * d)
+
+
+def _row_heads(h, kvh, lq, lk, d, itemsize):
+    """Query heads per grid step of the one-tile kernels: the largest
+    divisor of ``h`` that keeps whole GQA groups together, that Mosaic
+    can block (all of H, or a multiple of 8 whose q and kv lane widths
+    are multiples of 128), and whose working set fits
+    ``_ROW_VMEM_BUDGET``. 0: none does, the streaming kernels run."""
+    group = h // kvh
+    for hb in range(h, 0, -1):
+        if h % hb or hb % group:
+            continue
+        kvb = hb // group
+        if hb != h and (hb % 8 or (hb * d) % 128 or (kvb * d) % 128):
+            continue
+        if _row_vmem_bytes(hb, kvb, lq, lk, d, itemsize) <= _ROW_VMEM_BUDGET:
+            return hb
+    return 0
+
+
+def _one_tile_heads(q, k, block_q, block_k):
+    """``_row_heads`` for these operands when the (fitted) blocks cover
+    both sequences whole, else 0. From shapes alone, so the forward,
+    the backward and the counter agree."""
+    _, lq, h, d = q.shape
+    _, lk, kvh, _ = k.shape
+    if lq != block_q or lk != block_k:
+        return 0
+    return _row_heads(h, kvh, lq, lk, d, q.dtype.itemsize)
+
+
+def _head(ref, i, d):
+    """Head ``i``'s ``[L, D]`` window of a ``(1, L, heads*D)`` block."""
+    return ref[0, :, i * d:(i + 1) * d]
+
+
+def _nt(a, b):
+    """``a @ b.T`` with float32 accumulation."""
+    return jax.lax.dot_general(
+        a, b, dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+
+
+def _eye(n):
+    return (jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+            == jax.lax.broadcasted_iota(jnp.int32, (n, n), 1))
+
+
+def _lanes(col, eye):
+    """A ``[n, 1]`` column laid along lanes as ``[1, n]``: picked off
+    the diagonal and summed over sublanes (exact: one term a lane), so
+    HBM holds n numbers a head and not n padded tiles."""
+    return jnp.sum(jnp.where(eye, col, 0.0), axis=0, keepdims=True)
+
+
+def _fwd_rows_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, *,
+                     scale, causal, window, hb, group, d):
+    lq, lk = q_ref.shape[1], k_ref.shape[1]
+    # The mask is the batch row's: made once, shared by every head.
+    keep = _keep_tile(mask_ref, causal, 0, 0, lq, lk, (lq, lk), window)
+    bias = (1.0 - keep) * _NEG
+    eye = _eye(lq)
+    outs = []
+    for i in range(hb):
+        q, k, v = _head(q_ref, i, d), _head(k_ref, i // group, d), _head(
+            v_ref, i // group, d)
+        s = _nt(q, k) * scale + bias                   # [lq, lk]
+        m = jnp.max(s, axis=-1, keepdims=True)
+        # exp(NEG - NEG) == 1 on rows with no valid key; * keep zeroes
+        # them so fully-masked rows come out 0, not NaN.
+        p = jnp.exp(s - m) * keep
+        l = jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
+        o = jax.lax.dot_general(
+            p.astype(v.dtype), v,
+            dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        outs.append((o * (1.0 / l)).astype(o_ref.dtype))
+        lse_ref[0, i:i + 1, :] = _lanes(m + jnp.log(l), eye)
+    # One full-width store: a head's D lanes written alone start
+    # mid-tile for every other head at D = 64 (0.42 against 0.33 ms a
+    # call at the benchmark cell's shapes on a v5e, PR 28).
+    o_ref[0] = jnp.concatenate(outs, axis=-1)
+
+
+def _keep_tile_t(mask_ref, causal, lq, lk, window):
+    """``_keep_tile`` of the one tile, transposed: ``[lk, lq]`` (or a
+    ``[lk, 1]`` column when nothing depends on the query)."""
+    row = mask_ref[0, 0][None, :].astype(jnp.float32)        # [1, lk]
+    keep = jnp.sum(jnp.where(_eye(lk), row, 0.0), axis=1, keepdims=True)
+    if causal:
+        k_pos = jax.lax.broadcasted_iota(jnp.int32, (lk, lq), 0)
+        q_pos = jax.lax.broadcasted_iota(jnp.int32, (lk, lq), 1)
+        keep = keep * (q_pos >= k_pos)
+        if window is not None:
+            keep = keep * (q_pos - k_pos < window)
+    return keep
+
+
+def _p_ds_t(q, k, v, do, lse, delta, keep, bias, scale):
+    """Recompute one head's probabilities and score gradients from the
+    saved statistics, TRANSPOSED (``[lk, lq]``): the statistics are
+    ``[1, lq]`` rows along lanes, as HBM holds them, and dv/dk come out
+    of plain products. Masked lanes give exp(NEG - lse), large but
+    finite (lse >= NEG + log(eps)); * keep zeroes them."""
+    p = jnp.exp(_nt(k, q) * scale + bias - lse) * keep
+    ds = p * (_nt(v, do) - delta) * scale
+    return p, ds
+
+
+def _bwd_dq_rows_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, o_ref,
+                        lse_ref, g_lse_ref, dq_ref, delta_ref, *, scale,
+                        causal, window, hb, group, d):
+    """dq, and delta_i = Σ_d dO_i · O_i for the dk/dv kernel to read:
+    made here from the tiles already in VMEM, so XLA re-lays no
+    float32 ``[B, L, H*D]`` product for a reduction over D. A cotangent
+    on the LSE folds in exactly: ∂lse_i/∂s_ij = p_ij, so
+    ds_ij = p_ij·(dp_ij - (delta_i - g_lse_i))·scale."""
+    lq, lk = q_ref.shape[1], k_ref.shape[1]
+    keep = _keep_tile_t(mask_ref, causal, lq, lk, window)
+    bias = (1.0 - keep) * _NEG
+    eye = _eye(lq)
+    for i in range(hb):
+        k, do = _head(k_ref, i // group, d), _head(do_ref, i, d)
+        delta = _lanes(
+            jnp.sum(
+                do.astype(jnp.float32)
+                * _head(o_ref, i, d).astype(jnp.float32),
+                axis=-1, keepdims=True,
+            ),
+            eye,
+        ) - g_lse_ref[0, i:i + 1, :]
+        delta_ref[0, i:i + 1, :] = delta
+        _, ds = _p_ds_t(
+            _head(q_ref, i, d), k, _head(v_ref, i // group, d), do,
+            lse_ref[0, i:i + 1, :], delta, keep, bias, scale,
+        )
+        # dq = ds . k, contracting the k dim of the transposed tile.
+        dq_ref[0, :, i * d:(i + 1) * d] = jax.lax.dot_general(
+            ds.astype(k.dtype), k,
+            dimension_numbers=(((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ).astype(dq_ref.dtype)
+
+
+def _bwd_dkv_rows_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref,
+                         delta_ref, dk_ref, dv_ref, *, scale, causal,
+                         window, hb, group, d):
+    """dk/dv for the ``hb // group`` kv heads of the step: each sums
+    over its ``group`` query heads in registers (GQA-native, no
+    repeated K/V)."""
+    lq, lk = q_ref.shape[1], k_ref.shape[1]
+    keep = _keep_tile_t(mask_ref, causal, lq, lk, window)
+    bias = (1.0 - keep) * _NEG
+    for j in range(hb // group):
+        k, v = _head(k_ref, j, d), _head(v_ref, j, d)
+        dk = jnp.zeros((lk, d), jnp.float32)
+        dv = jnp.zeros((lk, d), jnp.float32)
+        for i in range(j * group, (j + 1) * group):
+            q, do = _head(q_ref, i, d), _head(do_ref, i, d)
+            p, ds = _p_ds_t(
+                q, k, v, do, lse_ref[0, i:i + 1, :],
+                delta_ref[0, i:i + 1, :], keep, bias, scale,
+            )
+            dv = dv + jnp.dot(p.astype(do.dtype), do,
+                              preferred_element_type=jnp.float32)
+            dk = dk + jnp.dot(ds.astype(q.dtype), q,
+                              preferred_element_type=jnp.float32)
+        dk_ref[0, :, j * d:(j + 1) * d] = dk.astype(dk_ref.dtype)
+        dv_ref[0, :, j * d:(j + 1) * d] = dv.astype(dv_ref.dtype)
+
+
+def _row_specs(q, k, hb):
+    """Block specs of the one-tile kernels over grid ``(B, H/hb)``: q
+    and kv lane slabs (``hb`` query heads and their kv heads), the
+    mask row, the statistics rows."""
+    _, lq, h, d = q.shape
+    _, lk, kvh, _ = k.shape
+    kvb = hb * kvh // h
+    return (
+        pl.BlockSpec((1, lq, hb * d), lambda bi, hi: (bi, 0, hi)),
+        pl.BlockSpec((1, lk, kvb * d), lambda bi, hi: (bi, 0, hi)),
+        pl.BlockSpec((1, 1, lk), lambda bi, hi: (bi, 0, 0)),
+        pl.BlockSpec((1, hb, lq), lambda bi, hi: (bi, hi, 0)),
+    )
+
+
+def _row_call(kernel, hb, q, k, interpret, out_shape, out_specs, in_specs,
+              **static):
+    """The ``pallas_call`` of one one-tile kernel: grid ``(B, H/hb)``,
+    ``hb`` query heads (and their kv heads) a step."""
+    b, _, h, d = q.shape
+    return pl.pallas_call(
+        functools.partial(
+            kernel, hb=hb, group=h // k.shape[2], d=d, **static
+        ),
+        grid=(b, h // hb),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_ROW_VMEM_LIMIT
+        ),
+        interpret=interpret,
+    )
+
+
+def _fwd_rows(q, k, v, mask, causal, scale, interpret, window, hb):
+    b, lq, h, _ = q.shape
+    q_spec, kv_spec, mask_spec, stat_spec = _row_specs(q, k, hb)
+    q3, k3, v3 = (x.reshape(*x.shape[:2], -1) for x in (q, k, v))
+    out, lse = _row_call(
+        _fwd_rows_kernel, hb, q, k, interpret,
+        out_shape=[
+            _out_struct(q3.shape, q.dtype, q),
+            _out_struct((b, h, lq), jnp.float32, q),
+        ],
+        out_specs=[q_spec, stat_spec],
+        in_specs=[q_spec, kv_spec, kv_spec, mask_spec],
+        scale=scale, causal=causal, window=window,
+    )(q3, k3, v3, mask.astype(jnp.float32)[:, None, :])
+    return out.reshape(q.shape), lse
+
+
+def _bwd_rows(q, k, v, mask, out, lse, g, g_lse, causal, scale, interpret,
+              window, hb):
+    b, lq, h, _ = q.shape
+    q_spec, kv_spec, mask_spec, stat_spec = _row_specs(q, k, hb)
+    q3, k3, v3, g3, o3 = (
+        x.reshape(*x.shape[:2], -1) for x in (q, k, v, g, out)
+    )
+    mask3 = mask.astype(jnp.float32)[:, None, :]
+    stat = _out_struct((b, h, lq), jnp.float32, q)
+    static = dict(scale=scale, causal=causal, window=window)
+    dq, delta = _row_call(
+        _bwd_dq_rows_kernel, hb, q, k, interpret,
+        out_shape=[_out_struct(q3.shape, q.dtype, q), stat],
+        out_specs=[q_spec, stat_spec],
+        in_specs=[q_spec, kv_spec, kv_spec, mask_spec, q_spec, q_spec,
+                  stat_spec, stat_spec],
+        **static,
+    )(q3, k3, v3, mask3, g3, o3, lse, g_lse.astype(jnp.float32))
+    dk, dv = _row_call(
+        _bwd_dkv_rows_kernel, hb, q, k, interpret,
+        out_shape=[
+            _out_struct(k3.shape, k.dtype, q),
+            _out_struct(v3.shape, v.dtype, q),
+        ],
+        out_specs=[kv_spec, kv_spec],
+        in_specs=[q_spec, kv_spec, kv_spec, mask_spec, q_spec, stat_spec,
+                  stat_spec],
+        **static,
+    )(q3, k3, v3, mask3, g3, lse, delta)
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
+
+
 def _fwd(q, k, v, mask, causal, scale, block_q, block_k, interpret,
          window=None):
+    hb = _one_tile_heads(q, k, block_q, block_k)
+    if hb:
+        return _fwd_rows(q, k, v, mask, causal, scale, interpret, window, hb)
     b, lq, h, d = q.shape
     lk = k.shape[1]
     # GQA: k/v may carry fewer heads than q (validated in _prepare);
@@ -498,6 +806,12 @@ def _bwd_dkv_kernel(
 
 def _bwd(q, k, v, mask, out, lse, g, causal, scale, block_q, block_k,
          interpret, g_lse=None, window=None):
+    hb = _one_tile_heads(q, k, block_q, block_k)
+    if hb:
+        return _bwd_rows(
+            q, k, v, mask, out, lse, g, g_lse, causal, scale, interpret,
+            window, hb,
+        )
     b, lq, h, d = q.shape
     lk = k.shape[1]
     kvh = k.shape[2]
@@ -667,6 +981,21 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, window, res, g):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+def _counted_flash(q, k, v, mask, causal, scale, block_q, block_k,
+                   interpret, window):
+    """``_flash`` behind the two entry points, counted where the
+    blocking is chosen: once a TRACE in ``utils.metrics.REGISTRY``
+    (``flash.calls_traced``; ``flash.calls_row_blocked`` when the
+    one-tile kernels take the call). Nothing is counted per step."""
+    REGISTRY.counter("flash.calls_traced").inc()
+    if _one_tile_heads(q, k, block_q, block_k):
+        REGISTRY.counter("flash.calls_row_blocked").inc()
+    return _flash(
+        q, k, v, mask.astype(jnp.float32), causal, scale, block_q, block_k,
+        interpret, window,
+    )
+
+
 def _fit_block(requested: int, length: int) -> int:
     b = min(requested, length)
     while length % b:
@@ -734,20 +1063,23 @@ def flash_attention(
     ``mask``: optional binary ``[B, L]`` over keys. Returns
     ``[B, L, H, D]`` in ``q.dtype``.
 
-    Differentiable end to end in Pallas: the forward streams K/V in
-    ``block_k`` tiles with the online-softmax recurrence and saves the
-    per-row log-sum-exp; the backward recomputes probability tiles
-    from it and accumulates dq (k-inner grid) and dk/dv (q-inner
-    grid) — no ``[L, L]`` tensor in HBM in either pass.
+    Differentiable end to end in Pallas: the forward saves the per-row
+    log-sum-exp and the backward recomputes probability tiles from it
+    (dq, then dk/dv) — no ``[L, L]`` tensor in HBM in either pass. A
+    sequence that is one tile takes the row-blocked kernels, a longer
+    one streams K/V in ``block_k`` tiles with the online-softmax
+    recurrence (module docstring).
     ``interpret=True`` runs the Pallas interpreter (CPU testing).
 
     Int8-KV policy (the three-way split, see
     ``ops/quant.maybe_dequant_kv``): quantized ``{"q", "scale"}`` K/V
     operands dequantize AT THIS BOUNDARY (one fused convert+multiply
-    feeding the kernel's first tile load) — full-sequence
-    prefill/training shapes are MXU-bound, so the byte format of the
-    operand read is not the lever here. The DECODE read, which IS
-    bandwidth-bound, runs as its own kernel
+    feeding the kernel's first tile load): a full-sequence call reads
+    K/V once for L queries' worth of work (at short L it is bound by
+    bytes all the same — 12.5% of that roofline before PR 28 — but by
+    q, k, v, o and the cotangents alike, so the K/V byte format is
+    not the lever here). The DECODE read, which re-reads the whole
+    cache for one token a step, runs as its own kernel
     (``ops/pallas/decode_attention``) that DMAs int8 payload+scale
     tiles to VMEM and dequantizes per tile in registers; the einsum
     decode path dequantizes at the read seam (``kv_cache_kv``).
@@ -762,9 +1094,8 @@ def flash_attention(
     if interpret and _inside_vma_shard_map(q):
         out, _ = _jnp_flash(q, k, v, mask, causal, scale, window)
         return out
-    out, _ = _flash(
-        q, k, v, mask.astype(jnp.float32), causal, scale, block_q, block_k,
-        interpret, window,
+    out, _ = _counted_flash(
+        q, k, v, mask, causal, scale, block_q, block_k, interpret, window
     )
     return out
 
@@ -832,8 +1163,8 @@ def flash_attention_with_lse(
     weighted average). Used by ``ring_attention``'s flash block mode;
     differentiable through BOTH outputs. Same int8-KV policy as
     :func:`flash_attention`: quantized K/V pairs dequantize at entry
-    (full-sequence shapes are MXU-bound; the in-kernel int8 tile path
-    belongs to the decode kernel, ``decode_attention``)."""
+    (the in-kernel int8 tile path belongs to the decode kernel,
+    ``decode_attention``)."""
     from mlapi_tpu.ops.quant import maybe_dequant_kv
 
     k = maybe_dequant_kv(k, q.dtype)
@@ -843,7 +1174,6 @@ def flash_attention_with_lse(
     )
     if interpret and _inside_vma_shard_map(q):
         return _jnp_flash(q, k, v, mask, causal, scale, window)
-    return _flash(
-        q, k, v, mask.astype(jnp.float32), causal, scale, block_q, block_k,
-        interpret, window,
+    return _counted_flash(
+        q, k, v, mask, causal, scale, block_q, block_k, interpret, window
     )

@@ -446,3 +446,197 @@ def test_on_mesh_matches_the_plain_call(shape):
     ):
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
     assert flash_attention_on_mesh(None, q, k, v, mask, interpret=True).shape == q.shape
+
+
+# -- one-tile sequences: the row-blocked kernels ------------------------
+# (a batch row's heads in one grid step, statistics [B, H, L] along
+# lanes, operands in the model's own [B, L, H*D] layout)
+
+def _oracle(q, k, v, mask, causal=False, window=None):
+    """Plain float32 masked softmax attention, differentiable; K/V
+    heads repeated for GQA; rows with no key to attend come out 0."""
+    group = q.shape[2] // k.shape[2]
+    k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
+    lq, lk = q.shape[1], k.shape[1]
+    keep = jnp.broadcast_to(mask[:, None, None, :] > 0, (q.shape[0], 1, lq, lk))
+    if causal:
+        dist = jnp.arange(lq)[:, None] - jnp.arange(lk)[None, :]
+        keep = keep & (dist >= 0) & (dist < (window or lk))
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / q.shape[-1] ** 0.5
+    s = jnp.where(keep, s, -1e30)
+    p = jnp.exp(s - s.max(-1, keepdims=True)) * keep
+    p = p / jnp.maximum(p.sum(-1, keepdims=True), 1e-30)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+
+# name: (B, L, H, KVH, D, lengths, causal, window)
+ONE_TILE = {
+    "padded-with-a-fully-masked-row": (3, 32, 4, 4, 16, [32, 9, 0], False, None),
+    "causal-d64": (1, 128, 2, 2, 64, [128], True, None),
+    "causal-window": (2, 128, 2, 2, 16, [128, 77], True, 24),
+    "gqa-8-over-2": (2, 32, 8, 2, 16, [30, 17], True, None),
+    "three-local-heads-of-a-tp-shard": (2, 128, 3, 3, 64, [128, 50], False, None),
+    "l512-padded-causal": (1, 512, 2, 2, 16, [400], True, None),
+    "l512-gqa-d64-window": (1, 512, 2, 1, 64, [512], True, 100),
+}
+
+
+def _one_tile_case(name):
+    b, l, h, kvh, d, lengths, causal, window = ONE_TILE[name]
+    ks = jax.random.split(jax.random.key(len(name)), 3)
+    q = jax.random.normal(ks[0], (b, l, h, d))
+    k = jax.random.normal(ks[1], (b, l, kvh, d))
+    v = jax.random.normal(ks[2], (b, l, kvh, d))
+    mask = jnp.asarray(
+        np.arange(l)[None, :] < np.asarray(lengths)[:, None], jnp.float32
+    )
+    kw = dict(causal=causal, window=window)
+    from mlapi_tpu.ops.pallas.flash_attention import _one_tile_heads
+
+    assert _one_tile_heads(q, k, l, l) == h  # the new blocking takes it
+    return (q, k, v), mask, kw
+
+
+@pytest.mark.parametrize("name", list(ONE_TILE))
+def test_one_tile_forward_matches_oracle(name):
+    (q, k, v), mask, kw = _one_tile_case(name)
+    out = flash_attention(q, k, v, mask, interpret=True, **kw)
+    assert np.isfinite(np.asarray(out)).all()
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(_oracle(q, k, v, mask, **kw)), atol=2e-5
+    )
+
+
+@pytest.mark.parametrize("name", list(ONE_TILE))
+def test_one_tile_gradients_match_oracle(name):
+    (q, k, v), mask, kw = _one_tile_case(name)
+    w = jax.random.normal(jax.random.key(99), q.shape)
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v) * w)
+
+    got = jax.grad(loss(lambda q, k, v: flash_attention(
+        q, k, v, mask, interpret=True, **kw)), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(lambda q, k, v: _oracle(q, k, v, mask, **kw)),
+                    argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        assert np.isfinite(np.asarray(a)).all()
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
+
+
+@pytest.mark.parametrize("name", ["gqa-8-over-2", "causal-d64"])
+def test_one_tile_lse_and_its_cotangent_match_jnp_flash(name):
+    """``flash_attention_with_lse`` returns ``[B, H, L]`` equal to the
+    pure-jnp twin's, and a loss on BOTH outputs differentiates the
+    same (the LSE cotangent folds into delta inside the dq kernel):
+    what ring attention's merge trains through. (Cases in which every
+    query sees a key: through a fully masked row's LSE the twin's
+    plain autodiff gives NaN where the kernels give 0.)"""
+    from mlapi_tpu.ops.pallas import flash_attention_with_lse
+    from mlapi_tpu.ops.pallas.flash_attention import _jnp_flash
+
+    (q, k, v), mask, kw = _one_tile_case(name)
+    scale = 1.0 / q.shape[-1] ** 0.5
+
+    def kernel(q, k, v):
+        return flash_attention_with_lse(q, k, v, mask, interpret=True, **kw)
+
+    def twin(q, k, v):
+        return _jnp_flash(q, k, v, mask, kw["causal"], scale, kw["window"])
+
+    out, lse = kernel(q, k, v)
+    ref_out, ref_lse = twin(q, k, v)
+    assert lse.shape == (q.shape[0], q.shape[2], q.shape[1])
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref_out), atol=2e-5)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(ref_lse), atol=2e-5)
+
+    def loss(fn):
+        def f(q, k, v):
+            o, s = fn(q, k, v)
+            return jnp.sum(o ** 2) + jnp.sum(jnp.sin(s))
+        return f
+
+    for a, b in zip(jax.grad(loss(kernel), argnums=(0, 1, 2))(q, k, v),
+                    jax.grad(loss(twin), argnums=(0, 1, 2))(q, k, v)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
+
+
+def _flash_counts():
+    from mlapi_tpu.utils.metrics import REGISTRY
+
+    c = REGISTRY.snapshot()["counters"]
+    return (c.get("flash.calls_traced", 0), c.get("flash.calls_row_blocked", 0))
+
+
+def test_counters_rise_once_per_traced_one_tile_call():
+    """The blocking is chosen at trace time and counted there: one
+    trace, one count, and nothing when the cached program runs
+    again."""
+    q = jax.random.normal(jax.random.key(40), (1, 24, 2, 8))  # a shape of its own
+    traced, blocked = _flash_counts()
+    flash_attention(q, q, q, interpret=True)
+    assert _flash_counts() == (traced + 1, blocked + 1)
+    flash_attention(q, q, q, interpret=True)
+    assert _flash_counts() == (traced + 1, blocked + 1)
+    from mlapi_tpu.ops.pallas import flash_attention_with_lse
+
+    flash_attention_with_lse(q, q, q, interpret=True)
+    assert _flash_counts() == (traced + 2, blocked + 2)
+
+
+def test_multi_tile_sequences_keep_the_streaming_kernels():
+    """L = 1024 at the default 512 blocks is four tiles a head: the
+    call is traced but NOT row-blocked, and the streaming kernels are
+    still right, forward and backward."""
+    ks = jax.random.split(jax.random.key(41), 3)
+    q, k, v = (jax.random.normal(x, (1, 1024, 2, 16)) for x in ks)
+    mask = jnp.asarray(np.arange(1024)[None, :] < 900, jnp.float32)
+    traced, blocked = _flash_counts()
+    out = flash_attention(q, k, v, mask, causal=True, interpret=True)
+    assert _flash_counts() == (traced + 1, blocked)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(_oracle(q, k, v, mask, causal=True)),
+        atol=2e-5,
+    )
+    w = jax.random.normal(jax.random.key(42), q.shape)
+    got = jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, mask, causal=True, interpret=True) * w), argnums=(0, 1, 2)
+    )(q, k, v)
+    want = jax.grad(lambda q, k, v: jnp.sum(
+        _oracle(q, k, v, mask, causal=True) * w), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
+
+
+@pytest.mark.parametrize(
+    "h, kvh, lq, lk, d, itemsize, want",
+    [
+        (12, 12, 128, 128, 64, 2, 12),   # the benchmark cell: all heads
+        (12, 12, 512, 512, 64, 2, 12),   # GPT-2 prefill at one tile
+        (3, 3, 128, 128, 64, 2, 3),      # TP (1,4): the LOCAL heads, whole
+        (8, 2, 256, 256, 64, 2, 8),      # GQA: whole groups
+        (32, 8, 512, 512, 128, 2, 32),   # llama-like, bf16: fits whole
+        (32, 8, 512, 512, 128, 4, 16),   # float32: half, 4 kv heads a step
+        (64, 64, 512, 512, 128, 4, 8),   # only multiples of 8 below H
+        (12, 12, 512, 512, 128, 4, 0),   # 12 has none: streaming kernels
+        (16, 16, 512, 512, 64, 4, 16),
+        (16, 2, 512, 512, 256, 4, 8),    # hb a multiple of the group (8)
+    ],
+)
+def test_heads_per_step_rule(h, kvh, lq, lk, d, itemsize, want):
+    """``_row_heads``, the one-tile kernels' heads per grid step, as a
+    pure function of the shapes: the largest divisor of H that keeps
+    GQA groups whole, that Mosaic can block (all of H, or a multiple
+    of 8 with 128-lane-aligned q and kv slabs) and that fits the VMEM
+    budget; 0 sends the call to the streaming kernels."""
+    from mlapi_tpu.ops.pallas.flash_attention import (
+        _ROW_VMEM_BUDGET, _row_heads, _row_vmem_bytes,
+    )
+
+    hb = _row_heads(h, kvh, lq, lk, d, itemsize)
+    assert hb == want
+    if hb:
+        group = h // kvh
+        assert h % hb == 0 and hb % group == 0
+        assert hb == h or (hb % 8 == 0 and (hb // group * d) % 128 == 0)
+        assert _row_vmem_bytes(hb, hb // group, lq, lk, d, itemsize) <= _ROW_VMEM_BUDGET

@@ -17,6 +17,7 @@ does that on the chip.
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -37,6 +38,8 @@ from mlapi_tpu.ops.pallas import (
 
 # BERT-base: 12 heads x 64, the sst2-bert preset's batch and length.
 BERT_B, BERT_L, HEADS, HEAD_DIM = 32, 128, 12, 64
+# The benchmark cell bert-base.finetune: 128 rows of 128 tokens.
+CELL_B = 128
 # GPT-2 small serving: 12 KV heads x 64, 1024 positions, 16-token
 # pages (what the verify skill and chip_smoke drive), a 16-token
 # extend span, 4 rows.
@@ -103,12 +106,16 @@ def _compile(fn, *args):
     return jax.jit(fn).lower(*args).compile()
 
 
+@pytest.mark.parametrize("batch", [BERT_B, CELL_B])
 @pytest.mark.parametrize("direction", ["forward", "backward"])
-def test_flash_attention_bert_base(one_chip, direction):
+def test_flash_attention_bert_base(one_chip, direction, batch):
     """The sst2-bert preset's attention: forward, and the custom-VJP
-    backward kernels, at batch 32 x 128 tokens."""
-    qkv = _shape((BERT_B, BERT_L, HEADS, HEAD_DIM), jnp.bfloat16, one_chip)
-    mask = _shape((BERT_B, BERT_L), jnp.float32, one_chip)
+    backward kernels, at the preset's batch and at the benchmark
+    cell's (128 x 128 tokens). One tile a sequence, so the row
+    statistics are ``[B, H, L]`` along lanes: no float32 array with a
+    trailing dimension of 1 (a 128-lane tile a number) is left."""
+    qkv = _shape((batch, BERT_L, HEADS, HEAD_DIM), jnp.bfloat16, one_chip)
+    mask = _shape((batch, BERT_L), jnp.float32, one_chip)
 
     def fwd(q, k, v, m):
         return flash_attention(q, k, v, m, interpret=False)
@@ -123,7 +130,41 @@ def test_flash_attention_bert_base(one_chip, direction):
             return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
 
     txt = _compile(fn, qkv, qkv, qkv, mask).as_text()
-    assert "tpu_custom_call" in txt
+    assert txt.count('custom_call_target="tpu_custom_call"') == (
+        1 if direction == "forward" else 3
+    )
+    assert not re.findall(r"f32\[[\d,]+,1\]", txt)
+
+
+def test_flash_attention_layer_has_no_layout_copy(one_chip):
+    """An attention layer as ``models/bert.py`` writes it (projections,
+    ``[B, L, H, D]`` reshapes, flash, output projection) at the cell's
+    shapes, forward and backward: the kernels read and write the
+    model's own ``[B, L, H*D]`` layout, so the compiled layer holds no
+    ``copy`` or ``transpose`` of an attention-sized tensor around the
+    three custom calls."""
+    hidden = HEADS * HEAD_DIM
+    x = _shape((CELL_B, BERT_L, hidden), jnp.bfloat16, one_chip)
+    w = _shape((hidden, hidden), jnp.bfloat16, one_chip)
+    mask = _shape((CELL_B, BERT_L), jnp.float32, one_chip)
+
+    def layer(x, wq, wk, wv, wo, m):
+        q, k, v = (
+            (x @ p).reshape(CELL_B, BERT_L, HEADS, HEAD_DIM)
+            for p in (wq, wk, wv)
+        )
+        ctx = flash_attention(q, k, v, m, interpret=False)
+        out = ctx.reshape(CELL_B, BERT_L, hidden) @ wo
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    txt = _compile(
+        jax.grad(layer, argnums=(0, 1, 2, 3, 4)), x, w, w, w, w, mask
+    ).as_text()
+    assert txt.count('custom_call_target="tpu_custom_call"') == 3
+    sized = rf"\[{CELL_B},{BERT_L},(?:{HEADS},{HEAD_DIM}|{hidden})\]"
+    moved = re.findall(rf"= \w+{sized}\S* (?:copy|transpose)\(", txt)
+    assert not moved, moved
+    assert not re.findall(r"f32\[[\d,]+,1\]", txt)
 
 
 @pytest.mark.parametrize("shape", [(4, 1), (1, 4)])

@@ -4,7 +4,10 @@ head geometry ``chip_smoke.py`` serves (BERT-base / GPT-2 small:
 12 heads x 64), in ONE process.
 
     python -m tools.chip_kernels            # the five kernels, both
-                                            # cache formats
+                                            # cache formats; flash also
+                                            # at the benchmark cell's
+                                            # shapes and at L = 1024,
+                                            # timed beside XLA's own
     python -m tools.chip_kernels --tp       # decode_attention_tp on a
                                             # (1, 4) mesh vs the
                                             # unsharded kernel
@@ -23,6 +26,7 @@ from __future__ import annotations
 import argparse
 import json
 import sys
+import time
 
 # Operands are unit-variance bf16; every dot accumulates in f32 and
 # the probabilities are cast to bf16 for the PV contraction, so the
@@ -142,6 +146,71 @@ def run(tiny: bool, tp: bool) -> list[dict]:
         norm = float(jnp.max(jnp.abs(gr))) or 1.0
         case(f"flash_attention-grad-d{name}", gk.astype(jnp.float32) / norm,
              gr / norm)
+
+    # The benchmark cell's own attention (bert-base.finetune: 128 rows
+    # x 128 tokens, padded lengths; one tile, so the row-blocked
+    # kernels) and a streaming-path witness at L = 1024: forward and
+    # the three gradients against the oracle, and the time of one
+    # forward + backward beside XLA's own full_attention.
+    from mlapi_tpu.ops import full_attention
+
+    def timed(fn, *args, n=20):
+        jax.block_until_ready(fn(*args))
+        t0 = time.perf_counter()
+        for _ in range(n):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        return (time.perf_counter() - t0) / n * 1e3
+
+    for tag, cb, cl, causal in (
+        ("cell", *((2, 32) if tiny else (128, 128)), False),
+        ("l1024", *((2, 64) if tiny else (4, 1024)), True),
+    ):
+        cq, ck, cv = (normal(cb, cl, heads, dim) for _ in range(3))
+        clens = np.linspace(cl // 8, cl, cb).astype(np.int32)
+        cmask = jnp.asarray(np.arange(cl)[None] < clens[:, None], jnp.float32)
+        valid = cmask[:, :, None, None]  # padded query rows: not compared
+        allow = jnp.broadcast_to(cmask[:, None], (cb, cl, cl))
+        if causal:
+            allow = allow * jnp.tril(jnp.ones((cl, cl), jnp.float32))
+        blocks = {} if not (tiny and causal) else {"block_q": 32, "block_k": 32}
+
+        def kern(q, k, v):
+            return flash_attention(q, k, v, cmask, causal=causal,
+                                   interpret=interp, **blocks) * valid.astype(
+                                       jnp.bfloat16)
+
+        def xla(q, k, v):
+            return full_attention(q, k, v, cmask, causal=causal) * valid.astype(
+                jnp.bfloat16)
+
+        def ref(q, k, v):
+            return _oracle(q, k, v, allow) * valid
+
+        case(f"flash_attention-{tag}", kern(cq, ck, cv), ref(cq, ck, cv))
+        g_kernel = jax.grad(loss(kern), argnums=(0, 1, 2))(cq, ck, cv)
+        g_ref = jax.grad(loss(ref), argnums=(0, 1, 2))(
+            *(x.astype(jnp.float32) for x in (cq, ck, cv)))
+        for name, gk, gr in zip("qkv", g_kernel, g_ref):
+            norm = float(jnp.max(jnp.abs(gr))) or 1.0
+            case(f"flash_attention-{tag}-grad-d{name}",
+                 gk.astype(jnp.float32) / norm, gr / norm)
+
+        def timed_grad(fn):
+            # Operands as a model holds them: [B, L, H*D] projections,
+            # split into heads inside the program.
+            flat = [x.reshape(cb, cl, heads * dim) for x in (cq, ck, cv)]
+            split = lambda *xs: loss(fn)(  # noqa: E731
+                *(x.reshape(cb, cl, heads, dim) for x in xs))
+            return timed(jax.jit(jax.grad(split, argnums=(0, 1, 2))), *flat)
+
+        print(json.dumps({
+            "timing": f"flash_attention-{tag}", "what": "forward + backward, "
+            "host clock around 20 drained calls, ms a call",
+            "shape": [cb, cl, heads, dim], "causal": causal,
+            "kernel_ms": timed_grad(kern),
+            "xla_full_attention_ms": timed_grad(xla),
+        }), flush=True)
 
     # The four cache-read kernels x both stored formats.
     n_pages = lk // page
