@@ -373,3 +373,34 @@ register_preset(
         eval_every=100,
     )
 )
+
+# The Kimi-Linear family at CPU widths: five layers of the published
+# kinds (KDA + dense, KDA, KDA, MLA, KDA; the last four with the
+# sparse-expert FFN), 16 experts with 4 a token of which this model
+# holds the first 8 (``experts_held``: the layer expert parallelism
+# needs, run without its exchange). Training only: the serving CLI
+# refuses the checkpoint.
+register_preset(
+    TrainConfig(
+        name="docs-kimi-linear",
+        model="kimi_linear_lm",
+        model_kwargs={
+            "vocab_size": 260, "hidden_size": 64, "num_layers": 5,
+            "kda_layers": [1, 2, 3, 5], "full_attn_layers": [4],
+            "first_k_dense_replace": 1, "intermediate_size": 256,
+            "num_heads": 4, "qk_nope_head_dim": 16, "qk_rope_head_dim": 8,
+            "v_head_dim": 16, "kv_lora_rank": 32,
+            "kda_num_heads": 4, "kda_head_dim": 16, "kda_chunk": 32,
+            "num_experts": 16, "num_experts_per_token": 4,
+            "moe_intermediate_size": 32, "experts_held": [0, 8],
+            "moe_tile": 32, "compute_dtype": "float32",
+        },
+        dataset="docs_text",
+        dataset_kwargs={"seq_len": 128},
+        steps=200,
+        batch_size=16,
+        optimizer="adamw",
+        learning_rate=1e-3,
+        eval_every=100,
+    )
+)

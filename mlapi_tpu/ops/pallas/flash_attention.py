@@ -331,13 +331,17 @@ def _row_heads(h, kvh, lq, lk, d, itemsize):
     return 0
 
 
-def _one_tile_heads(q, k, block_q, block_k):
+def _one_tile_heads(q, k, block_q, block_k, v=None):
     """``_row_heads`` for these operands when the (fitted) blocks cover
     both sequences whole, else 0. From shapes alone, so the forward,
-    the backward and the counter agree."""
+    the backward and the counter agree. Value heads of another width
+    than the query/key heads (latent attention) stream at any length:
+    the one-tile kernels slice every operand by one ``d``."""
     _, lq, h, d = q.shape
     _, lk, kvh, _ = k.shape
-    if lq != block_q or lk != block_k:
+    if lq != block_q or lk != block_k or (
+        v is not None and v.shape[-1] != d
+    ):
         return 0
     return _row_heads(h, kvh, lq, lk, d, q.dtype.itemsize)
 
@@ -570,11 +574,11 @@ def _bwd_rows(q, k, v, mask, out, lse, g, g_lse, causal, scale, interpret,
 
 def _fwd(q, k, v, mask, causal, scale, block_q, block_k, interpret,
          window=None):
-    hb = _one_tile_heads(q, k, block_q, block_k)
+    hb = _one_tile_heads(q, k, block_q, block_k, v)
     if hb:
         return _fwd_rows(q, k, v, mask, causal, scale, interpret, window, hb)
     b, lq, h, d = q.shape
-    lk = k.shape[1]
+    lk, dv = k.shape[1], v.shape[-1]
     # GQA: k/v may carry fewer heads than q (validated in _prepare);
     # the kv BlockSpec indexes `hi // group`, so each query head
     # streams its group's K/V block straight from HBM — no repeated
@@ -616,16 +620,21 @@ def _fwd(q, k, v, mask, causal, scale, block_q, block_k, interpret,
             kb = _window_k_tile(qi, ki, block_q, block_k, nkw)
             return (bi, 0, jnp.maximum(kb, 0))
 
-        kv_spec = pl.BlockSpec((1, 1, block_k, d), _kmap)
         mask_spec = pl.BlockSpec((1, 1, block_k), _mmap)
     else:
-        kv_spec = pl.BlockSpec(
-            (1, 1, block_k, d),
-            lambda bi, hi, qi, ki: (bi, hi // group, ki, 0),
-        )
+        def _kmap(bi, hi, qi, ki):
+            return (bi, hi // group, ki, 0)
+
         mask_spec = pl.BlockSpec(
             (1, 1, block_k), lambda bi, hi, qi, ki: (bi, 0, ki)
         )
+    # Scores run over the query/key width ``d``, values and the output
+    # over ``dv`` (the same unless the value heads are narrower).
+    k_spec = pl.BlockSpec((1, 1, block_k, d), _kmap)
+    v_spec = pl.BlockSpec((1, 1, block_k, dv), _kmap)
+    o_spec = pl.BlockSpec(
+        (1, 1, block_q, dv), lambda bi, hi, qi, ki: (bi, hi, qi, 0)
+    )
     # LSE rides as [B, H, L, 1]: Mosaic requires the last two block
     # dims tile-aligned (8, 128) or equal to the array dims; a
     # (1, 1, block_q) block over [B, H, L] fails that for H > 1,
@@ -642,16 +651,16 @@ def _fwd(q, k, v, mask, causal, scale, block_q, block_k, interpret,
             windowed_grid=windowed_grid,
         ),
         grid=grid,
-        in_specs=[q_spec, kv_spec, kv_spec, mask_spec],
-        out_specs=[q_spec, lse_spec],
+        in_specs=[q_spec, k_spec, v_spec, mask_spec],
+        out_specs=[o_spec, lse_spec],
         out_shape=[
-            _out_struct(qt.shape, q.dtype, q),
+            _out_struct((b, h, lq, dv), q.dtype, q),
             _out_struct((b, h, lq, 1), jnp.float32, q),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, _LANES), jnp.float32),  # running max m
             pltpu.VMEM((block_q, _LANES), jnp.float32),  # running sum l
-            pltpu.VMEM((block_q, d), jnp.float32),       # output acc
+            pltpu.VMEM((block_q, dv), jnp.float32),      # output acc
         ],
         interpret=interpret,
     )(qt, kt, vt, mask3)
@@ -806,14 +815,14 @@ def _bwd_dkv_kernel(
 
 def _bwd(q, k, v, mask, out, lse, g, causal, scale, block_q, block_k,
          interpret, g_lse=None, window=None):
-    hb = _one_tile_heads(q, k, block_q, block_k)
+    hb = _one_tile_heads(q, k, block_q, block_k, v)
     if hb:
         return _bwd_rows(
             q, k, v, mask, out, lse, g, g_lse, causal, scale, interpret,
             window, hb,
         )
     b, lq, h, d = q.shape
-    lk = k.shape[1]
+    lk, dv = k.shape[1], v.shape[-1]
     kvh = k.shape[2]
     group = h // kvh
     mask3 = mask.astype(jnp.float32)[:, None, :]
@@ -854,9 +863,16 @@ def _bwd(q, k, v, mask, out, lse, g, causal, scale, block_q, block_k,
     q_spec = pl.BlockSpec(
         (1, 1, block_q, d), lambda bi, hi, qi, kr: (bi, hi, qi, 0)
     )
-    kv_spec = pl.BlockSpec(
-        (1, 1, block_k, d),
-        lambda bi, hi, qi, kr: (bi, hi // group, _kb(qi, kr), 0),
+    # q, k and dq are ``d`` wide; v, dO and dv ``dv`` wide.
+    do_spec = pl.BlockSpec(
+        (1, 1, block_q, dv), lambda bi, hi, qi, kr: (bi, hi, qi, 0)
+    )
+    k_spec, v_spec = (
+        pl.BlockSpec(
+            (1, 1, block_k, w),
+            lambda bi, hi, qi, kr: (bi, hi // group, _kb(qi, kr), 0),
+        )
+        for w in (d, dv)
     )
     mask_spec = pl.BlockSpec(
         (1, 1, block_k), lambda bi, hi, qi, kr: (bi, 0, _kb(qi, kr))
@@ -872,7 +888,7 @@ def _bwd(q, k, v, mask, out, lse, g, causal, scale, block_q, block_k,
             windowed_grid=dq_windowed,
         ),
         grid=(b, h, nq, nkq),
-        in_specs=[q_spec, kv_spec, kv_spec, mask_spec, q_spec, row_spec,
+        in_specs=[q_spec, k_spec, v_spec, mask_spec, do_spec, row_spec,
                   row_spec],
         out_specs=q_spec,
         out_shape=_out_struct(qt.shape, q.dtype, q),
@@ -903,12 +919,18 @@ def _bwd(q, k, v, mask, out, lse, g, causal, scale, block_q, block_k,
             )
         return gq % nq_eff
 
-    q_spec_T = pl.BlockSpec(
-        (1, 1, block_q, d),
-        lambda bi, kvi, ki, gq: (bi, _hq(kvi, gq), _qt(ki, gq), 0),
+    q_spec_T, do_spec_T = (
+        pl.BlockSpec(
+            (1, 1, block_q, w),
+            lambda bi, kvi, ki, gq: (bi, _hq(kvi, gq), _qt(ki, gq), 0),
+        )
+        for w in (d, dv)
     )
-    kv_spec_T = pl.BlockSpec(
-        (1, 1, block_k, d), lambda bi, kvi, ki, gq: (bi, kvi, ki, 0)
+    k_spec_T, v_spec_T = (
+        pl.BlockSpec(
+            (1, 1, block_k, w), lambda bi, kvi, ki, gq: (bi, kvi, ki, 0)
+        )
+        for w in (d, dv)
     )
     mask_spec_T = pl.BlockSpec(
         (1, 1, block_k), lambda bi, kvi, ki, gq: (bi, 0, ki)
@@ -924,16 +946,16 @@ def _bwd(q, k, v, mask, out, lse, g, causal, scale, block_q, block_k,
             nq_eff=nq_eff, nq_total=nq, windowed_grid=dkv_windowed,
         ),
         grid=(b, kvh, nk_full, group * nq_eff),
-        in_specs=[q_spec_T, kv_spec_T, kv_spec_T, mask_spec_T, q_spec_T,
+        in_specs=[q_spec_T, k_spec_T, v_spec_T, mask_spec_T, do_spec_T,
                   row_spec_T, row_spec_T],
-        out_specs=[kv_spec_T, kv_spec_T],
+        out_specs=[k_spec_T, v_spec_T],
         out_shape=[
             _out_struct(kt.shape, k.dtype, q),
             _out_struct(vt.shape, v.dtype, q),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, dv), jnp.float32),
         ],
         interpret=interpret,
     )(qt, kt, vt, mask3, gt, lse4, delta4)
@@ -988,7 +1010,7 @@ def _counted_flash(q, k, v, mask, causal, scale, block_q, block_k,
     (``flash.calls_traced``; ``flash.calls_row_blocked`` when the
     one-tile kernels take the call). Nothing is counted per step."""
     REGISTRY.counter("flash.calls_traced").inc()
-    if _one_tile_heads(q, k, block_q, block_k):
+    if _one_tile_heads(q, k, block_q, block_k, v):
         REGISTRY.counter("flash.calls_row_blocked").inc()
     return _flash(
         q, k, v, mask.astype(jnp.float32), causal, scale, block_q, block_k,
@@ -1021,6 +1043,11 @@ def _prepare(q, k, v, mask, causal, scale, block_q, block_k,
     if k.shape[2] != v.shape[2]:
         raise ValueError(
             f"k and v head counts disagree: {k.shape[2]} vs {v.shape[2]}"
+        )
+    if k.shape[3] != d:
+        raise ValueError(
+            f"q and k head widths disagree: {d} vs {k.shape[3]} (only "
+            "the value heads may have a width of their own)"
         )
     if h % k.shape[2]:
         raise ValueError(
@@ -1061,7 +1088,9 @@ def flash_attention(
 ):
     """Fused softmax attention. ``q, k, v``: ``[B, L, H, D]``;
     ``mask``: optional binary ``[B, L]`` over keys. Returns
-    ``[B, L, H, D]`` in ``q.dtype``.
+    ``[B, L, H, D]`` in ``q.dtype``. ``v`` may have a head width of
+    its own (latent attention: scores over 192, values over 128); the
+    output then has ``v``'s, and the call streams at any length.
 
     Differentiable end to end in Pallas: the forward saves the per-row
     log-sum-exp and the backward recomputes probability tiles from it

@@ -19,6 +19,7 @@ import asyncio
 import tempfile
 
 from mlapi_tpu.serving import InferenceEngine, Server, build_app
+from mlapi_tpu.serving.engine import NotServable
 from mlapi_tpu.utils.logging import get_logger
 
 _log = get_logger("serving.main")
@@ -1017,26 +1018,29 @@ def main(argv=None) -> None:
         # A shape smaller than the host's device count serves on the
         # first `need` devices (create_mesh's rule).
         mesh = create_mesh(shape)
-    engine = InferenceEngine.from_checkpoint(
-        ckpt, quantize=args.quantize,
-        kv_quant=args.kv_quant,
-        decode_attn_impl=args.decode_attn_impl,
-        kv_page_size=args.kv_page_size,
-        kv_pages=args.kv_pages,
-        prefill_page_native=args.prefill_page_native,
-        prefill_interleave=args.prefill_interleave,
-        kv_tier_bytes=args.kv_tier_bytes,
-        kv_tier_disk_dir=args.kv_tier_disk_dir,
-        kv_peer_fetch=args.kv_peer_fetch,
-        replica_role=args.replica_role,
-        draft_checkpoint=args.draft_checkpoint,
-        spec_sample=args.spec_sample,
-        sched_max_batches=args.sched_max_batches,
-        adapter_slots=args.adapter_slots,
-        adapter_store_bytes=args.adapter_store_bytes,
-        adapter_disk_dir=args.adapter_disk_dir,
-        mesh=mesh,
-    )
+    try:
+        engine = InferenceEngine.from_checkpoint(
+            ckpt, quantize=args.quantize,
+            kv_quant=args.kv_quant,
+            decode_attn_impl=args.decode_attn_impl,
+            kv_page_size=args.kv_page_size,
+            kv_pages=args.kv_pages,
+            prefill_page_native=args.prefill_page_native,
+            prefill_interleave=args.prefill_interleave,
+            kv_tier_bytes=args.kv_tier_bytes,
+            kv_tier_disk_dir=args.kv_tier_disk_dir,
+            kv_peer_fetch=args.kv_peer_fetch,
+            replica_role=args.replica_role,
+            draft_checkpoint=args.draft_checkpoint,
+            spec_sample=args.spec_sample,
+            sched_max_batches=args.sched_max_batches,
+            adapter_slots=args.adapter_slots,
+            adapter_store_bytes=args.adapter_store_bytes,
+            adapter_disk_dir=args.adapter_disk_dir,
+            mesh=mesh,
+        )
+    except NotServable as e:
+        parser.error(str(e))
     for spec in args.adapter or ():
         # Startup preload: ID=PATH into the host store (device slots
         # install lazily, at the first request naming the tenant).

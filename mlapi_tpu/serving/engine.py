@@ -61,6 +61,11 @@ _log = get_logger("serving.engine")
 DEFAULT_BUCKETS = (1, 2, 4, 8, 16, 32, 64, 128, 256, 512)
 
 
+class NotServable(ValueError):
+    """The checkpoint's model family cannot be served (its own
+    ``serving_refusal`` says why, in one sentence)."""
+
+
 class InferenceEngine:
     """Batched classification inference over a jitted forward pass.
 
@@ -239,6 +244,9 @@ class InferenceEngine:
             feature_names = meta.config.get("feature_names", ())
         else:
             feature_names = ()
+        if getattr(model, "serving_refusal", None):
+            # a family that trains but has no cache protocol yet
+            raise NotServable(model.serving_refusal)
 
         # eval_shape: abstract tree only — a full random init of a
         # large model would allocate (and page) every parameter just

@@ -215,6 +215,7 @@ def run(
         "steps": result.steps,
         "wall_seconds": result.wall_seconds,
         "host_ms_per_step": result.host_ms_per_step,
+        **({"model_stats": result.model_stats} if result.model_stats else {}),
         "first_loss": result.first_loss,
         "final_loss": result.final_loss,
         "test_accuracy": result.test_accuracy,

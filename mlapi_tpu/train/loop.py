@@ -56,6 +56,9 @@ class TrainResult:
     # holds whatever the runtime makes the host wait for the device),
     # ``sync`` (every ``float(loss)`` the loop read).
     host_ms_per_step: dict = field(default_factory=dict)
+    # The last step's model statistics (a sparse-expert model's
+    # ``moe.*`` load); empty for a model that reports none.
+    model_stats: dict = field(default_factory=dict)
 
 
 def _host_ms_per_step(before: dict) -> dict:
@@ -91,9 +94,17 @@ def make_train_step(
     distill_temperature: float = 2.0,
     distill_alpha: float = 0.5,
     state_shardings: tuple | None = None,
+    stats_apply: Callable | None = None,
 ) -> Callable:
     """Build a jit-compiled SGD step ``(params, opt_state, x, y) ->
     (params, opt_state, loss)``.
+
+    ``stats_apply`` (a model's ``apply_with_stats``: ``(params, x) ->
+    (logits, {name: device scalar})``) takes ``apply_fn``'s place in the
+    loss and the step returns a fourth value, that dictionary, computed
+    in the same program with no sync of its own (a sparse-expert
+    model's load a step). Without it the step is the three-output
+    program it has always been.
 
     ``params`` and ``opt_state`` are donated — the optimizer update
     happens in-place in device memory, no copies.
@@ -153,7 +164,10 @@ def make_train_step(
         return distill_alpha * hard + (1.0 - distill_alpha) * (t * t) * soft
 
     def loss_fn(params, x, y, tp):
-        logits = apply_fn(params, x)
+        if stats_apply is not None:
+            logits, stats = stats_apply(params, x)
+        else:
+            logits = apply_fn(params, x)
         if task == "lm":
             targets = y[:, 1:]
             keep = (targets != 0).astype(jnp.float32)
@@ -185,13 +199,17 @@ def make_train_step(
                 if p.ndim >= 2
             )
             loss = loss + 0.5 * weight_decay * l2
-        return loss
+        return loss if stats_apply is None else (loss, stats)
 
     def step(params, opt_state, x, y, tp):
-        loss, grads = jax.value_and_grad(loss_fn)(params, x, y, tp)
+        out, grads = jax.value_and_grad(
+            loss_fn, has_aux=stats_apply is not None
+        )(params, x, y, tp)
         updates, opt_state = tx.update(grads, opt_state, params)
         params = optax.apply_updates(params, updates)
-        return params, opt_state, loss
+        if stats_apply is None:
+            return params, opt_state, out
+        return params, opt_state, *out
 
     if debug_checks:
         from jax.experimental import checkify
@@ -220,6 +238,8 @@ def make_train_step(
             mesh_of, jax.sharding.PartitionSpec()
         )
         out_shardings = (p_sh, o_sh, scalar)
+        if stats_apply is not None:
+            out_shardings += (scalar,)  # a prefix: every stat replicated
 
     jitted = jax.jit(step, donate_argnums=(0, 1), out_shardings=out_shardings)
 
@@ -644,6 +664,12 @@ def fit(
             distill_temperature=distill_temperature,
             distill_alpha=distill_alpha,
             state_shardings=state_shardings,
+            # the class's own: a wrapper (LoRA) hands unknown names to
+            # its inner model, whose forward is not the wrapper's
+            stats_apply=(
+                model.apply_with_stats
+                if hasattr(type(model), "apply_with_stats") else None
+            ),
         )
 
     def eval_fn(p):
@@ -679,7 +705,21 @@ def fit(
         """The loss on the host, inside the loop: where a step waits
         for the device."""
         with span("fit.sync", "fit.sync"):
-            return float(loss)
+            value = float(loss)
+        read_stats()
+        return value
+
+    def read_stats() -> dict:
+        """The newest step's model statistics into ``REGISTRY`` gauges
+        of their names: only where the loop already waits for the
+        device (after a loss readback, at the loop's end)."""
+        if not stats:
+            return {}
+        with span("fit.stats", "fit.stats"):
+            read = {name: v.item() for name, v in stats[0].items()}
+        for name, value in read.items():
+            REGISTRY.gauge(name).set(value)
+        return read
 
     sums0 = REGISTRY.snapshot()["counters"]
     trace_from = start_step + PROFILE_SKIP_STEPS if profile_dir else -1
@@ -693,6 +733,7 @@ def fit(
     history: list[dict] = []
     loss = float("nan")
     first_loss = None
+    stats: tuple = ()  # the newest step's, as device arrays
     try:
         for i in range(start_step, steps):
             if i == trace_from:
@@ -705,7 +746,7 @@ def fit(
                     if mesh is not None:
                         x, y = shard_batch_for_mesh((x, y), mesh)
                 with span("fit.dispatch", "fit.dispatch"):
-                    params, opt_state, loss = step_fn(
+                    params, opt_state, loss, *stats = step_fn(
                         params, opt_state, x, y
                     )
                 if first_loss is None:
@@ -779,6 +820,7 @@ def fit(
                     save_pool.shutdown(wait=True)
     wall = time.perf_counter() - t0
     final_loss = float(loss)  # after the last step: no span of the loop's
+    model_stats = read_stats()
     if steps > start_step and not np.isfinite(final_loss):
         raise FloatingPointError(
             f"training ended with non-finite loss {final_loss}"
@@ -798,4 +840,5 @@ def fit(
         history=history,
         first_loss=None if first_loss is None else float(first_loss),
         host_ms_per_step=_host_ms_per_step(sums0),
+        model_stats=model_stats,
     )

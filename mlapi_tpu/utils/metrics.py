@@ -47,6 +47,18 @@ class Counter:
             self.value += n
 
 
+@dataclass
+class Gauge:
+    """A quantity that is read, not summed (the newest training step's
+    expert load)."""
+
+    name: str
+    value: float = 0
+
+    def set(self, value: float) -> None:
+        self.value = value
+
+
 class Histogram:
     """Reservoir-sampled latency histogram (values in milliseconds)."""
 
@@ -86,16 +98,22 @@ class Histogram:
 
 
 class MetricsRegistry:
-    """Named counters + histograms, rendered as one JSON object."""
+    """Named counters, gauges + histograms, rendered as one JSON
+    object."""
 
     def __init__(self):
         self._counters: dict[str, Counter] = {}
+        self._gauges: dict[str, Gauge] = {}
         self._histograms: dict[str, Histogram] = {}
         self._lock = threading.Lock()
 
     def counter(self, name: str) -> Counter:
         with self._lock:
             return self._counters.setdefault(name, Counter(name))
+
+    def gauge(self, name: str) -> Gauge:
+        with self._lock:
+            return self._gauges.setdefault(name, Gauge(name))
 
     def histogram(self, name: str) -> Histogram:
         with self._lock:
@@ -111,9 +129,11 @@ class MetricsRegistry:
     def snapshot(self) -> dict:
         with self._lock:
             counters = dict(self._counters)
+            gauges = dict(self._gauges)
             histograms = dict(self._histograms)
         return {
             "counters": {n: c.value for n, c in counters.items()},
+            "gauges": {n: g.value for n, g in gauges.items()},
             "histograms": {n: h.summary() for n, h in histograms.items()},
         }
 

@@ -136,6 +136,42 @@ def test_flash_attention_bert_base(one_chip, direction, batch):
     assert not re.findall(r"f32\[[\d,]+,1\]", txt)
 
 
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+def test_flash_attention_latent_heads_stream(one_chip, direction):
+    """The latent-attention call of ``kimi_linear_lm`` at the cell
+    ``kimi-linear.pretrain_8k``: one row of 8,192 positions, 32 heads,
+    scores over 192 (128 + the 64-wide shared key part), values over
+    128, causal. The streaming kernels take it (16 tiles of 512), with
+    a 192-wide block for q and k and a 128-wide one for v and the
+    output."""
+    b, l, h = 1, 8192, 32
+    qk = _shape((b, l, h, 192), jnp.bfloat16, one_chip)
+    v = _shape((b, l, h, 128), jnp.bfloat16, one_chip)
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=False)
+
+    if direction == "forward":
+        fn = fwd
+    else:
+        def fn(q, k, v):
+            loss = lambda q, k, v: jnp.sum(  # noqa: E731
+                fwd(q, k, v).astype(jnp.float32) ** 2
+            )
+            return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    compiled = _compile(fn, qk, qk, v)
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"'
+    ) == (1 if direction == "forward" else 3)
+    out = jax.eval_shape(fn, qk, qk, v)
+    shapes = [o.shape for o in jax.tree.leaves(out)]
+    assert shapes == (
+        [(b, l, h, 128)] if direction == "forward"
+        else [(b, l, h, 192), (b, l, h, 192), (b, l, h, 128)]
+    )
+
+
 def test_flash_attention_layer_has_no_layout_copy(one_chip):
     """An attention layer as ``models/bert.py`` writes it (projections,
     ``[B, L, H, D]`` reshapes, flash, output projection) at the cell's
