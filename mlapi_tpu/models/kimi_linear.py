@@ -23,7 +23,10 @@ and the output is ``W_o [RMSNorm_head(o_t) * sigmoid(W_g2 W_g1 x_t)]``.
 The program computes it in CHUNKS of ``kda_chunk`` positions
 (:func:`kda_chunked`): inside a chunk by matrix products and the
 inverse of one unit lower-triangular matrix, across chunks by a
-``lax.scan`` that carries ``S``; plain XLA, differentiated by JAX. The in-chunk products weigh
+``lax.scan`` that carries ``S``; plain XLA, differentiated by JAX. At
+heads of 128 the same computation runs as Pallas kernels
+(``ops/pallas/kda.py``, forward and backward; :func:`kda` chooses by
+shapes and ``kda_chunked`` is their oracle). The in-chunk products weigh
 keys by ratios of cumulative decays, ``exp(G_r - G_i)``, which a
 factorisation ``exp(G_r) * exp(-G_i)`` overflows in any float format
 once a chunk decays by more than e^88. So the chunk is SPLIT by
@@ -71,6 +74,8 @@ import jax
 import jax.numpy as jnp
 
 from mlapi_tpu.models import register_model
+from mlapi_tpu.ops.pallas import kda as kda_kernels
+from mlapi_tpu.utils.metrics import REGISTRY
 from mlapi_tpu.utils.platform import pallas_interpret
 
 # Positions whose in-chunk matrices are built at once (kda_chunked). On a
@@ -253,6 +258,27 @@ def kda_chunked(q, k, v, g, beta, *, chunk: int, compute_dtype="float32"):
     # [groups, per, B, H, C, Dv] -> [B, L, H, Dv]
     o = jnp.transpose(o, (2, 0, 1, 4, 3, 5))
     return o.reshape(b, groups * per * c, h, dv)[:, :l]
+
+
+def kda(q, k, v, g, beta, *, chunk: int, compute_dtype="float32"):
+    """The gated delta rule, by whichever implementation the shapes
+    allow: heads that are whole 128-lane slabs (the published
+    ``head_dim`` 128) run the Pallas kernels
+    (``ops/pallas/kda.py``: the chunk's matrices and the state stay in
+    VMEM, operands in this layout; they tile the sequence themselves
+    and the result does not depend on ``chunk``); anything narrower
+    runs :func:`kda_chunked`. Same arguments and result as
+    :func:`kda_chunked`. Counted once a TRACE in
+    ``utils.metrics.REGISTRY``: ``kda.calls_traced``, and
+    ``kda.calls_kernel`` when the kernels are chosen."""
+    REGISTRY.counter("kda.calls_traced").inc()
+    if not kda_kernels.takes(q, v):
+        return kda_chunked(q, k, v, g, beta, chunk=chunk,
+                           compute_dtype=compute_dtype)
+    REGISTRY.counter("kda.calls_kernel").inc()
+    return kda_kernels.kda_kernels(
+        q, k, v, g, beta, compute_dtype=compute_dtype,
+        interpret=pallas_interpret())
 
 
 # -- the expert FFN's grouped product ----------------------------------
@@ -538,8 +564,8 @@ class KimiLinearLM:
              ).reshape(b, l, nk, dk))
         beta = jax.nn.sigmoid(_mm(x, p["b"], cdt))
         with jax.named_scope("kda.core"):
-            o = kda_chunked(q, k, v, g, beta, chunk=self.kda_chunk,
-                            compute_dtype=self.compute_dtype)
+            o = kda(q, k, v, g, beta, chunk=self.kda_chunk,
+                    compute_dtype=self.compute_dtype)
         gate = jax.nn.sigmoid(
             _mm(_mm(x, p["g_a"], cdt), p["g_b"], cdt)).reshape(b, l, nk, dk)
         o = _rms_norm(o, p["o_norm"], self.rms_norm_eps) * gate

@@ -206,6 +206,17 @@ def test_bfloat16_program_is_told_from_8_bit_products(flat, ids):
 
 
 # -- the chunked delta rule -----------------------------------------------
+def _delta_rule_operands(length, decay, b=2, h=2, d=128):
+    ks = jax.random.split(jax.random.key(length), 6)
+    unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)  # noqa: E731
+    return (unit(jax.random.normal(ks[0], (b, length, h, d))) * d ** -0.5,
+            unit(jax.random.normal(ks[1], (b, length, h, d))),
+            jax.random.normal(ks[2], (b, length, h, d)),
+            -decay * jax.random.uniform(ks[3], (b, length, h, d), maxval=2.0),
+            jax.nn.sigmoid(jax.random.normal(ks[4], (b, length, h))),
+            jax.random.normal(ks[5], (b, length, h, d)))
+
+
 @pytest.mark.parametrize("length,decay", [
     (80, 1.0),    # not a multiple of the chunk (32), one group
     (200, 1.0),   # three groups of two chunks, the last one padded
@@ -217,15 +228,7 @@ def test_chunked_kda_matches_literal_recurrence(monkeypatch, length, decay):
     both sides; the chunked form inverts a unit-triangular matrix a
     chunk and carries the state across chunks and groups."""
     monkeypatch.setattr(kl, "_GROUP", 64)
-    b, h, d = 2, 3, 16
-    ks = jax.random.split(jax.random.key(length), 6)
-    unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)  # noqa: E731
-    q = unit(jax.random.normal(ks[0], (b, length, h, d))) * d ** -0.5
-    k = unit(jax.random.normal(ks[1], (b, length, h, d)))
-    v = jax.random.normal(ks[2], (b, length, h, d))
-    g = -decay * jax.random.uniform(ks[3], (b, length, h, d), maxval=2.0)
-    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, length, h)))
-    probe = jax.random.normal(ks[5], (b, length, h, d))
+    q, k, v, g, beta, probe = _delta_rule_operands(length, decay, h=3, d=16)
 
     def run(f):
         return jax.jit(jax.value_and_grad(
@@ -240,6 +243,64 @@ def test_chunked_kda_matches_literal_recurrence(monkeypatch, length, decay):
     for got, want in zip(grads, grads_ref):
         assert np.all(np.isfinite(np.asarray(got)))
         assert rel(got, want) < 1e-4
+
+
+@pytest.mark.parametrize("length,decay,cdt,tol", [
+    (200, 1.0, "float32", 1e-4),   # no multiple of the tile (32)
+    (520, 1.0, "float32", 1e-4),   # 17 tiles: 16 carried states
+    (80, 40.0, "float32", 1e-4),   # a tile decays by far more than e^88
+    # bfloat16 products, float32 accumulation: the kernels read 0.0026-
+    # 0.0033 and kda_chunked 0.0026-0.0033 against the same oracle
+    (200, 1.0, "bfloat16", 1e-2),
+])
+def test_kda_kernels_match_literal_recurrence(length, decay, cdt, tol):
+    """The Pallas kernels (``ops/pallas/kda.py``, interpreter) at 2 rows
+    x 2 heads of 128 against ``reference.delta_rule``: the output and
+    all five gradients, as norm of the difference over the oracle's
+    norm. float32 operands: rounding only. ``compute_dtype="bfloat16"``:
+    the tolerance ``kda_chunked`` meets against the same oracle, and
+    the kernels within a quarter more than what it reads."""
+    from mlapi_tpu.ops.pallas import kda as kk
+
+    *args, probe = _delta_rule_operands(length, decay)
+
+    def run(f):
+        y, vjp = jax.vjp(f, *args)
+        return (y,) + vjp(probe)
+
+    got = jax.jit(lambda: run(lambda *a: kk.kda_kernels(
+        *a, compute_dtype=cdt, interpret=True)))()
+    want = jax.jit(lambda: run(ref.delta_rule))()
+    errs = [rel(a, w) for a, w in zip(got, want)]
+    for a, e in zip(got, errs):
+        assert np.all(np.isfinite(np.asarray(a)))
+        assert e < tol, errs
+    if cdt != "float32":
+        xla = jax.jit(lambda: run(lambda *a: kl.kda_chunked(
+            *a, chunk=32, compute_dtype=cdt)))()
+        for e, a, w in zip(errs, xla, want):
+            assert rel(a, w) < tol
+            assert e < 1.25 * rel(a, w) + 1e-4, (errs, rel(a, w))
+
+
+def test_shapes_choose_between_the_kernels_and_the_xla_scan():
+    """Heads of 128 take the kernels, 16-wide heads ``kda_chunked``:
+    read from ``kda.calls_traced`` / ``kda.calls_kernel``, counted once
+    a trace where ``models.kimi_linear.kda`` chooses. Both answers are
+    the recurrence's."""
+    def counts():
+        c = REGISTRY.snapshot()["counters"]
+        return c.get("kda.calls_traced", 0), c.get("kda.calls_kernel", 0)
+
+    for d, kernel in ((16, 0), (128, 1)):
+        *args, _ = _delta_rule_operands(40, 1.0, b=1, h=2, d=d)
+        before = counts()
+        fn = jax.jit(lambda *a: kl.kda(*a, chunk=32))
+        o = fn(*args)
+        fn(*args)  # a second call of the same trace counts nothing
+        after = counts()
+        assert (after[0] - before[0], after[1] - before[1]) == (1, kernel)
+        assert rel(o, ref.delta_rule(*args)) < 1e-4
 
 
 def test_unit_lower_inverse_is_a_stable_blocked_substitution():

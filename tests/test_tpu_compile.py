@@ -172,6 +172,56 @@ def test_flash_attention_latent_heads_stream(one_chip, direction):
     )
 
 
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+def test_kda_kernels_at_the_cell_call(one_chip, direction):
+    """The gated delta rule of ``kimi_linear_lm`` at the cell
+    ``kimi-linear.pretrain_8k``'s call, ``1 x 8192 x 32 x 128``
+    (operands as the model's projections leave them, ``[B, L, H * D]``,
+    split into heads inside the program), forward and the gradient of a
+    sum in all five operands: Mosaic takes the kernels (one custom call
+    forward; the forward that saves the states and the backward in the
+    gradient) and no ``while`` of the XLA scan is left. The blocks are
+    rows of ``[B, L, H, D]`` as it lies in memory, so what XLA lays out
+    is one ``reshape`` an operand and result between that and the
+    projections' ``[B, L, H * D]`` (9 forward and backward, the cost of
+    the choice: PERF.md, PR 30), and no ``copy``, ``transpose`` or pad
+    besides."""
+    from mlapi_tpu.ops.pallas.kda import kda_kernels
+
+    b, l, h, d = 1, 8192, 32, 128
+    x = _shape((b, l, h * d), jnp.float32, one_chip)
+    beta = _shape((b, l, h), jnp.float32, one_chip)
+
+    def fwd(q, k, v, g, beta):
+        q, k, v, g = (a.reshape(b, l, h, d) for a in (q, k, v, g))
+        return kda_kernels(q, k, v, g, beta, compute_dtype="bfloat16",
+                           interpret=False).reshape(b, l, h * d)
+
+    if direction == "forward":
+        fn = fwd
+    else:
+        def fn(*a):
+            return jax.grad(lambda *a: jnp.sum(fwd(*a)),
+                            argnums=(0, 1, 2, 3, 4))(*a)
+
+    txt = _compile(fn, x, x, x, x, beta).as_text()
+    assert txt.count('custom_call_target="tpu_custom_call"') == (
+        1 if direction == "forward" else 2
+    )
+    def sized(ops):
+        return [m for m in re.findall(
+            rf"= f32\[([\d,]+)\]\S* (?:{ops})\(", txt)
+            if np.prod([int(n) for n in m.split(",")]) >= b * l * h * d]
+
+    assert not sized("copy|transpose|pad"), sized("copy|transpose|pad")
+    assert len(sized("reshape")) <= (5 if direction == "forward" else 9)
+    assert " while(" not in txt
+    shapes = [o.shape for o in jax.tree.leaves(
+        jax.eval_shape(fn, x, x, x, x, beta))]
+    assert shapes == ([(b, l, h * d)] if direction == "forward"
+                      else [(b, l, h * d)] * 4 + [(b, l, h)])
+
+
 def test_flash_attention_layer_has_no_layout_copy(one_chip):
     """An attention layer as ``models/bert.py`` writes it (projections,
     ``[B, L, H, D]`` reshapes, flash, output projection) at the cell's

@@ -7,10 +7,22 @@ head geometry ``chip_smoke.py`` serves (BERT-base / GPT-2 small:
                                             # cache formats; flash also
                                             # at the benchmark cell's
                                             # shapes and at L = 1024,
-                                            # timed beside XLA's own
+                                            # timed beside XLA's own;
+                                            # the gated delta rule's
+                                            # agreement rows
     python -m tools.chip_kernels --tp       # decode_attention_tp on a
                                             # (1, 4) mesh vs the
                                             # unsharded kernel
+    python -m tools.chip_kernels --kda      # the gated delta rule
+                                            # alone: kernels against
+                                            # kda_chunked and the
+                                            # literal recurrence, and
+                                            # ms a call at the cell
+                                            # kimi-linear.pretrain_8k's
+                                            # shapes beside kda_chunked
+                                            # (--kda-tile 32 64 and
+                                            # --kda-unroll 2 4 sweep
+                                            # the kernel's two knobs)
     python -m tools.chip_kernels --tiny     # CPU rehearsal sizes
 
 ``interpret`` follows the one rule (``utils.platform.
@@ -254,10 +266,127 @@ def run(tiny: bool, tp: bool) -> list[dict]:
     return rows
 
 
+def run_kda(tiny: bool, timing: bool = True, tiles=(), unrolls=()) -> list[dict]:
+    """The gated delta rule (``ops/pallas/kda.py``). Agreement at small
+    sizes (2 rows x 2 heads of 128, 200 positions: a padded tail and
+    several carried states): float32 operands against the literal
+    recurrence (``benchmark/reference``), and at ``bfloat16`` products
+    the kernels beside ``kda_chunked``, each against the same oracle,
+    as norm of the difference over the oracle's norm, output and the
+    five gradients. With ``timing``, at the benchmark cell's call (1 x
+    8192 x 32 x 128), ms a call forward and forward + backward beside
+    ``kda_chunked``'s, one JSON line a variant."""
+    import os
+
+    import jax
+    import jax.numpy as jnp
+
+    from mlapi_tpu.models.kimi_linear import kda_chunked
+    from mlapi_tpu.ops.pallas import kda as kk
+    from mlapi_tpu.utils.platform import pallas_interpret
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from reference import kimi_linear as ref
+
+    interp = pallas_interpret()
+    rows: list[dict] = []
+
+    def operands(b, l, h, d, seed=0):
+        ks = jax.random.split(jax.random.key(seed), 6)
+        unit = lambda a: a / jnp.linalg.norm(  # noqa: E731
+            a, axis=-1, keepdims=True)
+        return (unit(jax.random.normal(ks[0], (b, l, h, d))) * d ** -0.5,
+                unit(jax.random.normal(ks[1], (b, l, h, d))),
+                jax.random.normal(ks[2], (b, l, h, d)),
+                -jax.random.uniform(ks[3], (b, l, h, d), maxval=0.2),
+                jax.nn.sigmoid(jax.random.normal(ks[4], (b, l, h))),
+                jax.random.normal(ks[5], (b, l, h, d)))
+
+    def kernel(cdt):
+        return lambda *a: kk.kda_kernels(
+            *a, compute_dtype=cdt, interpret=interp)
+
+    def chunked(cdt):
+        return lambda *a: kda_chunked(*a, chunk=32, compute_dtype=cdt)
+
+    *args, probe = operands(2, 64 if tiny else 200, 2, 128)
+
+    def both(fn):
+        y, vjp = jax.vjp(fn, *args)
+        return (y,) + vjp(probe)
+
+    def rel(a, b):
+        return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+
+    want = jax.jit(lambda: both(ref.delta_rule))()
+    # float32: rounding only. bfloat16 products: what kda_chunked
+    # itself reads against the same oracle (0.003 at these sizes).
+    for tag, fn, tol in (("kernel-float32", kernel("float32"), 1e-4),
+                         ("kernel-bfloat16", kernel("bfloat16"), 2e-2),
+                         ("chunked-bfloat16", chunked("bfloat16"), 2e-2)):
+        got = jax.jit(lambda fn=fn: both(fn))()
+        for name, a, w in zip(("o", "dq", "dk", "dv", "dg", "dbeta"),
+                              got, want):
+            e = rel(a, w)
+            row = {"kernel": f"kda-{tag}-{name}", "rel_err": e, "tol": tol,
+                   "shape": list(a.shape), "interpret": interp,
+                   "within_tol": bool(jnp.all(jnp.isfinite(a))) and e <= tol}
+            print(json.dumps(row), flush=True)
+            rows.append(row)
+
+    if not timing:
+        return rows
+    # The cell's call, operands as the model holds them ([B, L, H*D]
+    # projections split into heads inside the program).
+    b, l, h, d = (1, 128, 2, 128) if tiny else (1, 8192, 32, 128)
+    *flat, probe = (x.reshape(b, l, -1) for x in operands(b, l, h, d, 1))
+    beta = flat.pop()
+
+    def heads(fn):
+        return lambda q, k, v, g, beta: fn(
+            *(x.reshape(b, l, h, d) for x in (q, k, v, g)), beta
+        ).reshape(b, l, -1)
+
+    def timed(fn, n=2 if tiny else 10):
+        fn = jax.jit(fn)
+        jax.block_until_ready(fn(*flat, beta))
+        t0 = time.perf_counter()
+        for _ in range(n):
+            out = fn(*flat, beta)
+        jax.block_until_ready(out)
+        return (time.perf_counter() - t0) / n * 1e3
+
+    def fwd_and_both(fn):
+        loss = lambda *a: jnp.sum(heads(fn)(*a) * probe)  # noqa: E731
+        return {"forward_ms": timed(heads(fn)),
+                "forward_backward_ms": timed(
+                    jax.grad(loss, argnums=(0, 1, 2, 3, 4)))}
+
+    line = {"timing": "kda", "shape": [b, l, h, d],
+            "compute_dtype": "bfloat16", "what": "host clock around "
+            "drained calls, ms a call", "interpret": interp}
+    print(json.dumps({**line, "path": "kda_chunked",
+                      **fwd_and_both(chunked("bfloat16"))}), flush=True)
+    for tile in tiles or (kk._TILE,):
+        for unroll in unrolls or (kk._UNROLL,):
+            kk._TILE, kk._UNROLL = tile, unroll
+            print(json.dumps({**line, "path": "kernels", "tile": tile,
+                              "heads_side_by_side": unroll,
+                              **fwd_and_both(kernel("bfloat16"))}),
+                  flush=True)
+    return rows
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser("tools.chip_kernels")
     ap.add_argument("--tiny", action="store_true")
     ap.add_argument("--tp", action="store_true")
+    ap.add_argument("--kda", action="store_true")
+    ap.add_argument("--kda-tile", type=int, nargs="*", default=())
+    ap.add_argument("--kda-unroll", type=int, nargs="*", default=())
     args = ap.parse_args(argv)
 
     from mlapi_tpu.utils.platform import (
@@ -268,11 +397,16 @@ def main(argv=None) -> int:
 
     apply_platform_override()
     enable_compile_cache()
-    rows = run(args.tiny, args.tp)
+    if args.kda:
+        rows = run_kda(args.tiny, True, args.kda_tile, args.kda_unroll)
+    else:
+        rows = run(args.tiny, args.tp)
+        if not args.tp:
+            rows += run_kda(args.tiny, timing=False)
     bad = [r["kernel"] for r in rows if not r["within_tol"]]
     print(json.dumps({
         "kernels": len(rows), "failed": bad, "tol": TOL,
-        "worst": max(r["max_abs_err"] for r in rows),
+        "worst": max(r.get("max_abs_err", 0.0) for r in rows),
         "device": device_report(),
     }), flush=True)
     return 1 if bad else 0
