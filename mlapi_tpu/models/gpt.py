@@ -70,8 +70,8 @@ class GptLM:
     # scales (ops/quant.py) — ~2x less decode HBM per cached token,
     # ~2x the serving cache budget per chip. A dataclass field (not a
     # method argument) so every lru_cache'd program factory
-    # (prefill_fn, decode_chunk_fn, generate_tier_fn, ...) keys on the
-    # cache format for free.
+    # (prefill_fn, decode_chunk_fn, ...) keys on the cache format for
+    # free.
     kv_quant: str = "none"
     # Cache-read attention: "einsum" (the reference oracle — one
     # [B,U,H,D] x [B,L,H,D] einsum over the dequantized cache) or
@@ -869,57 +869,6 @@ def _generate_fn(model, max_new_tokens: int):
             key_data, max_new_tokens - 1, jnp.int32(1), top_k, top_p,
         )
         return jnp.concatenate([first[:, None], rest], axis=1)
-
-    return jax.jit(_run)
-
-
-@functools.lru_cache(maxsize=64)
-def generate_tier_fn(model, tier: int):
-    """A whole generation — any batch size — as ONE XLA program:
-    prefill + a ``lax.while_loop`` of cached decode steps writing into
-    a ``[B, tier]`` output buffer, with per-row budgets ``n_actual <=
-    tier`` TRACED (the loop runs to the row maximum; a finished row's
-    later writes land beyond its budget and are sliced off by the
-    caller). One compile per (model, batch, prompt bucket, tier)
-    serves every budget combination in the tier, and where each
-    dispatch pays a host round trip the whole BATCH costs ONE
-    dispatch + ONE readback instead of one per chunk — the serving engine's fused fast path,
-    solo and batched.
-
-    ``(params, prompt_ids [B, P], key_data [B, ...], temps [B],
-    n_pad [B], top_k [B], top_p [B], n_actual [B] or scalar)`` →
-    ``tokens [B, tier]`` (row ``b``'s first ``n_actual[b]`` valid).
-    Every row's stream is byte-identical to the chunked engine path
-    AND to its own solo run: same left-padded prefill, same per-row
-    PRNG streams at per-token ``_pick_token`` indices (first token at
-    0, then 1, 2, ...) — a row's tokens do not depend on its batch.
-    """
-
-    def _run(params, prompt_ids, key_data, temps, n_pad, top_k, top_p,
-             n_actual):
-        p = prompt_ids.shape[1]
-        cache, logits = _prefill_core(
-            model, params, prompt_ids, n_pad, p + tier
-        )
-        first = _pick_token(temps, logits, key_data, 0, top_k, top_p)
-        b = first.shape[0]
-        out = jnp.zeros((b, tier), jnp.int32).at[:, 0].set(first)
-        n_max = jnp.max(jnp.asarray(n_actual))
-
-        def cond(s):
-            return s[3] < n_max
-
-        def body(s):
-            cache, tok, pos, i, out = s
-            logits, cache = model.decode_step(
-                params, cache, tok[:, None], pos, n_pad
-            )
-            nxt = _pick_token(temps, logits, key_data, i, top_k, top_p)
-            out = jax.lax.dynamic_update_slice(out, nxt[:, None], (0, i))
-            return (cache, nxt, pos + 1, i + 1, out)
-
-        s = (cache, first, jnp.int32(p), jnp.int32(1), out)
-        return jax.lax.while_loop(cond, body, s)[4]
 
     return jax.jit(_run)
 

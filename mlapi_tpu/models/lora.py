@@ -237,7 +237,7 @@ def merge_adapter(params: dict, payload: dict) -> dict:
     """Eagerly fold a serving payload into a fresh plain params tree:
     ``W + a @ b`` per target (``b`` already carries the scale). The
     merged-weights REFERENCE for the slot-path token-identity pins
-    (tests + bench) — and the escape hatch for serving one tenant on
+    (tests) — and the escape hatch for serving one tenant on
     an engine built without adapter slots."""
     merged = jax.tree.map(lambda x: x, params)  # fresh containers
     for ln, layer in payload.items():

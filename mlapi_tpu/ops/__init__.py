@@ -18,10 +18,8 @@ from mlapi_tpu.ops.ring_attention import ring_attention, ring_self_attention
 from mlapi_tpu.ops.speculative import (
     speculative_generate,
     speculative_generate_batched,
-    speculative_generate_fused,
     speculative_sample,
     speculative_sample_batched,
-    speculative_sample_fused,
 )
 
 __all__ = [
@@ -32,8 +30,6 @@ __all__ = [
     "dequantize_tree",
     "speculative_generate",
     "speculative_generate_batched",
-    "speculative_generate_fused",
     "speculative_sample",
     "speculative_sample_batched",
-    "speculative_sample_fused",
 ]

@@ -476,15 +476,6 @@ def _step_bytes(h, dk, dv):
     return 4 * (2 * blocks + h * dv * dk)
 
 
-def _step_bytes(h, dk, dv):
-    """VMEM the backward kernel's grid step needs (the heavier of the
-    two): its double-buffered blocks (q, k, g and their cotangents; v,
-    dO, dv; the tile's states and ``T``) and the ``dS`` scratch."""
-    rows = _TILE * h
-    blocks = 6 * rows * dk + 3 * rows * dv + h * (dv * dk + _TILE * _TILE)
-    return 4 * (2 * blocks + h * dv * dk)
-
-
 def takes(q, v) -> bool:
     """Whether the kernels take these operands, from shapes alone:
     heads that are whole 128-lane slabs (the published ``head_dim``

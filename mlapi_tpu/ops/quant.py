@@ -202,8 +202,8 @@ def paged_pools_of(cache: dict) -> dict:
 
 def kv_page_bytes(model, page_size: int) -> int:
     """Exact per-page device bytes across every layer — pure
-    dtype/shape arithmetic (the capacity-model unit the paged bench
-    asserts against, never wall-clock)."""
+    dtype/shape arithmetic (the capacity-model unit the paged tests
+    assert against, never wall-clock)."""
     proto = jax.eval_shape(lambda: model.init_cache(1, page_size))
     return sum(
         int(np.prod(leaf.shape)) * leaf.dtype.itemsize

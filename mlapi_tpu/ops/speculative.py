@@ -23,10 +23,15 @@ TPU-first mechanics worth noting:
 - The draft runs single-token steps through the same
   ``decode_chunk_fn`` program the serving engine uses.
 
-Batch-1 only: per-row acceptance lengths desynchronize cache
-positions across rows, which the scalar-``pos`` decode layout cannot
-express — batched serving gets its parallelism from continuous
-batching instead; speculation is the SINGLE-STREAM latency lever.
+This module is the LIBRARY implementation: host loops over jitted
+round programs, one row (:func:`speculative_generate`,
+:func:`speculative_sample`) or a whole batch with a cache position
+per row (:func:`speculative_generate_batched`,
+:func:`speculative_sample_batched`), and the oracle the tests hold to
+plain decoding. The other implementation is the serving engine's own
+(``serving/spec_phase.py``), which runs the same round programs
+(:func:`propose_fn`, :func:`verify_fn` and their sampled and batched
+twins) against the engine's live caches between scheduler units.
 
 Two schemes share the round/cache algebra:
 
@@ -239,8 +244,8 @@ def propose_fn(model, n_in: int, k: int, sampled: bool = False):
 
 def _accept_and_draw(key, pr, q_probs, props, usable, step0):
     """The distribution-critical acceptance-rejection core shared by
-    the jitted verify (:func:`sample_verify_fn`) and the fused loop
-    (:func:`fused_spec_fn`): test each proposal with ``u*q < p``
+    the solo and batched jitted verifies (:func:`sample_verify_fn`,
+    :func:`sample_verify_batched_fn`): test each proposal with ``u*q < p``
     (ACC-tagged per-token uniforms), find the first rejection ``m``
     (capped by ``usable``), and draw the round's final token — from
     the normalized residual ``max(p_m - q_m, 0)`` at a NATURAL
@@ -578,10 +583,10 @@ def speculative_sample_batched(
     """SAMPLED speculative generation for a WHOLE BATCH of rows, each
     with its own PRNG stream (``seeds``: one per row, default
     ``0..B-1``) and its own acceptance-driven cache position. Every
-    row's emitted stream is byte-identical to its solo
-    :func:`speculative_sample_fused` run (same tagged-stream
-    discipline, same ``usable = 0`` budget-capped rounds) and hence
-    exactly target-distributed for any draft. Same window-headroom
+    row's emitted stream is byte-identical to a batch of that row
+    alone with the same seed (a row never reads another row's keys or
+    cache; a budget-capped round is ``usable = 0``) and exactly
+    target-distributed for any draft. Same window-headroom
     requirement as the greedy batched variant. ``temperature <= 0``
     delegates to :func:`speculative_generate_batched`."""
     if temperature <= 0.0:
@@ -794,462 +799,6 @@ def speculative_generate_batched(
                 d_upto[i] = t_upto[i]
                 d_pend[i] = [bonus]
     return [o[:n] for o in out], stats
-
-
-# maxsize must dominate the serving engine's fused warm grid
-# (buckets x tiers x greedy/sampled — up to ~24 entries on a wide
-# config): an evicted entry would rebuild its jax.jit wrapper with an
-# EMPTY compile cache, and strict mode would then stall a request on
-# a remote recompile for a shape the fused warm set claims is warm.
-@functools.lru_cache(maxsize=64)
-def fused_spec_fn(target, draft, p: int, n: int, k: int,
-                  sampled: bool = False):
-    """The ENTIRE speculative generation as ONE XLA program: target +
-    draft prefills, then a ``lax.while_loop`` whose body is a full
-    round — draft scan (consume pending + chain k proposals), verify
-    block, acceptance, accepted-segment scatter into the output
-    buffer, cache-position algebra — with no host round-trip
-    anywhere. Through a high-RTT attach a generation costs ONE
-    dispatch + ONE packed readback regardless of length; on any
-    attach it removes the per-round host sync the chunked engine
-    pays.
-
-    ``sampled`` is STATIC: the greedy variant argmaxes everywhere;
-    the sampled variant draws the first token at the untagged stream
-    index 0, proposals from the draft's warped distribution
-    (DRAFT-tagged per-token streams), acceptance uniforms and the
-    residual/bonus draw from the ACC/RES-tagged streams — the same
-    key discipline as the host-loop scheme, so the emitted stream
-    keeps the exact target sampling distribution for any draft.
-
-    Compiled per ``(target, draft, prompt_width, n_tier, k,
-    sampled)``. ``p`` is the PROMPT WIDTH (a serving bucket: real
-    tokens right-aligned, ``n_pad`` left-pad slots masked — pass
-    zeros for an exact-length prompt) and ``n`` the OUTPUT TIER: the
-    jitted program additionally takes ``(n_pad [1] int32, n_actual
-    scalar int32)`` TRACED arguments and emits ``n_actual <= n``
-    tokens, so one compile per (bucket, tier) serves every request
-    budget — the serving engine's compile-count contract, honoured by
-    the fused path. Requires window headroom ``p + n + k + 1 <=
-    max_positions`` for both models (rounds never need plain-step
-    fallback: a budget-1 round emits exactly its final token via
-    ``usable = 0``).
-
-    Returns ``packed [n + 3]``: tokens (first ``n_actual`` valid)
-    then (rounds, accepted, drafted).
-    """
-    kw = k + 1
-    total_t = total_d = p + n + k + 1
-
-    def _run(t_params, d_params, prompt_ids, key_data, temps, topk,
-             topp, n_pad, n_actual):
-        from mlapi_tpu.models.gpt import _pick_token
-
-        key = jax.random.wrap_key_data(key_data[0])
-        t_cache, t_logits = target.prefill_core(
-            t_params, prompt_ids, n_pad, total_t
-        )
-        d_cache, _ = draft.prefill_core(
-            d_params, prompt_ids, n_pad, total_d
-        )
-        if sampled:
-            t0 = _pick_token(
-                temps, t_logits, key_data, 0, topk, topp
-            )[0]
-        else:
-            t0 = jnp.argmax(t_logits, axis=-1).astype(jnp.int32)[0]
-        out = jnp.zeros((n + kw,), jnp.int32).at[0].set(t0)
-
-        def body(s):
-            t_cache, d_cache, out, n_out, t_upto, d_upto, pend, n_pend = s
-
-            # Draft phase: consume the pending accepted tokens and
-            # chain k proposals (same schedule as propose_fn, with
-            # the pending width traced).
-            def dstep(carry, i):
-                d_cache, tok = carry
-                logits, d_cache = draft.decode_step(
-                    d_params, d_cache, tok[None, None], d_upto + i, n_pad
-                )
-                if sampled:
-                    probs = _warped_probs(logits, temps, topk, topp)
-                    prop_i = jnp.maximum(i - (n_pend - 1), 0) + n_out
-                    kk = jax.random.fold_in(
-                        jax.random.fold_in(key, _DRAFT_TAG), prop_i
-                    )
-                    nxt = jax.random.categorical(
-                        kk, jnp.log(probs[0])
-                    ).astype(jnp.int32)
-                else:
-                    probs = jnp.zeros((1, 0), jnp.float32)
-                    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)[0]
-                feed = jnp.where(
-                    i + 1 < n_pend, pend[jnp.minimum(i + 1, 1)], nxt
-                )
-                return (d_cache, feed), (nxt, probs[0])
-
-            (d_cache, _), (toks, qrows) = jax.lax.scan(
-                dstep, (d_cache, pend[0]), jnp.arange(kw)
-            )
-            j = (n_pend - 1) + jnp.arange(k)
-            props = toks[j]                       # [k]
-            d_upto = d_upto + n_pend + k - 1
-
-            # Verify: ONE target block forward over the LAST EMITTED
-            # token + proposals. `pend` is the DRAFT's pending list;
-            # its final entry (index n_pend - 1) is always the
-            # previous round's bonus — the target's own pending token
-            # (after a full round pend[0] is the draft's unfed k-th
-            # proposal, which must NOT head the verify block).
-            head = pend[n_pend - 1]
-            block = jnp.concatenate([head[None], props])[None]
-            t_cache, logits = target.extend_core(
-                t_params, t_cache, block, t_upto, n_pad,
-                jnp.int32(0), jnp.int32(0), all_logits=True,
-            )
-            usable = jnp.minimum(k, n_actual - n_out - 1)
-            if sampled:
-                q_probs = qrows[j]                # [k, V]
-                wide = lambda x: jnp.broadcast_to(x, (kw,))
-                pr = _warped_probs(
-                    logits[0], wide(temps[0]), wide(topk[0]),
-                    wide(topp[0]),
-                )
-                m, bonus = _accept_and_draw(
-                    key, pr, q_probs, props, usable, n_out
-                )
-            else:
-                expect = jnp.argmax(logits[0], axis=-1).astype(jnp.int32)
-                acc = (props == expect[:k]) & (jnp.arange(k) < usable)
-                m = jnp.argmin(
-                    jnp.concatenate(
-                        [acc, jnp.zeros((1,), bool)]
-                    ).astype(jnp.int32)
-                )
-                bonus = expect[m]
-            seg = jnp.where(
-                jnp.arange(kw) < m,
-                jnp.concatenate([props, jnp.zeros((1,), jnp.int32)]),
-                bonus,
-            )
-            out = jax.lax.dynamic_update_slice(out, seg, (n_out,))
-            t_upto = t_upto + m + 1
-            full = m == k
-            pend = jnp.where(
-                full,
-                jnp.stack([props[k - 1], bonus]),
-                jnp.stack([bonus, jnp.int32(0)]),
-            )
-            n_pend = jnp.where(full, jnp.int32(2), jnp.int32(1))
-            d_upto = jnp.where(full, d_upto, t_upto)
-            n_out = n_out + m + 1
-            return (
-                t_cache, d_cache, out, n_out, t_upto, d_upto, pend,
-                n_pend,
-            )
-
-        def cond2(s):
-            return s[0][3] < n_actual
-
-        def body2(s):
-            core, rounds, accepted, drafted = s
-            usable = jnp.minimum(k, n_actual - core[3] - 1)
-            nxt = body(core)
-            emitted = nxt[3] - core[3]
-            return (nxt, rounds + 1, accepted + emitted - 1,
-                    drafted + usable)
-
-        init = (
-            t_cache, d_cache, out, jnp.int32(1), jnp.int32(p),
-            jnp.int32(p), jnp.stack([t0, jnp.int32(0)]), jnp.int32(1),
-        )
-        (core, rounds, accepted, drafted) = jax.lax.while_loop(
-            cond2, body2, (init, jnp.int32(0), jnp.int32(0),
-                           jnp.int32(0))
-        )
-        # ONE packed readback: tokens + stats in a single transfer
-        # (separate scalar fetches each cost a full round trip).
-        return jnp.concatenate(
-            [core[2][:n], jnp.stack([rounds, accepted, drafted])]
-        )
-
-    return jax.jit(_run)
-
-
-@functools.lru_cache(maxsize=32)
-def fused_spec_batched_fn(target, draft, p: int, n: int, k: int,
-                          sampled: bool = False):
-    """The ENTIRE **batched** speculative generation as ONE XLA
-    program — the last cell of the fused matrix ({greedy, sampled} ×
-    {solo, batched} × {host-loop, fused}). Per-row cache positions
-    desynchronize immediately (row ``b`` advances ``m_b + 1`` slots a
-    round), which the rank-polymorphic decode/extend cores already
-    express: ``decode_step``/``extend_core`` take ``[B]`` position
-    vectors, cache writes vmap per row. Rows that exhaust their budget
-    FREEZE (``active`` mask pins their positions; their round writes
-    overwrite their own dead slots) until every row finishes, so the
-    loop trip count is the slowest row's. Through a high-RTT attach
-    this replaces the host batched loop's 2 dispatches per round
-    (~2·rounds·RTT per batch) with ONE dispatch + ONE packed readback.
-
-    Same compile-key/traced-argument discipline as
-    :func:`fused_spec_fn`: static ``(prompt_width, n_tier, k,
-    sampled)``; traced ``(n_pad [B], n_actual [B])``. Every row's
-    emitted stream is byte-identical to its SOLO fused run (greedy:
-    argmax-exact; sampled: per-row keys drive the same tagged
-    streams), which is what the tests pin.
-
-    Returns ``packed [B, n + 3]``: per-row tokens (first
-    ``n_actual[b]`` valid) then (rounds, accepted, drafted).
-    """
-    kw = k + 1
-    total = p + n + k + 1
-
-    def _run(t_params, d_params, prompt_ids, key_data, temps, topk,
-             topp, n_pad, n_actual):
-        from mlapi_tpu.models.gpt import _pick_token
-
-        b = prompt_ids.shape[0]
-        rows = jnp.arange(b)
-        keys = jax.vmap(jax.random.wrap_key_data)(key_data)
-        t_cache, t_logits = target.prefill_core(
-            t_params, prompt_ids, n_pad, total
-        )
-        d_cache, _ = draft.prefill_core(d_params, prompt_ids, n_pad, total)
-        if sampled:
-            t0 = _pick_token(temps, t_logits, key_data, 0, topk, topp)
-        else:
-            t0 = jnp.argmax(t_logits, axis=-1).astype(jnp.int32)
-        out = jnp.zeros((b, n + kw), jnp.int32).at[:, 0].set(t0)
-
-        def body(s):
-            (t_cache, d_cache, out, n_out, t_upto, d_upto, pend,
-             n_pend, rounds, accepted, drafted) = s
-            active = n_out < n_actual
-
-            def dstep(carry, i):
-                d_cache, tok = carry
-                logits, d_cache = draft.decode_step(
-                    d_params, d_cache, tok[:, None], d_upto + i, n_pad
-                )
-                if sampled:
-                    probs = _warped_probs(logits, temps, topk, topp)
-                    prop_i = jnp.maximum(i - (n_pend - 1), 0) + n_out
-                    nxt = jax.vmap(
-                        lambda kk, pi, pr: jax.random.categorical(
-                            jax.random.fold_in(
-                                jax.random.fold_in(kk, _DRAFT_TAG), pi
-                            ),
-                            jnp.log(pr),
-                        )
-                    )(keys, prop_i, probs).astype(jnp.int32)
-                else:
-                    probs = jnp.zeros((b, 0), jnp.float32)
-                    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                feed = jnp.where(
-                    i + 1 < n_pend,
-                    pend[rows, jnp.minimum(i + 1, 1)],
-                    nxt,
-                )
-                return (d_cache, feed), (nxt, probs)
-
-            (d_cache, _), (toks, qrows) = jax.lax.scan(
-                dstep, (d_cache, pend[:, 0]), jnp.arange(kw)
-            )
-            # Per-row proposal window: row b's k proposals start at
-            # its own pending offset (n_pend[b] - 1) in the scan.
-            props = jax.vmap(
-                lambda tb, o: jax.lax.dynamic_slice(tb, (o,), (k,))
-            )(toks.T, n_pend - 1)                        # [B, k]
-            d_upto_n = d_upto + jnp.where(active, n_pend + k - 1, 0)
-
-            head = pend[rows, n_pend - 1]
-            block = jnp.concatenate([head[:, None], props], axis=1)
-            t_cache, logits = target.extend_core(
-                t_params, t_cache, block, t_upto, n_pad,
-                jnp.int32(0), jnp.int32(0), all_logits=True,
-            )                                            # [B, kw, V]
-            usable = jnp.clip(
-                jnp.minimum(k, n_actual - n_out - 1), 0, k
-            )
-            if sampled:
-                q_probs = jax.vmap(
-                    lambda qb, o: jax.lax.dynamic_slice(
-                        qb, (o, 0), (k, qb.shape[-1])
-                    )
-                )(jnp.swapaxes(qrows, 0, 1), n_pend - 1)  # [B, k, V]
-                pr = jax.vmap(
-                    lambda lg, t, tk, tp: _warped_probs(
-                        lg, jnp.broadcast_to(t, (kw,)),
-                        jnp.broadcast_to(tk, (kw,)),
-                        jnp.broadcast_to(tp, (kw,)),
-                    )
-                )(logits, temps, topk, topp)              # [B, kw, V]
-                m, bonus = jax.vmap(_accept_and_draw)(
-                    keys, pr, q_probs, props, usable, n_out
-                )
-            else:
-                expect = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                acc = (props == expect[:, :k]) & (
-                    jnp.arange(k)[None, :] < usable[:, None]
-                )
-                m = jnp.argmin(
-                    jnp.concatenate(
-                        [acc, jnp.zeros((b, 1), bool)], axis=1
-                    ).astype(jnp.int32),
-                    axis=1,
-                )
-                bonus = expect[rows, m]
-            seg = jnp.where(
-                jnp.arange(kw)[None, :] < m[:, None],
-                jnp.concatenate(
-                    [props, jnp.zeros((b, 1), jnp.int32)], axis=1
-                ),
-                bonus[:, None],
-            )
-            out = jax.vmap(
-                lambda ob, sb, o: jax.lax.dynamic_update_slice(
-                    ob, sb, (o,)
-                )
-            )(out, seg, n_out)
-            adv = jnp.where(active, m + 1, 0)
-            t_upto_n = t_upto + adv
-            full = (m == k) & active
-            pend_n = jnp.where(
-                full[:, None],
-                jnp.stack([props[:, k - 1], bonus], axis=1),
-                jnp.stack([bonus, jnp.zeros((b,), jnp.int32)], axis=1),
-            )
-            n_pend_n = jnp.where(
-                active, jnp.where(full, 2, 1), n_pend
-            )
-            d_upto_n = jnp.where(full, d_upto_n, t_upto_n)
-            return (
-                t_cache, d_cache, out, n_out + adv, t_upto_n,
-                d_upto_n, pend_n, n_pend_n, rounds + 1,
-                accepted + jnp.where(active, m, 0),
-                drafted + jnp.where(active, usable, 0),
-            )
-
-        def cond(s):
-            return jnp.any(s[3] < n_actual)
-
-        init = (
-            t_cache, d_cache, out, jnp.ones((b,), jnp.int32),
-            jnp.full((b,), p, jnp.int32), jnp.full((b,), p, jnp.int32),
-            jnp.stack([t0, jnp.zeros((b,), jnp.int32)], axis=1),
-            jnp.ones((b,), jnp.int32), jnp.int32(0),
-            jnp.zeros((b,), jnp.int32), jnp.zeros((b,), jnp.int32),
-        )
-        s = jax.lax.while_loop(cond, body, init)
-        return jnp.concatenate(
-            [
-                s[2][:, :n],
-                jnp.broadcast_to(s[8], (b,))[:, None],
-                s[9][:, None],
-                s[10][:, None],
-            ],
-            axis=1,
-        )
-
-    return jax.jit(_run)
-
-
-def _fused_run(target, t_params, draft, d_params, prompt_ids,
-               max_new_tokens, k, sampled, key_data, temps, topk, topp):
-    """Shared validation + dispatch + packed-stats unpack for both
-    fused wrappers (the packed layout and the headroom formula live
-    in exactly one place)."""
-    b, p = prompt_ids.shape
-    if b != 1:
-        raise ValueError("speculative decoding is single-row (batch=1)")
-    if target.vocab_size != draft.vocab_size:
-        raise ValueError("draft and target must share a vocabulary")
-    n = int(max_new_tokens)
-    k = max(1, min(int(k), n))
-    total = p + n + k + 1
-    if total > target.max_positions or total > draft.max_positions:
-        raise ValueError(
-            f"fused speculation needs prompt + max_new_tokens + k + 1 "
-            f"(= {total}) cache slots within both model windows; use "
-            "the host-loop variant near the window edge"
-        )
-    packed = np.asarray(
-        fused_spec_fn(target, draft, p, n, k, sampled)(
-            t_params, d_params, jnp.asarray(prompt_ids), key_data,
-            temps, topk, topp, jnp.zeros((1,), jnp.int32),
-            jnp.int32(n),
-        )
-    )
-    stats = SpecStats(
-        rounds=int(packed[n]), drafted=int(packed[n + 2]),
-        accepted=int(packed[n + 1]), emitted=n,
-    )
-    return packed[:n].tolist(), stats
-
-
-def speculative_generate_fused(
-    target,
-    t_params,
-    draft,
-    d_params,
-    prompt_ids,
-    *,
-    max_new_tokens: int,
-    k: int = 4,
-) -> tuple[list[int], SpecStats]:
-    """Greedy speculative generation with the WHOLE loop on device
-    (:func:`fused_spec_fn`) — byte-identical to
-    :func:`speculative_generate` and plain target greedy decoding,
-    at one dispatch + one readback per generation."""
-    return _fused_run(
-        target, t_params, draft, d_params, prompt_ids,
-        max_new_tokens, k, False, _zero_key(),
-        jnp.zeros((1,), jnp.float32), jnp.zeros((1,), jnp.int32),
-        jnp.ones((1,), jnp.float32),
-    )
-
-
-def speculative_sample_fused(
-    target,
-    t_params,
-    draft,
-    d_params,
-    prompt_ids,
-    *,
-    max_new_tokens: int,
-    k: int = 4,
-    temperature: float = 1.0,
-    top_k: int = 0,
-    top_p: float = 1.0,
-    seed: int = 0,
-) -> tuple[list[int], SpecStats]:
-    """SAMPLED speculative generation with the WHOLE loop on device
-    (:func:`fused_spec_fn` with ``sampled=True``): one dispatch + one
-    packed readback per generation, emitted stream distributed
-    exactly as plain target sampling under the same warp for ANY
-    draft (the same acceptance-rejection scheme and tagged-stream
-    key discipline as :func:`speculative_sample`; the two are not
-    byte-identical only because the host loop serves budget-1 tails
-    with an untagged plain step while the fused loop uses a
-    ``usable = 0`` round — both draw from the full target
-    distribution). ``temperature <= 0`` delegates to the byte-exact
-    greedy :func:`speculative_generate_fused`."""
-    if temperature <= 0.0:
-        return speculative_generate_fused(
-            target, t_params, draft, d_params, prompt_ids,
-            max_new_tokens=max_new_tokens, k=k,
-        )
-    key_data = jnp.asarray(
-        np.asarray(jax.random.key_data(jax.random.key(seed)))[None]
-    )
-    return _fused_run(
-        target, t_params, draft, d_params, prompt_ids,
-        max_new_tokens, k, True, key_data,
-        jnp.asarray(np.asarray([temperature], np.float32)),
-        jnp.asarray(np.asarray([top_k], np.int32)),
-        jnp.asarray(np.asarray([top_p], np.float32)),
-    )
 
 
 def speculative_sample(

@@ -159,3 +159,21 @@ def fsdp_spec_tree(
         )
 
     return jax.tree.map(one, params, spec_tree, is_leaf=_is_quant_leaf)
+
+
+def bytes_per_device(tree) -> int:
+    """Max-over-devices of the bytes a pytree's shards occupy locally
+    (``addressable_shards[...].data.nbytes``) — the committed,
+    deterministic measure of the FSDP memory win (wall-clock on this
+    box swings ±25-30%; byte counts do not). A replicated leaf costs
+    its full ``nbytes`` on EVERY device; an fsdp-sharded leaf 1/axis
+    of it. Host numpy leaves count once (single-device placement)."""
+    per_dev: dict = {}
+    for leaf in jax.tree.leaves(tree):
+        shards = getattr(leaf, "addressable_shards", None)
+        if shards is not None:
+            for s in shards:
+                per_dev[s.device] = per_dev.get(s.device, 0) + s.data.nbytes
+        elif hasattr(leaf, "nbytes"):
+            per_dev[None] = per_dev.get(None, 0) + leaf.nbytes
+    return max(per_dev.values(), default=0)

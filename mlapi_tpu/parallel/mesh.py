@@ -267,7 +267,7 @@ def place_train_state(model, params, init_opt, mesh: Mesh, layout=None):
     Returns ``(params, opt_state, state_shardings)`` with
     ``state_shardings = (param_shardings, opt_shardings)`` — the ONE
     implementation of the "moments must be placed explicitly"
-    invariant, shared by ``fit``, the bench, and the multichip dryrun
+    invariant, shared by ``fit`` and the multichip dryrun
     so they cannot measure different memory layouts than training
     uses.
     """

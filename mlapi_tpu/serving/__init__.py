@@ -20,6 +20,6 @@ from mlapi_tpu.serving.engine import (  # noqa: F401
     TextClassificationEngine,
 )
 from mlapi_tpu.serving.registry import ModelRegistry, TenantLedger  # noqa: F401
-from mlapi_tpu.serving.scoring import MicroBatcher, ScorePath  # noqa: F401
+from mlapi_tpu.serving.scoring import ScorePath  # noqa: F401
 from mlapi_tpu.serving.router import Router, build_router_app  # noqa: F401
 from mlapi_tpu.serving.server import Server  # noqa: F401

@@ -6,7 +6,7 @@ fine-tuned variant. This module is the serving half of ROADMAP item
 3's many-tenant story: the base model's params stay resident ONCE,
 and each tenant contributes only its tiny ``(A, B)`` pair, so N
 resident tenants cost exactly ``base_bytes + N × slot_bytes`` (closed
-dtype/shape arithmetic, asserted in the bench — never wall-clock).
+dtype/shape arithmetic, asserted in the tests — never wall-clock).
 
 Three tiers, coldest to hottest, each generalizing an existing
 mechanism rather than inventing one:
@@ -99,7 +99,7 @@ class AdapterPoolPoisoned(RuntimeError):
 
 def adapter_bytes(payload: dict) -> int:
     """Exact adapter bytes from dtype/shape arithmetic — the closed
-    form every counter and the bench assert; never wall-clock."""
+    form every counter and the tests assert; never wall-clock."""
     return sum(
         int(np.prod(ab[k].shape)) * ab[k].dtype.itemsize
         for layer in payload.values()

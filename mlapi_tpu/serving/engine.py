@@ -913,7 +913,7 @@ class TextGenerationEngine:
         self.prefill_interleave = bool(prefill_interleave)
         # KV-cache storage format and decode-attention impl, owned by
         # the MODEL (program factories key on them); mirrored here for
-        # /metrics and bench.
+        # /metrics.
         self.kv_quant = getattr(model, "kv_quant", "none")
         self.decode_attn_impl = getattr(model, "decode_attn_impl", "einsum")
         self._kv_slot_bytes: int | None = None
@@ -1037,7 +1037,7 @@ class TextGenerationEngine:
         # a collector local — so drain()'s budget-exhausted sweep can
         # deliver their DrainCancelled frames too.
         self._carry: list = []
-        # Robustness counters (exported on /metrics + bench snapshot).
+        # Robustness counters (exported on /metrics).
         self.shed_queue_full = 0
         self.shed_deadline_infeasible = 0
         self.shed_draining = 0
@@ -1361,11 +1361,11 @@ class TextGenerationEngine:
         prefix-cache entry of this tier, and one spec mirror row each
         cost this much device HBM; ``kv_quant="int8"`` roughly
         halving it is the storage half of the int8-KV claim, reported
-        on ``/metrics`` and in the bench block. The READ half —
-        whether decode traffic actually shrinks — depends on the
-        decode impl too: see :meth:`decode_bytes_per_step`."""
+        on ``/metrics``. The READ half — whether decode traffic
+        actually shrinks — depends on the decode impl too: see
+        :meth:`decode_bytes_per_step`."""
         if self._kv_slot_bytes is None:
-            from mlapi_tpu.train.bench import bytes_per_device
+            from mlapi_tpu.parallel.layout import bytes_per_device
 
             total = self._cache_len(
                 self.prompt_buckets[-1], self.default_max_new_tokens
@@ -1379,10 +1379,9 @@ class TextGenerationEngine:
         """Modeled HBM bytes ONE decode step's attention read moves
         per slot at the default bucket/tier config — the number that
         makes the int8 READ saving observable in production
-        (``/metrics`` gauge ``generate.decode_bytes_per_step``), not
-        just in bench. Pure host arithmetic over abstract cache
-        shapes (``jax.eval_shape`` — no device allocation), so it is
-        exact and deterministic. The model, per (cache format,
+        (``/metrics`` gauge ``generate.decode_bytes_per_step``). Pure
+        host arithmetic over abstract cache shapes (``jax.eval_shape``
+        — no device allocation), so it is exact and deterministic. The model, per (cache format,
         ``decode_attn_impl``):
 
         - **flash**: the kernel streams the STORED tiles — int8
@@ -2514,7 +2513,7 @@ class TextGenerationEngine:
         req.rid = self.requests
         return req
 
-    # -- synchronous single-shot (tests, bench, CLI) -----------------------
+    # -- synchronous single-shot (tests, CLI) ------------------------------
     def generate_text(
         self,
         text: str,

@@ -1,30 +1,20 @@
-"""Fused-chunk width policy for generative serving (r20).
+"""Fused-chunk width policy for generative serving.
 
-One :class:`FusedSinglePath` per :class:`TextGenerationEngine`. Up to
-r15 this module dispatched a solo (or whole-batch) generation as ONE
-uninterruptible XLA program — the r03 RTT-floor lever — which meant
-declining deadlines (r12) and disaggregation (r18) at per-path gates
-and blocking every concurrent scheduler lane for a whole generation.
-r20 folds that dispatch saving into the typed-unit execution model
-instead: a fused-eligible batch decodes through the SAME
-``decode_chunk_fn`` the chunked path uses, just at TIER-WIDE chunk
-sizes, so each fused chunk is one ``"decode"`` unit yielded at
-``BatchRun.units()`` boundaries. Deadlines, speculation, brownout,
-faults, roles, and drain all apply to fused traffic through that one
-seam, and a concurrent lane's head-of-line stall drops from a whole
-generation to one fused-chunk dispatch
-(``engine.sched_lane_stall_max`` pins it from counters).
+One :class:`FusedSinglePath` per :class:`TextGenerationEngine`. A
+fused-eligible batch (no streaming consumer) decodes through the SAME
+``decode_chunk_fn`` the chunked path uses, at TIER-WIDE chunk sizes:
+each fused chunk is one ``"decode"`` unit yielded at
+``BatchRun.units()`` boundaries, so fewer dispatches carry a
+generation while deadlines, speculation, brownout, faults, roles and
+drain apply to it through that one seam. A concurrent lane's
+head-of-line stall is bounded by one fused-chunk dispatch
+(``engine.sched_lane_stall_max`` pins it from counters). Streams are
+byte-identical to plain-chunk decoding
+(``tests/test_serving_fused.py``).
 
-The retired whole-generation serving paths (``try_run`` /
-``try_run_batch`` and their warm grids) are measured against this
-fold in ``bench.py::_sched_report``:
-``generate_tier_fn`` / ``fused_spec_fn`` remain available as LIBRARY
-entry points (``ops/speculative.py``, ``models/gpt.py``) but the
-serving engine no longer routes requests to them.
+The policy:
 
-What remains here is the WIDTH POLICY:
-
-- :meth:`tiers` — the fused width ladder (unchanged from r03/r04).
+- :meth:`tiers` — the fused width ladder.
 - :meth:`chunk_width` — formation-time decision: the batch's top
   fused width, 0 to pin the plain ``eng.chunk``.
 - :meth:`width_at` — per-boundary width: shrinks to the smallest

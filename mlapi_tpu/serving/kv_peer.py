@@ -542,7 +542,7 @@ class KVPush:
                         xfer, chunk, num_chunks, span, kv
                     )
                     # Exact payload arithmetic (the closed form the
-                    # bench asserts) — header bytes excluded.
+                    # tests assert) — header bytes excluded.
                     nbytes = sum(
                         np.asarray(a).nbytes
                         for layer in kv.values()

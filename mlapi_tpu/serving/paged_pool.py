@@ -152,7 +152,7 @@ class PagePool:
         # behavior: eviction discards, restore never happens.
         self.tier = None
 
-    # -- accounting (read by /metrics and bench) -----------------------
+    # -- accounting (read by /metrics) ---------------------------------
     @property
     def pages_total(self) -> int:
         """Allocatable pages (the null page is bookkeeping, not

@@ -896,7 +896,7 @@ class Router:
         """The warm-peer hint for one forward: the key's HRW head
         whenever the target is NOT it (fallback, failover, depth
         overflow, post-drain remap — every hop that loses warmth).
-        Counted, so the bench/e2e can assert hinting happened from
+        Counted, so the e2e tests can assert hinting happened from
         the router side."""
         if pref is None or pref is target:
             return None

@@ -4,7 +4,7 @@ first-class typed unit.
 
 This module is the ONE batching implementation for classification and
 recsys models (r22; ROADMAP item 1). It folds the legacy ``/predict``
-``MicroBatcher`` (r2) onto the multi-model registry: same slot-first
+micro-batcher (r2) onto the multi-model registry: same slot-first
 collection loop, same straggler window, same deadline sweep, same
 drain/shed contract, same counters — plus two things the single-model
 batcher never had:
@@ -497,9 +497,3 @@ class ScorePath:
         for f, label, prob in zip(futures, labels, probs):
             if not f.done():
                 f.set_result((label, float(prob)))
-
-
-# r22 fold: the single-model ``MicroBatcher`` became the multi-model
-# ScorePath (serving/batcher.py is gone). The alias keeps external
-# imports working one release; new code names ScorePath.
-MicroBatcher = ScorePath

@@ -8,7 +8,9 @@ the live cache and host mirrors at a round boundary and resumes
 chunked decoding from whatever ``(cache, pos)`` comes back; yield
 discipline routes through ``engine._spec_should_yield`` (tests
 monkeypatch it there). Split out of ``engine.py`` (r04 VERDICT
-"Next" #7). The library twins live in ``ops/speculative.py``.
+"Next" #7). This is one of two implementations of speculation: the
+host-loop library in ``ops/speculative.py`` is the other, builds the
+round programs both run, and is what the tests hold to plain decoding.
 """
 
 from __future__ import annotations

@@ -42,8 +42,8 @@ def run(
     from mlapi_tpu.datasets import get_dataset
     from mlapi_tpu.models import get_model
     from mlapi_tpu.parallel import initialize_from_env, mesh_for_config
+    from mlapi_tpu.parallel.layout import bytes_per_device
     from mlapi_tpu.train import fit
-    from mlapi_tpu.train.bench import bytes_per_device
     from mlapi_tpu.utils.platform import device_report
 
     initialize_from_env()  # multi-host no-op on a single host
@@ -236,31 +236,11 @@ def main(argv=None) -> None:
     apply_platform_override()
     enable_compile_cache()
     parser = argparse.ArgumentParser("mlapi_tpu.train")
-    group = parser.add_mutually_exclusive_group()
+    group = parser.add_mutually_exclusive_group(required=True)
     group.add_argument(
         "--preset", choices=preset_names(), help="a ladder config by name"
     )
     group.add_argument("--config", help="path to a TrainConfig YAML")
-    parser.add_argument(
-        "--bench", action="store_true",
-        help="measure step time / examples/s / MFU on the attached "
-             "backend (one JSON line per preset; combine with --preset "
-             "to bench one config) instead of training",
-    )
-    parser.add_argument(
-        "--bench-steps", type=int, default=10,
-        help="measured steps per preset in --bench mode",
-    )
-    parser.add_argument(
-        "--bench-batch", type=int, default=None,
-        help="override the preset's batch size in --bench mode (MFU "
-             "sweeps: run once per batch size)",
-    )
-    parser.add_argument(
-        "--bench-attn", choices=["full", "flash", "ring"], default=None,
-        help="override the preset's attention_impl in --bench mode "
-             "(flash-vs-full MFU controls)",
-    )
     parser.add_argument("--out", help="checkpoint output dir")
     parser.add_argument(
         "--steps", type=int, default=None, help="override config steps"
@@ -271,8 +251,7 @@ def main(argv=None) -> None:
              "'8,1' = pure DP, '2,4' = DP x TP, and THREE dims "
              "'d,f,m' add a ZeRO/FSDP axis — e.g. '1,8,1' shards "
              "params AND optimizer moments over 8 devices "
-             "(per-device state bytes drop ~8x; same math). Works "
-             "with --bench for memory sweeps",
+             "(per-device state bytes drop ~8x; same math)",
     )
     parser.add_argument(
         "--save-every", type=int, default=0,
@@ -344,35 +323,6 @@ def main(argv=None) -> None:
                 f"--mesh-shape {args.mesh_shape!r}: need 2 (data,model) "
                 "or 3 (data,fsdp,model) positive dimensions"
             )
-
-    if args.bench:
-        from mlapi_tpu.train.bench import DEFAULT_BENCH_PRESETS, bench_train
-
-        if args.config:
-            targets = [TrainConfig.from_yaml(args.config)]
-        elif args.preset:
-            targets = [args.preset]
-        else:
-            targets = [p for p in DEFAULT_BENCH_PRESETS if p in preset_names()]
-        for t in targets:
-            if args.bench_attn is not None:
-                import dataclasses
-
-                cfg_t = get_preset(t) if isinstance(t, str) else t
-                t = dataclasses.replace(
-                    cfg_t,
-                    model_kwargs={**cfg_t.model_kwargs,
-                                  "attention_impl": args.bench_attn},
-                )
-            row = bench_train(
-                t, bench_steps=args.bench_steps,
-                batch_size=args.bench_batch,
-                mesh_shape=mesh_shape,
-            )
-            print(json.dumps(row))
-        return
-    if not args.preset and not args.config:
-        parser.error("need --preset, --config, or --bench")
 
     cfg = get_preset(args.preset) if args.preset else TrainConfig.from_yaml(args.config)
     import dataclasses

@@ -1,9 +1,9 @@
 """In-process metrics: counters and latency histograms.
 
-Backs the serving layer's ``/metrics`` endpoint and the bench harness
-(the ``BASELINE.json`` north-star metric is requests/sec/chip and p50
-latency on ``/predict`` — this is where those numbers come from at
-runtime). The reference has no metrics at all (SURVEY §5).
+Backs the serving layer's ``/metrics`` endpoint (the ``BASELINE.json``
+north-star metric is requests/sec/chip and p50 latency on ``/predict``
+— this is where those numbers come from at runtime). The reference has
+no metrics at all (SURVEY §5).
 
 Thread-safe enough for the serving model: the event loop plus the
 batcher's single dispatch thread. Quantiles come from a reservoir
@@ -27,9 +27,8 @@ from dataclasses import dataclass, field
 
 
 def nearest_rank(values: list[float], q: float) -> float | None:
-    """Nearest-rank quantile over unsorted values (shared by the
-    serving histograms and the load generator so both report identical
-    semantics)."""
+    """Nearest-rank quantile over unsorted values: what every
+    :class:`Histogram` quantile is."""
     if not values:
         return None
     ordered = sorted(values)
@@ -148,8 +147,8 @@ _annotations = None  # (TraceAnnotation, StepTraceAnnotation), on first use
 
 def _annotation_types():
     """``jax.profiler``'s two annotation classes, imported on first use:
-    this module stays importable without jax (the load generator and
-    the benchmark read ``nearest_rank`` from it)."""
+    this module stays importable without jax, so a process that only
+    counts never pays for the import."""
     global _annotations
     if _annotations is None:
         from jax.profiler import StepTraceAnnotation, TraceAnnotation

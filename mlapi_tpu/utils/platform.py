@@ -5,9 +5,8 @@ where compiled programs are cached, and who decides ``interpret``.
 this package's own spelling of the same choice, applied by the CLIs
 that call :func:`apply_platform_override`: the ``--workers`` and
 ``--router`` supervisors set it to ``cpu`` in their children's
-environment (a chip belongs to one process), the tests and
-``bench.py`` set it on spawned servers, and an operator may set it by
-hand. It wins over ``JAX_PLATFORMS`` because it is applied to the
+environment (a chip belongs to one process), the tests set it on
+spawned servers, and an operator may set it by hand. It wins over ``JAX_PLATFORMS`` because it is applied to the
 config after import.
 """
 

@@ -131,7 +131,6 @@ _CACHE_FAMILIES = {
     "spec-family": frozenset({
         "test_speculative",
         "test_speculative_batched",
-        "test_speculative_fused",
         "test_speculative_sampling",
         "test_spec_batched_serving",
     }),

@@ -9,7 +9,7 @@ import threading
 import numpy as np
 import pytest
 
-from mlapi_tpu.serving.scoring import MicroBatcher
+from mlapi_tpu.serving.scoring import ScorePath
 
 pytestmark = pytest.mark.anyio
 
@@ -38,7 +38,7 @@ async def test_coalesces_to_ceil_n_over_b():
     # the queue first, making the coalescing count deterministic even
     # on a heavily loaded host (this test used to flake under CPU
     # contention when collection raced the submits).
-    batcher = MicroBatcher(
+    batcher = ScorePath(
         engine, max_batch=16, max_wait_ms=5.0, max_inflight=1
     )
     await batcher.start()
@@ -72,7 +72,7 @@ async def test_coalesces_to_ceil_n_over_b():
 
 async def test_single_request_low_latency_path():
     engine = FakeEngine()
-    batcher = MicroBatcher(engine, max_wait_ms=0.0)
+    batcher = ScorePath(engine, max_wait_ms=0.0)
     await batcher.start()
     try:
         label, prob = await batcher.submit(np.full(4, 7.0))
@@ -88,7 +88,7 @@ async def test_engine_error_propagates_to_caller():
         def predict_labels(self, batch):
             raise RuntimeError("device exploded")
 
-    batcher = MicroBatcher(BoomEngine(), max_wait_ms=0.0)
+    batcher = ScorePath(BoomEngine(), max_wait_ms=0.0)
     await batcher.start()
     try:
         with pytest.raises(RuntimeError, match="device exploded"):
@@ -100,6 +100,6 @@ async def test_engine_error_propagates_to_caller():
 
 
 async def test_submit_before_start_rejected():
-    batcher = MicroBatcher(FakeEngine())
+    batcher = ScorePath(FakeEngine())
     with pytest.raises(RuntimeError, match="not started"):
         await batcher.submit(np.zeros(4))

@@ -154,17 +154,3 @@ def test_compile_cache_defaults_to_one_fixed_path_in_the_checkout(
     assert platform.enable_compile_cache() == want  # same path every call
     ignored = (ROOT / ".gitignore").read_text().splitlines()
     assert ".jax_compile_cache/" in ignored
-
-
-# -- the peaks table ------------------------------------------------------
-def test_peak_for_raises_on_an_unknown_device_kind():
-    from types import SimpleNamespace
-
-    from mlapi_tpu.train.bench import _PEAK_BW, _peak_for
-
-    assert _peak_for(SimpleNamespace(device_kind="TPU v5 lite")) == 197e12
-    assert _peak_for(SimpleNamespace(device_kind="TPU v5 lite"),
-                     _PEAK_BW) == 819e9
-    for kind in ("TPU v5", "TPU v9", "", "cpu", None):
-        with pytest.raises(KeyError, match="no published peak"):
-            _peak_for(SimpleNamespace(device_kind=kind))

@@ -119,14 +119,3 @@ async def test_connection_close_honored(server):
         r = await client.get("/ping", headers={"connection": "close"})
         assert r.status_code == 200
         assert r.headers["connection"] == "close"
-
-
-async def test_loadgen_against_server(server):
-    from mlapi_tpu.serving.loadgen import run_load
-
-    result = await run_load(
-        "127.0.0.1", server.port, "/ping", concurrency=8, duration_s=0.5
-    )
-    assert result.errors == 0
-    assert result.requests > 50
-    assert result.quantile(0.5) < 50.0

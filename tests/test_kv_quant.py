@@ -33,9 +33,9 @@ from mlapi_tpu.ops.quant import (
     kv_quantize,
     maybe_dequant_kv,
 )
+from mlapi_tpu.parallel.layout import bytes_per_device
 from mlapi_tpu.serving.engine import TextGenerationEngine
 from mlapi_tpu.text import ByteTokenizer
-from mlapi_tpu.train.bench import bytes_per_device
 
 # Tiny fast config for path coverage (f32 compute: the cache baseline
 # is f32, ratio ~3.2x at D=16).

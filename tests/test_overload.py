@@ -18,7 +18,7 @@ import pytest
 
 from mlapi_tpu.models import get_model
 from mlapi_tpu.serving import InferenceEngine, build_app
-from mlapi_tpu.serving.scoring import MicroBatcher, OverloadedError
+from mlapi_tpu.serving.scoring import OverloadedError, ScorePath
 from mlapi_tpu.serving.engine import TextGenerationEngine
 from mlapi_tpu.text import ByteTokenizer
 from mlapi_tpu.utils.vocab import LabelVocab
@@ -76,7 +76,7 @@ async def test_batcher_sheds_fast_when_queue_full():
     excess requests fail in milliseconds — not after a timeout."""
     eng = FakeEngine()
     eng.gate.clear()  # device "wedged": nothing completes
-    b = MicroBatcher(
+    b = ScorePath(
         eng, max_batch=4, max_wait_ms=0.0, max_queue=8, max_inflight=1
     )
     await b.start()

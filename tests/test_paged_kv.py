@@ -18,8 +18,7 @@ The contract these tests pin, layer by layer:
   prefixes (whose pages are ref-shared, diverging by COW, never
   copied per row).
 - **The capacity model**: padding waste and slot capacity come from
-  dtype/shape arithmetic (never wall-clock), matching what
-  ``BENCH_GEN_PAGED`` publishes.
+  dtype/shape arithmetic (never wall-clock).
 """
 
 import asyncio
@@ -515,7 +514,7 @@ async def test_metrics_exports_page_pool_gauges(gpt_params):
 
 
 def test_capacity_model_exact_arithmetic(gpt_params):
-    """The BENCH_GEN_PAGED claim, pinned from shapes alone: pool bytes
+    """The paged-capacity claim, pinned from shapes alone: pool bytes
     per token equal contiguous bytes per token (paging adds
     indirection, not byte overhead), so any sequence shorter than its
     tier strictly beats the contiguous slot — waste bounded by one

@@ -32,11 +32,7 @@ import pytest
 
 from mlapi_tpu.models import get_model
 from mlapi_tpu.serving import build_app, faults
-from mlapi_tpu.serving.scoring import (
-    MicroBatcher,
-    OverloadedError,
-    ScorePath,
-)
+from mlapi_tpu.serving.scoring import OverloadedError, ScorePath
 from mlapi_tpu.serving.engine import TextGenerationEngine, _SyncSink
 from mlapi_tpu.serving.paged_pool import PagePoolExhausted
 from mlapi_tpu.serving.requests import DeadlineExceeded, DrainCancelled
@@ -484,7 +480,7 @@ async def test_microbatcher_drain_budget_sheds_queued_503():
     from tests.test_batcher import FakeEngine
 
     eng = FakeEngine()
-    b = MicroBatcher(eng, max_batch=4, max_wait_ms=0.0, max_inflight=1)
+    b = ScorePath(eng, max_batch=4, max_wait_ms=0.0, max_inflight=1)
     await b.start()
     try:
         row = np.zeros(4, np.float32)
@@ -555,7 +551,7 @@ async def test_microbatcher_drain_and_deadline():
     from tests.test_batcher import FakeEngine
 
     eng = FakeEngine()
-    b = MicroBatcher(eng, max_batch=4, max_wait_ms=0.0, max_inflight=1)
+    b = ScorePath(eng, max_batch=4, max_wait_ms=0.0, max_inflight=1)
     await b.start()
     try:
         row = np.zeros(4, np.float32)

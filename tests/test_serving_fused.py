@@ -1,13 +1,10 @@
 """Fused-chunk widths as typed units (r20).
 
-Up to r15 a fused-eligible request ran as ONE uninterruptible XLA
-program (``generate_tier_fn`` / ``fused_spec_fn``) behind per-path
-decline gates (deadlines, streams, joiners, disagg). r20 folds the
-dispatch saving into the one execution model: a fused-eligible batch
-decodes TIER-WIDE chunks through the same ``decode_chunk_fn`` seam,
-each fused chunk one schedulable ``"decode"`` unit — so deadlines,
-admission, faults and drain apply to fused traffic with no parallel
-path left to diverge. This module pins the fold's contract:
+A fused-eligible batch decodes TIER-WIDE chunks through the same
+``decode_chunk_fn`` seam as every other batch, each fused chunk one
+schedulable ``"decode"`` unit — so deadlines, admission, faults and
+drain apply to fused traffic with no parallel path to diverge. This
+module pins the width policy's contract:
 
 - byte-identity: fused widths change dispatch count, never tokens;
 - engagement: ``fused_calls`` ticks once per batch that dispatched at

@@ -9,7 +9,6 @@ import asyncio
 
 import httpx
 import jax
-import numpy as np
 import pytest
 
 from mlapi_tpu.models import get_model
@@ -150,48 +149,3 @@ async def test_generate_over_http_on_2x4_mesh(gpt_and_params, mesh_2x4):
             assert m["generate.fused_calls"] >= 1
     finally:
         await app.shutdown()
-
-
-def test_fused_batched_spec_on_mesh(gpt_and_params, mesh_1x4):
-    """The apex program: an ENTIRE batched speculative generation —
-    draft scan, verify, per-row acceptance, desynchronized cache
-    algebra — as one GSPMD-partitioned XLA program on a TP mesh,
-    byte-identical per row to the unsharded solo fused run."""
-    import jax.numpy as jnp
-
-    from mlapi_tpu.ops.speculative import (
-        fused_spec_batched_fn,
-        speculative_generate_fused,
-    )
-    from mlapi_tpu.parallel import params_for_model
-
-    model, params = gpt_and_params
-    draft = get_model("gpt_lm", **D_CFG)
-    dp = draft.init(jax.random.key(1))
-    tps = params_for_model(model, params, mesh_1x4)
-    dps = params_for_model(draft, dp, mesh_1x4)
-    B, P, tier, k = 2, 12, 16, 4
-    rows = np.zeros((B, P), np.int32)
-    rows[0, -6:] = np.arange(6) + 10
-    rows[1, -9:] = (np.arange(9) * 7) % 200 + 4
-    pads = np.asarray([6, 3], np.int32)
-    kd = np.stack([
-        np.asarray(jax.random.key_data(jax.random.key(s)))
-        for s in range(B)
-    ])
-    budgets = np.asarray([10, 4], np.int32)
-    packed = np.asarray(
-        fused_spec_batched_fn(model, draft, P, tier, k, False)(
-            tps, dps, jnp.asarray(rows), jnp.asarray(kd),
-            jnp.zeros((B,), jnp.float32), jnp.zeros((B,), jnp.int32),
-            jnp.ones((B,), jnp.float32), jnp.asarray(pads),
-            jnp.asarray(budgets),
-        )
-    )
-    for i in range(B):
-        n = int(budgets[i])
-        solo = rows[i, pads[i]:][None]
-        want, _ = speculative_generate_fused(
-            model, params, draft, dp, solo, max_new_tokens=n, k=k,
-        )
-        assert packed[i, :n].tolist() == want, i

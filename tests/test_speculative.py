@@ -55,22 +55,27 @@ def _train_repeater(model, seed=0):
     return params
 
 
+@pytest.mark.parametrize("budget", ["round", "ragged", "one"])
 @pytest.mark.parametrize("k", [1, 3, 5])
-def test_stream_equals_plain_greedy_random_models(k):
+def test_stream_equals_plain_greedy_random_models(k, budget):
     """Exactness holds regardless of draft quality: random draft +
     random target — every emitted token is the target's greedy
-    choice."""
+    choice, whether the budget is whole rounds of ``k + 1``, ends in
+    a budget-capped round, or is a single token."""
+    n = {"round": 4 * (k + 1), "ragged": 4 * (k + 1) + 1, "one": 1}[budget]
     target = get_model("gpt_lm", **T_CFG)
     draft = get_model("gpt_lm", **D_CFG)
     tp = target.init(jax.random.key(0))
     dp = draft.init(jax.random.key(1))
     prompt = np.arange(9, dtype=np.int32)[None] % 200 + 3
-    ref = _greedy_ref(target, tp, prompt, 24)
+    # One reference program for every case: greedy decoding's first n
+    # tokens do not depend on how many follow.
+    ref = _greedy_ref(target, tp, prompt, 25)[:n]
     got, stats = speculative_generate(
-        target, tp, draft, dp, prompt, max_new_tokens=24, k=k,
+        target, tp, draft, dp, prompt, max_new_tokens=n, k=k,
     )
-    assert got == ref, (k, stats)
-    assert stats.emitted + stats.fallback_steps + 1 == 24
+    assert got == ref, (k, n, stats)
+    assert stats.emitted + stats.fallback_steps + 1 == n
 
 
 def test_draft_equals_target_accepts_everything():

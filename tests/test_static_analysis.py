@@ -61,7 +61,6 @@ def fixture_config(**overrides) -> Config:
         production_prefix=f"{FIXTURES}prod/",
         serving_prefix=f"{FIXTURES}prod/",
         test_prefix=f"{FIXTURES}t/",
-        bench_files=(),
         doc_files=(f"{FIXTURES}fx_docs.md",),
         async_pure_modules=(f"{FIXTURES}prod/fx_router.py",),
         baseline_file=f"{FIXTURES}fx_baseline.txt",

@@ -346,7 +346,6 @@ DEFAULT_PY_GLOBS = (
     "mlapi_tpu/**/*.py",
     "tests/**/*.py",
     "tools/**/*.py",
-    "bench.py",
 )
 # The fixtures are DELIBERATE violations (the negative tests); the
 # clean-tree run must not see them. datasets/docs_corpus holds
@@ -371,7 +370,6 @@ class Config:
     serving_prefix: str = "mlapi_tpu/serving/"
     # Where fault-matrix coverage and metric scrapes are read from.
     test_prefix: str = "tests/"
-    bench_files: tuple[str, ...] = ("bench.py",)
     doc_files: tuple[str, ...] = ("README.md", "docs/DESIGN.md")
     async_pure_modules: tuple[str, ...] = ASYNC_PURE_MODULES
     lock_registry: dict = field(

@@ -9,8 +9,7 @@ based asserts (engine/scheduler counters, fault counts, trace
 contents), which are deterministic at any machine speed.
 
 Flags, in tier-1 test files (functions NOT marked ``slow`` or
-``heavy`` — soak tests may time themselves) and in bench assert
-paths:
+``heavy`` — soak tests may time themselves):
 
 - an ``assert`` whose comparison reads a wall-clock source directly
   (``time.time()``, ``time.perf_counter()``, ``time.monotonic()``,
@@ -73,11 +72,9 @@ class TestHygieneRule:
     def run(self, proj, cfg):
         findings: list[Finding] = []
         for sf in proj.files:
-            is_test = sf.path.startswith(cfg.test_prefix)
-            is_bench = sf.path in cfg.bench_files
-            if not (is_test or is_bench) or sf.tree is None:
+            if not sf.path.startswith(cfg.test_prefix) or sf.tree is None:
                 continue
-            if is_test and _module_exempt(sf.tree):
+            if _module_exempt(sf.tree):
                 continue
             for func in sf.tree.body:
                 if not isinstance(

@@ -19,12 +19,12 @@ Sets computed per run:
   summary key (the f-string export loop), and the configured
   dynamic prefixes (``replica.``/``router.``/``http.`` — relabeled
   or route-labeled at runtime).
-- **Scraped**: metric-shaped strings in tests/ and bench.py.
+- **Scraped**: metric-shaped strings in tests/.
 - **Documented**: metric-shaped tokens in README.md / DESIGN.md.
 
 Checks: every scraped and every documented name must be satisfied by
-the exported set — exactly, as a prefix of an exported name (bench
-filters on prefixes like ``generate.sched_``), or under a dynamic
+the exported set — exactly, as a prefix of an exported name (tests
+filter on prefixes like ``generate.sched_``), or under a dynamic
 prefix. Findings anchor at the scrape/doc line, because that is
 where the drift is fixable.
 """
@@ -113,7 +113,7 @@ def _satisfied(name: str, exported: set[str]) -> bool:
         return True
     if name in exported:
         return True
-    # A scraped/documented PREFIX (bench family filters, README's
+    # A scraped/documented PREFIX (family filters, README's
     # `generate.shed_` rows, brace shorthand truncated at `{`) is
     # satisfied by an exported name under it — but only at a real
     # name boundary (`_`, `.`, or a digit, the brace-expansion
@@ -145,13 +145,8 @@ class MetricsRule:
             return []  # nothing exports metrics in this scan set
         findings: list[Finding] = []
 
-        # Scrapes: tests + bench.
-        scrape_files = [
-            f for f in proj.files
-            if f.path.startswith(cfg.test_prefix)
-            or f.path in cfg.bench_files
-        ]
-        for sf in scrape_files:
+        # Scrapes: tests.
+        for sf in proj.matching(cfg.test_prefix):
             if sf.tree is None:
                 continue
             seen: set[tuple[str, int]] = set()
