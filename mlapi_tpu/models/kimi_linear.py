@@ -72,9 +72,11 @@ from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from mlapi_tpu.models import register_model
 from mlapi_tpu.ops.pallas import kda as kda_kernels
+from mlapi_tpu.ops.pallas.flash_attention import REMAT_NAMES as _FLASH_NAMES
 from mlapi_tpu.utils.metrics import REGISTRY
 from mlapi_tpu.utils.platform import pallas_interpret
 
@@ -84,6 +86,12 @@ from mlapi_tpu.utils.platform import pallas_interpret
 # chunks of 32 (PERF.md, PR 29): a group's temporaries should stay small.
 _GROUP = 128
 _HI = jax.lax.Precision.HIGHEST
+# What _moe names for a recomputing block to keep: the router's scores
+# and the routing plan (a HIGHEST product, a top_k over every expert, a
+# stable sort and a scatter of every pair; none of it differentiated but
+# the sigmoid, 9 MB a layer at the published widths).
+_ROUTE_NAMES = ("moe.s", "moe.idx", "moe.rows", "moe.tile_expert",
+                "moe.n_tiles", "moe.counts")
 
 
 def _rms_norm(x, scale, eps):
@@ -439,7 +447,10 @@ class KimiLinearLM:
     rms_norm_eps: float = 1e-5
     compute_dtype: str = "bfloat16"
     # every block under jax.checkpoint (what 8,192 positions at the
-    # published widths need beside 9.6 GB of state)
+    # published widths need beside 9.6 GB of state). A block's
+    # recomputation keeps what the delta-rule and flash forward kernels
+    # and the router made (the names their producers set), so those run
+    # once a step; everything else is made again from the block's input.
     remat: bool = True
     mesh: object = None
 
@@ -608,11 +619,13 @@ class KimiLinearLM:
                 x2, p["router"].astype(jnp.float32), precision=_HI))
             _, idx = jax.lax.top_k(
                 s + jax.lax.stop_gradient(p["router_bias"]), k)
+            s, idx, rows, tile_expert, n_tiles, counts = map(
+                checkpoint_name,
+                (s, idx, *_plan(idx, first, count, self.moe_tile)),
+                _ROUTE_NAMES)
             chosen = jnp.take_along_axis(s, idx, axis=1)
             w = (chosen / jnp.sum(chosen, axis=-1, keepdims=True)
                  * self.routed_scaling_factor)
-            rows, tile_expert, n_tiles, counts = _plan(
-                idx, first, count, self.moe_tile)
         with jax.named_scope("moe.experts"):
             e = p["experts"]
             y = grouped_ffn(
@@ -650,10 +663,14 @@ class KimiLinearLM:
         x = params["embed"][token_ids].astype(jnp.float32)
         here = fullest = jnp.zeros((), jnp.int32)
         uneven = jnp.zeros((), jnp.float32)
+        # a recomputed block keeps what its kernels and its router made
+        # (the producers name it; docs/DESIGN.md section 30)
+        keep = jax.checkpoint_policies.save_only_these_names(
+            *kda_kernels.REMAT_NAMES, *_FLASH_NAMES, *_ROUTE_NAMES)
         for n, kinds in enumerate(self.layer_kinds):
             block = functools.partial(self._block, kinds)
             if self.remat:
-                block = jax.checkpoint(block)
+                block = jax.checkpoint(block, policy=keep)
             x, (pairs, top) = block(params[f"layer_{n}"], x)
             here, fullest = here + pairs, jnp.maximum(fullest, top)
             uneven = jnp.maximum(

@@ -77,6 +77,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -979,11 +980,19 @@ def _flash(q, k, v, mask, causal, scale, block_q, block_k, interpret,
     )
 
 
+# What a recomputing caller should keep of a differentiated call: the
+# forward's two results as the rule returns them (``jax.checkpoint``
+# with ``save_only_these_names(*REMAT_NAMES)`` then runs the forward
+# kernel once a step). Under no checkpoint, or a bare one, the names
+# are identities that lower to nothing.
+REMAT_NAMES = ("flash.out", "flash.lse")
+
+
 def _flash_fwd(q, k, v, mask, causal, scale, block_q, block_k, interpret,
                window=None):
-    out, lse = _fwd(
+    out, lse = map(checkpoint_name, _fwd(
         q, k, v, mask, causal, scale, block_q, block_k, interpret, window
-    )
+    ), REMAT_NAMES)
     return (out, lse), (q, k, v, mask, out, lse)
 
 

@@ -57,6 +57,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -454,8 +455,16 @@ def _kda(q, k, v, g, beta, c, dt, interpret):
     return _forward(q, k, v, g, beta, c, dt, interpret, save=False)[0]
 
 
+# What a recomputing caller should keep of a differentiated call: the
+# forward kernel's three results (``jax.checkpoint`` with
+# ``save_only_these_names(*REMAT_NAMES)`` then runs ``kda_fwd`` once a
+# step). The operands are not named: they are cheap to make again.
+REMAT_NAMES = ("kda.o", "kda.states", "kda.t")
+
+
 def _kda_fwd(q, k, v, g, beta, c, dt, interpret):
     o, saved = _forward(q, k, v, g, beta, c, dt, interpret, save=True)
+    o, *saved = map(checkpoint_name, (o, *saved), REMAT_NAMES)
     return o, (q, k, v, g, beta, *saved)
 
 

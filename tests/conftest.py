@@ -278,6 +278,31 @@ def mesh_1x4():
     return create_mesh((1, 4), devices=_jax.devices()[:4])
 
 
+@pytest.fixture(scope="session")
+def count_primitives():
+    """``count_primitives(jaxpr) -> Counter``: how often each primitive
+    runs in a jaxpr, every sub-program counted where it is called; a
+    Pallas call under its kernel's name (``name=`` or the kernel
+    function's)."""
+    import collections
+
+    def count(jaxpr, counts=None):
+        counts = collections.Counter() if counts is None else counts
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                counts[eqn.params["name"]
+                       or eqn.params["jaxpr"].debug_info.func_name] += 1
+                continue
+            counts[eqn.primitive.name] += 1
+            for sub in jax.tree.leaves(
+                    eqn.params, is_leaf=lambda v: hasattr(v, "eqns")):
+                if hasattr(sub, "eqns"):
+                    count(getattr(sub, "jaxpr", sub), counts)
+        return counts
+
+    return count
+
+
 def _armed_witness():
     """One arming protocol for both witness fixtures: install the
     runtime lock-order witness (tools/lint/witness.py, the dynamic

@@ -608,6 +608,51 @@ def test_multi_tile_sequences_keep_the_streaming_kernels():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
 
 
+@pytest.mark.parametrize("wrap", ["no_checkpoint", "bare_checkpoint"])
+def test_names_on_the_forward_results_lower_to_nothing(
+        monkeypatch, count_primitives, wrap):
+    """``_flash_fwd`` names ``out`` and ``lse`` for a recomputing caller
+    whose ``jax.checkpoint`` has a policy (``models/kimi_linear.py``).
+    A differentiated call under no checkpoint (BERT's step), or under a
+    bare one, is what it was without them: the same lowered program
+    text with the names taken out, so the same gradients. A bare
+    checkpoint keeps nothing and runs the forward kernel twice."""
+    import importlib
+    import re
+
+    fa = importlib.import_module("mlapi_tpu.ops.pallas.flash_attention")
+    ks = jax.random.split(jax.random.key(43), 4)
+    q, k, v, w = (jax.random.normal(x, (2, 128, 2, 64)) for x in ks)
+    mask = jnp.asarray(np.arange(128)[None, :] < np.array([[128], [70]]),
+                       jnp.float32)
+
+    def lowered():
+        def attend(q, k, v):   # the un-jitted body: no cached trace
+            return fa.flash_attention.__wrapped__(
+                q, k, v, mask, interpret=True)
+
+        if wrap == "bare_checkpoint":
+            attend = jax.checkpoint(attend)
+        grad = jax.jit(jax.grad(
+            lambda *a: jnp.sum(attend(*a) * w), argnums=(0, 1, 2)))
+        # the interpreter's helper functions carry a running number
+        text = re.sub(r"@(\w+?)_\d+\b", r"@\1",
+                      grad.lower(q, k, v).as_text())
+        counts = count_primitives(jax.make_jaxpr(grad)(q, k, v).jaxpr)
+        return text, grad(q, k, v), counts["_fwd_rows_kernel"]
+
+    text, got, n_forward = lowered()
+    assert n_forward == (2 if wrap == "bare_checkpoint" else 1)
+    named = []
+    monkeypatch.setattr(
+        fa, "checkpoint_name", lambda x, name: named.append(name) or x)
+    plain_text, want, _ = lowered()
+    assert named[:2] == list(fa.REMAT_NAMES)   # the patch was traced
+    assert text == plain_text
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 @pytest.mark.parametrize(
     "h, kvh, lq, lk, d, itemsize, want",
     [

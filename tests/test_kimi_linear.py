@@ -325,6 +325,69 @@ def test_unit_lower_inverse_is_a_stable_blocked_substitution():
     assert rel(g, g_ref) < 1e-5
 
 
+# -- what a block keeps across its recomputation ---------------------------
+def _gradient_programs(head_dim, count_primitives):
+    """The loss's gradient of a three-layer model (KDA + dense, MLA +
+    experts, KDA + experts) with and without ``remat``: the primitive
+    counts of its jaxpr and its value."""
+    kw = dict(KW, hidden_size=32, num_layers=3, kda_layers=[1, 3],
+              full_attn_layers=[2], intermediate_size=64, num_heads=2,
+              kv_lora_rank=16, kda_num_heads=1, kda_head_dim=head_dim,
+              num_experts=8, num_experts_per_token=2,
+              moe_intermediate_size=16, experts_held=[2, 4])
+    x = np.random.default_rng(1).integers(1, VOCAB, (1, 64)).astype(np.int32)
+    out = {}
+    for remat in (True, False):
+        model = get_model("kimi_linear_lm", **kw, remat=remat)
+        params = model.init(jax.random.key(3))
+        grad = jax.grad(lambda p, m=model: program_loss(m, p, x))
+        out[remat] = (count_primitives(jax.make_jaxpr(grad)(params).jaxpr),
+                      flatten(jax.jit(grad)(params)))
+    return out
+
+
+@pytest.fixture(scope="module")
+def wide_heads(count_primitives):
+    return _gradient_programs(128, count_primitives)
+
+
+@pytest.mark.parametrize("remat", [True, False])
+def test_recomputed_block_keeps_kernels_and_routing(wide_heads, remat):
+    """Heads of whole 128-lane slabs (the kernels, interpreted): in the
+    gradient's jaxpr ``kda_fwd`` runs once a KDA layer (2), the flash
+    forward once an MLA layer (1), ``top_k`` and ``sort`` once an expert
+    layer (2), whether or not the blocks are recomputed: the checkpoint
+    keeps what their producers name. What is not named IS made again:
+    a recomputing gradient holds more products. The two gradients agree
+    leaf by leaf."""
+    counts, grads = wide_heads[remat]
+    assert (counts["kda_fwd"], counts["kda_bwd"]) == (2, 2)
+    assert (counts["_fwd_kernel"], counts["_bwd_dq_kernel"],
+            counts["_bwd_dkv_kernel"]) == (1, 1, 1)
+    assert (counts["top_k"], counts["sort"]) == (2, 2)
+    plain, want = wide_heads[False]
+    assert (counts["dot_general"] > plain["dot_general"]) == remat
+    assert grads.keys() == want.keys()
+    for name, g in grads.items():
+        assert np.all(np.isfinite(np.asarray(g))), name
+        assert rel(g, want[name]) < KIND_TOL or not np.any(want[name]), name
+
+
+def test_narrow_heads_still_recompute_the_xla_scan(count_primitives):
+    """16-wide heads go to ``kda_chunked``, which names nothing: no
+    ``kda_*`` kernel in the gradient, and under ``remat`` its scans
+    (groups, and chunks inside a group) run once more a KDA layer, as
+    before; the routing is kept at any width."""
+    both = _gradient_programs(16, count_primitives)
+    (counts, grads), (plain, want) = both[True], both[False]
+    for c in (counts, plain):
+        assert c["kda_fwd"] == c["kda_bwd"] == 0
+        assert (c["top_k"], c["sort"], c["_fwd_kernel"]) == (2, 2, 1)
+    assert counts["scan"] == plain["scan"] + 2 * 2
+    for name, g in grads.items():
+        assert rel(g, want[name]) < KIND_TOL or not np.any(want[name]), name
+
+
 # -- flash attention, value heads narrower than query/key heads -----------
 @pytest.mark.parametrize("length", [96, 640])
 def test_flash_unequal_head_widths(length):

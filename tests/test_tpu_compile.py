@@ -222,6 +222,41 @@ def test_kda_kernels_at_the_cell_call(one_chip, direction):
                       else [(b, l, h * d)] * 4 + [(b, l, h)])
 
 
+def test_recomputed_blocks_run_each_forward_kernel_once(one_chip, monkeypatch):
+    """Two blocks of ``kimi_linear_lm`` at the published widths (KDA +
+    experts, MLA + experts; 1 x 8192), each under the model's
+    ``jax.checkpoint``: the compiled gradient holds ONE ``kda_fwd``, one
+    ``kda_bwd`` and three flash kernels (forward, dq, dk/dv). A bare
+    checkpoint made each forward kernel again in the backward pass (two
+    and four); the policy keeps what the producers name, and the chip's
+    compiler honours it at these shapes."""
+    import json
+
+    from mlapi_tpu.models import get_model, kimi_linear
+
+    # code that asks the backend sees the CPU here: steer it in the test
+    monkeypatch.setattr(kimi_linear, "pallas_interpret", lambda: False)
+    with open(os.path.join(os.path.dirname(os.path.dirname(__file__)),
+                           "benchmark", "configs",
+                           "kimi-linear-48b-a3b-ep32.json")) as f:
+        kw = json.load(f)["program"]["model_kwargs"]
+    model = get_model("kimi_linear_lm", **dict(
+        kw, num_layers=2, kda_layers=[1], full_attn_layers=[2],
+        first_k_dense_replace=0, vocab_size=1024))
+    assert model.remat
+    params = jax.tree.map(
+        lambda a: _shape(a.shape, a.dtype, one_chip),
+        jax.eval_shape(model.init, jax.random.key(0)))
+    ids = _shape((1, 8192), jnp.int32, one_chip)
+    txt = _compile(
+        jax.grad(lambda p, x: jnp.mean(model.apply(p, x))), params, ids
+    ).as_text()
+    kernels = re.findall(
+        r"^\s*%?([a-z_]+?)[.\d]* = .*custom_call_target=\"tpu_custom_call\"",
+        txt, re.M)
+    assert sorted(kernels) == ["flash_attention"] * 3 + ["kda_bwd", "kda_fwd"]
+
+
 def test_flash_attention_layer_has_no_layout_copy(one_chip):
     """An attention layer as ``models/bert.py`` writes it (projections,
     ``[B, L, H, D]`` reshapes, flash, output projection) at the cell's
