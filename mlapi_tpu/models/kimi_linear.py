@@ -24,12 +24,13 @@ The program computes it in CHUNKS of ``kda_chunk`` positions
 (:func:`kda_chunked`): inside a chunk by matrix products and the
 inverse of one unit lower-triangular matrix, across chunks by a
 ``lax.scan`` that carries ``S``; plain XLA, differentiated by JAX. At
-heads of 128 the same computation runs as Pallas kernels
-(``ops/pallas/kda.py``, forward and backward; :func:`kda` chooses by
-shapes and ``kda_chunked`` is their oracle). The in-chunk products weigh
-keys by ratios of cumulative decays, ``exp(G_r - G_i)``, which a
-factorisation ``exp(G_r) * exp(-G_i)`` overflows in any float format
-once a chunk decays by more than e^88. So the chunk is SPLIT by
+heads of 128 the same computation, with the per-head normalisations
+around it, runs as Pallas kernels (``ops/pallas/kda.py``, forward and
+backward; :func:`kda` chooses by shapes and :func:`kda_plain`, which is
+``kda_chunked`` between those normalisations, is their oracle). The
+in-chunk products weigh keys by ratios of cumulative decays, ``exp(G_r -
+G_i)``, which a factorisation ``exp(G_r) * exp(-G_i)`` overflows in any
+float format once a chunk decays by more than e^88. So the chunk is SPLIT by
 halving: a pair (row ``r``, key ``i < r``) belongs to the one block
 size at which ``r`` lies in the upper and ``i`` in the lower half of the
 same block, and factors around that lower half's last position ``m``:
@@ -268,25 +269,52 @@ def kda_chunked(q, k, v, g, beta, *, chunk: int, compute_dtype="float32"):
     return o.reshape(b, groups * per * c, h, dv)[:, :l]
 
 
-def kda(q, k, v, g, beta, *, chunk: int, compute_dtype="float32"):
-    """The gated delta rule, by whichever implementation the shapes
-    allow: heads that are whole 128-lane slabs (the published
-    ``head_dim`` 128) run the Pallas kernels
-    (``ops/pallas/kda.py``: the chunk's matrices and the state stay in
-    VMEM, operands in this layout; they tile the sequence themselves
-    and the result does not depend on ``chunk``); anything narrower
-    runs :func:`kda_chunked`. Same arguments and result as
-    :func:`kda_chunked`. Counted once a TRACE in
-    ``utils.metrics.REGISTRY``: ``kda.calls_traced``, and
-    ``kda.calls_kernel`` when the kernels are chosen."""
+def kda_plain(q, k, v, g, beta, gate, o_scale, *, eps: float, chunk: int,
+              compute_dtype="float32"):
+    """A KDA layer between its projections in plain ``jax.numpy``: the
+    slabs split into heads, q and k L2-normalised (q scaled ``Dk **
+    -0.5``), :func:`kda_chunked`, the output RMS-normed per head
+    (``o_scale [Dv]``) and gated. ``q, k, g``: ``[B, L, H * Dk]``
+    (``q, k`` raw, ``g <= 0``); ``v, gate``: ``[B, L, H * Dv]``;
+    ``beta``: ``[B, L, H]``. Returns ``y [B, L, H * Dv]`` float32. What
+    narrow heads run, and the oracle of the kernels' normed call."""
+    b, l, h = beta.shape
+
+    def heads(a):
+        return a.reshape(b, l, h, -1)
+
+    def l2(a):
+        return a * jax.lax.rsqrt(
+            jnp.sum(jnp.square(a), axis=-1, keepdims=True) + 1e-6)
+
+    q, k = heads(q), heads(k)
+    o = kda_chunked(l2(q) * q.shape[-1] ** -0.5, l2(k), heads(v), heads(g),
+                    beta, chunk=chunk, compute_dtype=compute_dtype)
+    return (_rms_norm(o, o_scale, eps) * heads(gate)).reshape(b, l, -1)
+
+
+def kda(q, k, v, g, beta, gate, o_scale, *, eps: float, chunk: int,
+        compute_dtype="float32"):
+    """A KDA layer between its projections, by whichever implementation
+    the shapes allow: heads that are whole 128-lane slabs (the
+    published ``head_dim`` 128) run the Pallas kernels
+    (``ops/pallas/kda.py``: the chunk's matrices, the state and the
+    per-head normalisations stay in VMEM, operands in the projections'
+    own ``[B, L, H * D]``; they tile the sequence themselves and the
+    result does not depend on ``chunk``); anything narrower runs
+    :func:`kda_plain`. Same arguments and result as :func:`kda_plain`.
+    Counted once a TRACE in ``utils.metrics.REGISTRY``:
+    ``kda.calls_traced``, and ``kda.calls_kernel`` when the kernels are
+    chosen."""
     REGISTRY.counter("kda.calls_traced").inc()
-    if not kda_kernels.takes(q, v):
-        return kda_chunked(q, k, v, g, beta, chunk=chunk,
-                           compute_dtype=compute_dtype)
+    h = beta.shape[-1]
+    if not kda_kernels.takes(h, q.shape[-1] // h, v.shape[-1] // h):
+        return kda_plain(q, k, v, g, beta, gate, o_scale, eps=eps,
+                         chunk=chunk, compute_dtype=compute_dtype)
     REGISTRY.counter("kda.calls_kernel").inc()
-    return kda_kernels.kda_kernels(
-        q, k, v, g, beta, compute_dtype=compute_dtype,
-        interpret=pallas_interpret())
+    return kda_kernels.kda_layer(
+        q, k, v, g, beta, gate, o_scale, eps=eps,
+        compute_dtype=compute_dtype, interpret=pallas_interpret())
 
 
 # -- the expert FFN's grouped product ----------------------------------
@@ -557,30 +585,27 @@ class KimiLinearLM:
 
     # ------------------------------------------------------------------
     def _kda(self, p, x):
+        """Everything here is elementwise on ``[B, L, H * D]`` or a
+        product: what needs a head's channels together (the L2 norms of
+        q and k, the output's RMS norm) is :func:`kda`'s, so XLA has no
+        4-D tensor to lay out."""
         cdt = jnp.dtype(self.compute_dtype)
-        b, l, _ = x.shape
-        nk, dk = self.kda_num_heads, self.kda_head_dim
 
         def qkv(name):
-            y = _short_conv(_mm(x, p[name], cdt), p["conv_" + name])
-            return jax.nn.silu(y).reshape(b, l, nk, dk)
+            return jax.nn.silu(
+                _short_conv(_mm(x, p[name], cdt), p["conv_" + name]))
 
-        def l2(a):
-            return a * jax.lax.rsqrt(
-                jnp.sum(jnp.square(a), axis=-1, keepdims=True) + 1e-6)
-
-        q, k, v = l2(qkv("q")) * dk ** -0.5, l2(qkv("k")), qkv("v")
-        g = -jnp.exp(p["A_log"])[:, None] * jax.nn.softplus(
-            (_mm(_mm(x, p["f_a"], cdt), p["f_b"], cdt) + p["dt_bias"]
-             ).reshape(b, l, nk, dk))
+        g = -jnp.repeat(jnp.exp(p["A_log"]), self.kda_head_dim) * (
+            jax.nn.softplus(
+                _mm(_mm(x, p["f_a"], cdt), p["f_b"], cdt) + p["dt_bias"]))
         beta = jax.nn.sigmoid(_mm(x, p["b"], cdt))
+        gate = jax.nn.sigmoid(_mm(_mm(x, p["g_a"], cdt), p["g_b"], cdt))
+        q, k, v = qkv("q"), qkv("k"), qkv("v")
         with jax.named_scope("kda.core"):
-            o = kda(q, k, v, g, beta, chunk=self.kda_chunk,
+            y = kda(q, k, v, g, beta, gate, p["o_norm"],
+                    eps=self.rms_norm_eps, chunk=self.kda_chunk,
                     compute_dtype=self.compute_dtype)
-        gate = jax.nn.sigmoid(
-            _mm(_mm(x, p["g_a"], cdt), p["g_b"], cdt)).reshape(b, l, nk, dk)
-        o = _rms_norm(o, p["o_norm"], self.rms_norm_eps) * gate
-        return _mm(o.reshape(b, l, nk * dk), p["o"], cdt)
+        return _mm(y, p["o"], cdt)
 
     def _mla(self, p, x):
         cdt = jnp.dtype(self.compute_dtype)

@@ -16,14 +16,23 @@ and carries ``S`` (held TRANSPOSED, ``[Dv, Dk]``: the decay then runs
 along lanes and every product with it is the MXU's native ``a b^T``) in
 a float32 VMEM scratch across the sequential tile axis of the grid.
 
-**Layout.** A grid step is one tile of EVERY head: the blocks are the
-tile's rows of ``[B, L * H, D]``, which is ``[B, L, H, D]`` as it lies
-in memory (a bitcast), and head ``h``'s ``[C, D]`` is every ``H``-th
-row of the block, read and written by strided loads and stores. The
-other choice, ``[C, hb * D]`` lane slabs out of ``[B, L, H * D]``, runs
-the kernels 5% faster and the train step 1.2% slower (XLA then moves
-the model's per-head normalisations into a 4-D layout and pays for it
-around them: PERF.md, PR 30). ``beta`` comes as its own ``[C, H]``.
+**Layout.** Operands and results are the projections' own ``[B, L,
+H * D]`` (``beta``: ``[B, L, H]``). A grid step is one tile of every
+head, a ``[C, H * D]`` lane slab that lies in memory as one piece, and
+head ``h``'s ``[C, D]`` is lanes ``h * D .. (h + 1) * D`` of it: whole
+128-lane columns of the block, read and written in place. What needs a
+head's channels TOGETHER in the layer around the rule happens here too,
+on the tile that is in VMEM anyway (:func:`_normed_tile`): q's and k's
+L2 normalisation before the rule, the RMS norm of ``o`` times the
+output gate after it. That is why lane slabs no longer lose: while those
+reductions were XLA's, XLA put a 4-D layout somewhere around them and
+paid for the change whichever layout the kernels took (lane slabs ran
+the kernels 5% faster and the train step 1.2% slower: PERF.md, PR 30);
+with them in here everything XLA sees between the projections and the
+output projection is elementwise on ``[B, L, H * D]`` and nothing is
+laid out (PERF.md, PR 35). The 4-D entry :func:`kda_kernels` (the bare
+rule, what the oracle tests hold against the literal recurrence)
+reshapes to slabs and runs the same kernels without that head and tail.
 
 **In-tile pairs** keep every exponent <= 0 by the halving of
 ``_kda_intra``: the pair (row r, key i < r) belongs to the one block
@@ -38,17 +47,18 @@ inverse exactly as a row-by-row substitution would; its derivative is
 ``-T^T dT T^T``.
 
 **Backward**: tiles in reverse with ``dS`` in VMEM; the tile's forward
-is recomputed from ``q, k, v, g, beta`` and what the forward kernel
+is recomputed from the call's operands and what the forward kernel
 saved (the state at the tile's start, and ``T``, so the elimination is
-not run twice), and its derivative is ``jax.vjp`` of the SAME per-tile
-function the forward kernel traces. Matrix products are ``custom_vjp``
+not run twice; the output before its norm exists in VMEM only), and its
+derivative is ``jax.vjp`` of the SAME per-tile function the forward
+kernel traces. Matrix products are ``custom_vjp``
 so that the backward products take their operands in the compute dtype
 too, as XLA's do on the chip.
 
 Precision is ``kda_chunked``'s: products take ``compute_dtype``
 operands and accumulate in float32; ``G``, every ``exp``, the solve and
 the carried state are float32 (float32 products contract at
-``HIGHEST``).
+``HIGHEST``), and so are both normalisations and the gate.
 """
 
 from __future__ import annotations
@@ -71,7 +81,7 @@ _TILE = 64
 # in the cell than 2 and compiles twice the code).
 _UNROLL = 2
 # A step's blocks, double-buffered (a tile of every head: 1 MB an
-# operand at 64 x 32 x 128, the tile's states 2 MB: 25 MB backward),
+# operand at 64 x 32 x 128, the tile's states 2 MB: 29 MB backward),
 # of a v5e's 128 MiB.
 _VMEM_LIMIT = 64 << 20
 # contracting dimensions of ``a b``, ``a b^T``, ``a^T b``
@@ -302,21 +312,59 @@ def _tile(q, k, v, g, beta, st, *, dt, levels, t=None):
     return o, st, T
 
 
-def _head(refs, beta_ref, h, c, nh):
-    """Head ``h``'s operands out of a tile's blocks: rows ``h, h + nh,
-    ..`` of the ``[C * nh, D]`` tiles (position-major, as ``[B, L, H,
-    D]`` lies in memory) and lane ``h`` of ``beta``'s ``[C, nh]``."""
-    rows = pl.ds(h, c, stride=nh)
+def _l2(x):
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
+
+
+def _normed_tile(q, k, v, g, beta, gate, scale, st, *, eps, **kw):
+    """:func:`_tile` inside the layer's per-head normalisations, the
+    formulas of ``models.kimi_linear`` (``kda_plain``): raw ``q, k`` are
+    L2-normalised (q scaled ``Dk ** -0.5``) on the way in, and what
+    leaves is ``y = RMSNorm(o) * scale * gate`` (``gate [C, Dv]``,
+    ``scale [1, Dv]``). All float32; ``o`` itself never leaves VMEM."""
+    o, st, T = _tile(_l2(q) * q.shape[-1] ** -0.5, _l2(k), v, g, beta, st,
+                     **kw)
+    inv = jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + eps)
+    return o * inv * scale * gate, st, T
+
+
+def _tile_fn(eps, **kw):
+    """The per-tile function of a call: the bare rule (five operands
+    and the state), or with ``eps`` the normed one (seven)."""
+    if eps is None:
+        return functools.partial(_tile, **kw)
+    return functools.partial(_normed_tile, eps=eps, **kw)
+
+
+def _lanes(ref, h, nh):
+    """Head ``h``'s lanes of a ``[.., C, H * D]`` block."""
+    d = ref.shape[-1] // nh
+    return pl.ds(pl.multiple_of(h * d, 128), d)
+
+
+def _head(ins, h, c, nh):
+    """Head ``h``'s operands out of a tile's blocks, float32, in the
+    per-tile function's order: its lanes of the ``[C, H * D]`` slabs
+    (``q, k, v, g``), lane ``h`` of ``beta``'s ``[C, H]``, and for the
+    normed call its lanes of ``gate`` and the ``[1, Dv]`` scale."""
+    q, k, v, g, beta_ref, *tail = ins
     lane = jax.lax.broadcasted_iota(jnp.int32, (c, nh), 1)
     beta = jnp.sum(jnp.where(lane == h, beta_ref[0].astype(jnp.float32), 0.0),
                    axis=1, keepdims=True)
-    return tuple(r[0, rows, :].astype(jnp.float32) for r in refs) + (beta,)
+
+    def wide(r):
+        return r[0, :, _lanes(r, h, nh)].astype(jnp.float32)
+
+    out = (wide(q), wide(k), wide(v), wide(g), beta)
+    if tail:
+        out += (wide(tail[0]), tail[1][...].astype(jnp.float32))
+    return out
 
 
-def _each_head(nh, body):
-    """``body(h)`` for every head: a loop over ``_UNROLL`` heads at a
+def _each_head(nh, unroll, body):
+    """``body(h)`` for every head: a loop over ``unroll`` heads at a
     time, whose independent chains the scheduler interleaves."""
-    u = _UNROLL if nh % _UNROLL == 0 else 1
+    u = unroll if nh % unroll == 0 else 1
 
     def step(i, carry):
         for j in range(u):
@@ -326,133 +374,162 @@ def _each_head(nh, body):
     jax.lax.fori_loop(0, nh // u, step, 0)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest, c, nh,
-                dt):
+def _fwd_kernel(*refs, n, c, nh, dt, eps, unroll):
     """Grid ``(B, tiles)``, the tile axis sequential; every head of
-    the tile in one step. ``rest``: where the backward will want them,
+    the tile in one step. ``refs``: the call's ``n`` operands (five, or
+    seven with ``eps``), the result, where the backward will want them
     the blocks of the state at the tile's start and of the tile's
-    ``T``; and the state scratch ``[H, Dv, Dk]``."""
-    st_scr = rest[-1]
+    ``T``, and the state scratch ``[H, Dv, Dk]``."""
+    ins, (y_ref, *saved), st_scr = refs[:n], refs[n:-1], refs[-1]
 
     @pl.when(pl.program_id(1) == 0)
     def _():
         st_scr[...] = jnp.zeros(st_scr.shape, jnp.float32)
 
-    levels = _levels(c)
+    tile = _tile_fn(eps, dt=dt, levels=_levels(c))
 
     def head(h):
         st = st_scr[h]
-        o, st_next, t = _tile(
-            *_head((q_ref, k_ref, v_ref, g_ref), beta_ref, h, c, nh), st,
-            dt=dt, levels=levels)
-        if len(rest) == 3:
-            rest[0][0, 0, h] = st
-            rest[1][0, 0, h] = t
-        o_ref[0, pl.ds(h, c, stride=nh), :] = o.astype(o_ref.dtype)
+        y, st_next, t = tile(*_head(ins, h, c, nh), st)
+        if saved:
+            saved[0][0, 0, h] = st
+            saved[1][0, 0, h] = t
+        y_ref[0, :, _lanes(y_ref, h, nh)] = y.astype(y_ref.dtype)
         st_scr[h] = st_next
 
-    _each_head(nh, head)
+    _each_head(nh, unroll, head)
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, st_ref, t_ref, do_ref,
-                dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, dst_scr, *, c, nh,
-                dt):
+def _bwd_kernel(*refs, n, c, nh, dt, eps, unroll):
     """The same grid with the tile axis reversed by the index maps:
-    ``dst_scr`` carries the state's cotangent back through the tiles."""
+    ``dst_scr`` carries the state's cotangent back through the tiles.
+    ``refs``: the ``n`` operands, the saved states and ``T``, the
+    result's cotangent; then a cotangent for every operand (the scale's
+    summed over heads and tiles in its one revisited block)."""
+    ins, (st_ref, t_ref, dy_ref) = refs[:n], refs[n:n + 3]
+    outs, dst_scr = refs[n + 3:-1], refs[-1]
+
     @pl.when(pl.program_id(1) == 0)
     def _():
         dst_scr[...] = jnp.zeros(dst_scr.shape, jnp.float32)
+        for ref in outs[6:]:
+            ref[...] = jnp.zeros(ref.shape, jnp.float32)
 
     lane = jax.lax.broadcasted_iota(jnp.int32, (c, nh), 1)
-    levels = _levels(c)
+    tile = _tile_fn(eps, dt=dt, levels=_levels(c))
 
     def head(h):
-        rows = pl.ds(h, c, stride=nh)
         (_, _, t), vjp = jax.vjp(
-            functools.partial(_tile, dt=dt, levels=levels, t=t_ref[0, 0, h]),
-            *_head((q_ref, k_ref, v_ref, g_ref), beta_ref, h, c, nh),
-            st_ref[0, 0, h])
-        grads = vjp((do_ref[0, rows, :].astype(jnp.float32), dst_scr[h],
-                     jnp.zeros_like(t)))
-        for ref, d in zip((dq_ref, dk_ref, dv_ref, dg_ref), grads):
-            ref[0, rows, :] = d
-        dbeta_ref[0] = jnp.where(lane == h, grads[4], dbeta_ref[0])
-        dst_scr[h] = grads[5]
+            functools.partial(tile, t=t_ref[0, 0, h]),
+            *_head(ins, h, c, nh), st_ref[0, 0, h])
+        *grads, dst = vjp((
+            dy_ref[0, :, _lanes(dy_ref, h, nh)].astype(jnp.float32),
+            dst_scr[h], jnp.zeros_like(t)))
+        for i, (ref, d) in enumerate(zip(outs, grads)):
+            if i == 4:      # beta: this head's lane
+                ref[0] = jnp.where(lane == h, d, ref[0])
+            elif i == 6:    # the scale: every head's and tile's, summed
+                ref[0] += d
+            else:
+                ref[0, :, _lanes(ref, h, nh)] = d
+        dst_scr[h] = dst
 
-    _each_head(nh, head)
+    _each_head(nh, unroll, head)
 
 
-def _specs(c, nh, dk, dv, nc, reverse):
-    """Block specs over grid ``(B, tiles)``: a tile's rows of every
-    head, ``[C * H, D]`` out of ``[B, L * H, D]`` (``[B, L, H, D]`` as
-    it lies in memory: a bitcast of it), ``beta``'s
-    ``[C, H]``, the tile's states in ``[B, tiles, H, Dv, Dk]`` and
-    its ``T`` in ``[B, tiles, H, C, C]``."""
+def _spec(nc, reverse, block):
+    """A tile's block of an array whose second axis is the tiles':
+    ``[B, L, ..]`` in blocks of ``C`` positions, or the saved ``[B,
+    tiles, H, ..]`` a tile at a time; the backward walks them in
+    reverse."""
     at = (lambda ci: nc - 1 - ci) if reverse else (lambda ci: ci)
-    return (
-        pl.BlockSpec((1, c * nh, dk), lambda b, ci: (b, at(ci), 0)),
-        pl.BlockSpec((1, c * nh, dv), lambda b, ci: (b, at(ci), 0)),
-        pl.BlockSpec((1, c, nh), lambda b, ci: (b, at(ci), 0)),
-        pl.BlockSpec((1, 1, nh, dv, dk), lambda b, ci: (b, at(ci), 0, 0, 0)),
-        pl.BlockSpec((1, 1, nh, c, c), lambda b, ci: (b, at(ci), 0, 0, 0)),
-    )
+    zeros = (0,) * (len(block) - 2)
+    return pl.BlockSpec(block, lambda b, ci: (b, at(ci), *zeros))
 
 
-def _call(kernel, name, shape, c, dt, interpret, **kw):
-    b, l, h, dk, dv = shape
+def _slabs(spec, shapes, c):
+    """Specs of a tile of every head of the operands that have
+    positions (``q, k, v, g``, ``beta``'s ``[C, H]``, ``gate``), or of
+    their cotangents."""
+    return [spec((1, c, s[-1])) for s in shapes[:6]]
+
+
+def _f32(*shape):
+    return jax.ShapeDtypeStruct(shape, jnp.float32)
+
+
+def _call(kernel, name, shapes, spec, rest_specs, out_specs, out_shape, *,
+          c, dt, eps, interpret, unroll):
+    """One ``pallas_call`` over grid ``(B, tiles)`` on operands of
+    ``shapes`` (``q, k, v, g [B, L, H * D]``, ``beta [B, L, H]``, and
+    with ``eps`` ``gate`` and the scale as ``[1, Dv]``, whole every
+    step); ``rest_specs`` are the further inputs'."""
+    b, l, h = shapes[4]
+    dk, dv = shapes[0][-1] // h, shapes[2][-1] // h
+    whole = [pl.BlockSpec(s, lambda b, ci: (0, 0)) for s in shapes[6:]]
     return pl.pallas_call(
-        functools.partial(kernel, c=c, nh=h, dt=dt),
+        functools.partial(kernel, n=len(shapes), c=c, nh=h, dt=dt, eps=eps,
+                          unroll=unroll),
         grid=(b, l // c),
+        in_specs=_slabs(spec, shapes, c) + whole + rest_specs,
+        out_specs=out_specs, out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((h, dv, dk), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
-        interpret=interpret, name=name, **kw)
+        interpret=interpret, name=name)
 
 
-def _forward(q, k, v, g, beta, c, dt, interpret, save):
-    b, l, h, dk = q.shape
-    dv = v.shape[-1]
+# The two calls are BUILT once for each set of shapes and statics: a
+# model's layers then share one jitted callable, so its kernel body is
+# traced once a program and not once a layer (the elimination unrolls
+# to thousands of operations: 0.3 to 1 s of tracing a call).
+@functools.lru_cache(maxsize=None)
+def _forward_call(shapes, save, **kw):
+    b, l, h = shapes[4]
+    dk, dv = shapes[0][-1] // h, shapes[2][-1] // h
+    c = kw["c"]
     nc = l // c
-    qk_spec, v_spec, beta_spec, st_spec, t_spec = _specs(
-        c, h, dk, dv, nc, False)
-    out_shape = [jax.ShapeDtypeStruct((b, l * h, dv), jnp.float32)]
-    out_specs = [v_spec]
-    if save:
-        out_shape += [jax.ShapeDtypeStruct((b, nc, h, dv, dk), jnp.float32),
-                      jax.ShapeDtypeStruct((b, nc, h, c, c), jnp.float32)]
-        out_specs += [st_spec, t_spec]
-    rows = lambda a: a.reshape(b, l * h, -1)  # noqa: E731
-    out = _call(
-        _fwd_kernel, "kda_fwd", (b, l, h, dk, dv), c, dt, interpret,
-        in_specs=[qk_spec, qk_spec, v_spec, qk_spec, beta_spec],
-        out_specs=out_specs, out_shape=out_shape,
-    )(rows(q), rows(k), rows(v), rows(g), beta)
-    return out[0].reshape(b, l, h, dv), tuple(out[1:])
+    spec = functools.partial(_spec, nc, False)
+    out = [(b, l, h * dv)]
+    if save:   # the state at every tile's start, and every tile's T
+        out += [(b, nc, h, dv, dk), (b, nc, h, c, c)]
+    return _call(
+        _fwd_kernel, "kda_fwd", shapes, spec, [],
+        [spec((1, c, h * dv))] + [spec((1, 1) + s[2:]) for s in out[1:]],
+        [_f32(*s) for s in out], **kw)
 
 
-def _backward(q, k, v, g, beta, states, ts, do, c, dt, interpret):
-    b, l, h, dk = q.shape
-    dv = v.shape[-1]
-    qk_spec, v_spec, beta_spec, st_spec, t_spec = _specs(
-        c, h, dk, dv, l // c, True)
-    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)  # noqa: E731
-    rows = lambda a: a.reshape(b, l * h, -1)  # noqa: E731
-    dq, dkk, dvv, dg, dbeta = _call(
-        _bwd_kernel, "kda_bwd", (b, l, h, dk, dv), c, dt, interpret,
-        in_specs=[qk_spec, qk_spec, v_spec, qk_spec, beta_spec, st_spec,
-                  t_spec, v_spec],
-        out_specs=[qk_spec, qk_spec, v_spec, qk_spec, beta_spec],
-        out_shape=[f32(b, l * h, dk), f32(b, l * h, dk), f32(b, l * h, dv),
-                   f32(b, l * h, dk), f32(b, l, h)],
-    )(rows(q), rows(k), rows(v), rows(g), beta, states, ts, rows(do))
-    return (dq.reshape(q.shape), dkk.reshape(k.shape), dvv.reshape(v.shape),
-            dg.reshape(g.shape), dbeta)
+@functools.lru_cache(maxsize=None)
+def _backward_call(shapes, **kw):
+    """Takes the operands, the saved states and ``T`` and the result's
+    cotangent; a cotangent for every operand, the scale's a row a
+    batch row (summed over heads and tiles)."""
+    b, l, h = shapes[4]
+    dk, dv = shapes[0][-1] // h, shapes[2][-1] // h
+    c = kw["c"]
+    spec = functools.partial(_spec, l // c, True)
+    out_specs = _slabs(spec, shapes, c)
+    out_shape = [_f32(*s) for s in shapes[:6]]
+    for s in shapes[6:]:
+        out_specs.append(pl.BlockSpec((1, *s), lambda b, ci: (b, 0, 0)))
+        out_shape.append(_f32(b, *s))
+    return _call(
+        _bwd_kernel, "kda_bwd", shapes, spec,
+        [spec((1, 1, h, dv, dk)), spec((1, 1, h, c, c)),
+         spec((1, c, h * dv))],
+        out_specs, out_shape, **kw)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def _kda(q, k, v, g, beta, c, dt, interpret):
-    return _forward(q, k, v, g, beta, c, dt, interpret, save=False)[0]
+def _statics(ops, c, dt, eps, interpret):
+    return tuple(a.shape for a in ops), dict(
+        c=c, dt=dt, eps=eps, interpret=interpret, unroll=_UNROLL)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4))
+def _kda(ops, c, dt, eps, interpret):
+    shapes, kw = _statics(ops, c, dt, eps, interpret)
+    return _forward_call(shapes, False, **kw)(*ops)[0]
 
 
 # What a recomputing caller should keep of a differentiated call: the
@@ -462,15 +539,19 @@ def _kda(q, k, v, g, beta, c, dt, interpret):
 REMAT_NAMES = ("kda.o", "kda.states", "kda.t")
 
 
-def _kda_fwd(q, k, v, g, beta, c, dt, interpret):
-    o, saved = _forward(q, k, v, g, beta, c, dt, interpret, save=True)
-    o, *saved = map(checkpoint_name, (o, *saved), REMAT_NAMES)
-    return o, (q, k, v, g, beta, *saved)
+def _kda_fwd(ops, c, dt, eps, interpret):
+    shapes, kw = _statics(ops, c, dt, eps, interpret)
+    y, *saved = map(checkpoint_name, _forward_call(shapes, True, **kw)(*ops),
+                    REMAT_NAMES)
+    return y, (ops, *saved)
 
 
-def _kda_bwd(c, dt, interpret, res, do):
-    grads = _backward(*res, do, c, dt, interpret)
-    return tuple(g.astype(a.dtype) for g, a in zip(grads, res))
+def _kda_bwd(c, dt, eps, interpret, res, dy):
+    ops, states, ts = res
+    shapes, kw = _statics(ops, c, dt, eps, interpret)
+    grads = list(_backward_call(shapes, **kw)(*ops, states, ts, dy))
+    grads = grads[:6] + [jnp.sum(g, axis=0) for g in grads[6:]]
+    return (tuple(g.astype(a.dtype) for g, a in zip(grads, ops)),)
 
 
 _kda.defvjp(_kda_fwd, _kda_bwd)
@@ -479,34 +560,59 @@ _kda.defvjp(_kda_fwd, _kda_bwd)
 def _step_bytes(h, dk, dv):
     """VMEM the backward kernel's grid step needs (the heavier of the
     two): its double-buffered blocks (q, k, g and their cotangents; v,
-    dO, dv; the tile's states and ``T``) and the ``dS`` scratch."""
+    gate, dy and the first two's cotangents; the tile's states and
+    ``T``) and the ``dS`` scratch."""
     rows = _TILE * h
-    blocks = 6 * rows * dk + 3 * rows * dv + h * (dv * dk + _TILE * _TILE)
+    blocks = 6 * rows * dk + 5 * rows * dv + h * (dv * dk + _TILE * _TILE)
     return 4 * (2 * blocks + h * dv * dk)
 
 
-def takes(q, v) -> bool:
-    """Whether the kernels take these operands, from shapes alone:
-    heads that are whole 128-lane slabs (the published ``head_dim``
-    128) and a tile of every head that fits the VMEM limit."""
-    h, dk, dv = q.shape[2], q.shape[3], v.shape[3]
+def takes(h: int, dk: int, dv: int) -> bool:
+    """Whether the kernels take a call of ``h`` heads ``dk`` / ``dv``
+    wide, from shapes alone: heads that are whole 128-lane slabs (the
+    published ``head_dim`` 128) and a tile of every head that fits the
+    VMEM limit."""
     return (dk % 128 == 0 and dv % 128 == 0
             and _step_bytes(h, dk, dv) <= 3 * _VMEM_LIMIT // 4)
 
 
+def _run(ops, eps, compute_dtype, interpret):
+    """The kernels on slab operands of any ``L``: the tail is padded to
+    whole tiles of ``_TILE`` positions that write nothing (beta 0 and no
+    decay: the state passes unchanged)."""
+    l = ops[0].shape[1]
+    pad = -l % _TILE
+    if pad:
+        ops = tuple(
+            jnp.pad(a, ((0, 0), (0, pad), (0, 0))) if a.ndim == 3 else a
+            for a in ops)
+    y = _kda(ops, _TILE, jnp.dtype(compute_dtype), eps, interpret)
+    return y[:, :l]
+
+
+def kda_layer(q, k, v, g, beta, gate, o_scale, *, eps: float,
+              compute_dtype="float32", interpret: bool = False):
+    """A KDA layer between its projections, by the kernels: the gated
+    delta rule on L2-normalised ``q`` (scaled ``Dk ** -0.5``) and ``k``,
+    its output RMS-normed per head (``o_scale [Dv]``, ``eps``) and
+    gated. ``q, k, g``: ``[B, L, H * Dk]`` (``q, k`` raw, ``g <= 0``);
+    ``v, gate``: ``[B, L, H * Dv]``; ``beta``: ``[B, L, H]``; ``Dk, Dv``
+    multiples of 128 (:func:`takes`). Returns ``y [B, L, H * Dv]``
+    float32, differentiable in all seven. Any ``L``."""
+    return _run((q, k, v, g, beta, gate, o_scale.reshape(1, -1)), eps,
+                compute_dtype, interpret)
+
+
 def kda_kernels(q, k, v, g, beta, *, compute_dtype="float32",
                 interpret: bool = False):
-    """The gated delta rule with a decay per channel, by the kernels.
-    ``q, k, g``: ``[B, L, H, Dk]``; ``v``: ``[B, L, H, Dv]``; ``beta``:
-    ``[B, L, H]``; ``g <= 0``; ``Dk, Dv`` multiples of 128
-    (:func:`takes`). Returns ``o [B, L, H, Dv]`` float32,
-    differentiable in all five. Any ``L``: the tail is padded to whole
-    tiles of ``_TILE`` positions that write nothing."""
-    l = q.shape[1]
-    pad = -l % _TILE
-    if pad:   # beta 0 and no decay: the state passes unchanged
-        q, k, v, g, beta = (
-            jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
-            for a in (q, k, v, g, beta))
-    o = _kda(q, k, v, g, beta, _TILE, jnp.dtype(compute_dtype), interpret)
-    return o[:, :l]
+    """The bare gated delta rule with a decay per channel, by the same
+    kernels (no normalisation, no gate). ``q, k, g``: ``[B, L, H,
+    Dk]``; ``v``: ``[B, L, H, Dv]``; ``beta``: ``[B, L, H]``; ``g <=
+    0``. Returns ``o [B, L, H, Dv]`` float32, differentiable in all
+    five. Any ``L``. What the oracle tests hold against the literal
+    recurrence; the reshapes to and from slabs are XLA's to lay out."""
+    b, l, h, _ = q.shape
+    slab = lambda a: a.reshape(b, l, -1)  # noqa: E731
+    o = _run((slab(q), slab(k), slab(v), slab(g), beta), None,
+             compute_dtype, interpret)
+    return o.reshape(b, l, h, -1)

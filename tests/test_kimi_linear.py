@@ -283,24 +283,126 @@ def test_kda_kernels_match_literal_recurrence(length, decay, cdt, tol):
             assert e < 1.25 * rel(a, w) + 1e-4, (errs, rel(a, w))
 
 
+def _layer_operands(length, b=2, h=2, d=128, decay=1.0):
+    """The seven operands of a KDA layer's call as the projections
+    leave them (``[B, L, H * D]`` slabs, raw q and k, ``beta [B, L,
+    H]``, the output norm's scale ``[D]``) and a probe for ``y``."""
+    ks = jax.random.split(jax.random.key(1000 + length), 8)
+
+    def wide(i):
+        return jax.random.normal(ks[i], (b, length, h * d))
+
+    return (wide(0), wide(1), wide(2),
+            -decay * jax.random.uniform(ks[3], (b, length, h * d), maxval=2.0),
+            jax.nn.sigmoid(jax.random.normal(ks[4], (b, length, h))),
+            jax.nn.sigmoid(wide(5)),
+            1.0 + 0.1 * jax.random.normal(ks[6], (d,)), wide(7))
+
+
 def test_shapes_choose_between_the_kernels_and_the_xla_scan():
-    """Heads of 128 take the kernels, 16-wide heads ``kda_chunked``:
-    read from ``kda.calls_traced`` / ``kda.calls_kernel``, counted once
-    a trace where ``models.kimi_linear.kda`` chooses. Both answers are
-    the recurrence's."""
+    """Heads of 128 take the kernels, 16-wide heads the plain path
+    (``kda_chunked`` between the normalisations): read from
+    ``kda.calls_traced`` / ``kda.calls_kernel``, counted once a trace
+    where ``models.kimi_linear.kda`` chooses. Both answers are the
+    recurrence's, between the layer's normalisations."""
     def counts():
         c = REGISTRY.snapshot()["counters"]
         return c.get("kda.calls_traced", 0), c.get("kda.calls_kernel", 0)
 
+    def literal(q, k, v, g, beta, gate, scale):
+        b, l, h = beta.shape
+        heads = lambda a: a.reshape(b, l, h, -1)  # noqa: E731
+        unit = lambda a: a * jax.lax.rsqrt(  # noqa: E731
+            jnp.sum(a * a, -1, keepdims=True) + 1e-6)
+        q, k = heads(q), heads(k)
+        o = ref.delta_rule(unit(q) * q.shape[-1] ** -0.5, unit(k), heads(v),
+                           heads(g), beta)
+        o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + 1e-5)
+        return (o * scale * heads(gate)).reshape(b, l, -1)
+
     for d, kernel in ((16, 0), (128, 1)):
-        *args, _ = _delta_rule_operands(40, 1.0, b=1, h=2, d=d)
+        *args, _ = _layer_operands(40, b=1, d=d)
         before = counts()
-        fn = jax.jit(lambda *a: kl.kda(*a, chunk=32))
-        o = fn(*args)
+        fn = jax.jit(lambda *a: kl.kda(*a, eps=1e-5, chunk=32))
+        y = fn(*args)
         fn(*args)  # a second call of the same trace counts nothing
         after = counts()
         assert (after[0] - before[0], after[1] - before[1]) == (1, kernel)
-        assert rel(o, ref.delta_rule(*args)) < 1e-4
+        assert rel(y, literal(*args)) < 1e-4
+
+
+@pytest.mark.parametrize("length,cdt,tol", [
+    (200, "float32", 1e-4),    # no multiple of the tile (64)
+    (1100, "float32", 1e-4),   # 18 tiles: 17 carried states
+    # bfloat16 products, float32 accumulation and normalisations: the
+    # kernels and the plain path each against the float32 plain path
+    (200, "bfloat16", 1e-2),
+])
+def test_kda_layer_kernels_match_the_plain_path(length, cdt, tol):
+    """The kernels' seven-operand call (``kda_layer``, interpreter: the
+    L2 normalisations of q and k, the rule, the output's RMS norm times
+    the gate, all on a tile in VMEM) at 2 rows x 2 heads of 128 against
+    the plain ``jax.numpy`` path of the same entry point
+    (``kda_plain``, float32): ``y`` and all seven gradients, as norm of
+    the difference over the oracle's norm. float32 operands: rounding
+    only. ``compute_dtype="bfloat16"``: the tolerance the plain path
+    meets at that dtype against the same oracle, and the kernels within
+    a quarter more than what it reads."""
+    from mlapi_tpu.ops.pallas import kda as kk
+
+    *args, probe = _layer_operands(length)
+
+    def run(f):
+        y, vjp = jax.vjp(f, *args)
+        return (y,) + vjp(probe)
+
+    def plain(cdt):
+        return jax.jit(lambda: run(lambda *a: kl.kda_plain(
+            *a, eps=1e-5, chunk=32, compute_dtype=cdt)))()
+
+    got = jax.jit(lambda: run(lambda *a: kk.kda_layer(
+        *a, eps=1e-5, compute_dtype=cdt, interpret=True)))()
+    with jax.default_matmul_precision("highest"):
+        want = plain("float32")
+    assert len(got) == 8 and got[7].shape == (128,)
+    errs = [rel(a, w) for a, w in zip(got, want)]
+    for a, e in zip(got, errs):
+        assert np.all(np.isfinite(np.asarray(a)))
+        assert e < tol, errs
+    if cdt != "float32":
+        for e, a, w in zip(errs, plain(cdt), want):
+            assert rel(a, w) < tol
+            assert e < 1.25 * rel(a, w) + 1e-4, (errs, rel(a, w))
+
+
+def test_kda_block_by_the_kernels_matches_the_plain_block(monkeypatch):
+    """A KDA block of the model (norm, projections, convolutions, the
+    call, output projection, dense FFN) at ``head_dim`` 128, where the
+    entry point chooses the kernels, against the same block with
+    ``kda_plain`` called directly: the loss and every parameter's
+    gradient, float32."""
+    kw = dict(KW, hidden_size=32, num_layers=1, kda_layers=[1],
+              full_attn_layers=[], intermediate_size=64, kda_num_heads=2,
+              kda_head_dim=128, remat=False)
+    model = get_model("kimi_linear_lm", **kw)
+    params = model.init(jax.random.key(5))
+    x = np.random.default_rng(2).integers(1, VOCAB, (2, 100)).astype(np.int32)
+
+    def loss_and_grads():
+        before = REGISTRY.snapshot()["counters"].get("kda.calls_kernel", 0)
+        with jax.default_matmul_precision("highest"):
+            out = jax.jit(jax.value_and_grad(
+                lambda p: program_loss(model, p, x)))(params)
+        return out, REGISTRY.snapshot()["counters"].get(
+            "kda.calls_kernel", 0) - before
+
+    (loss, grads), chose = loss_and_grads()
+    monkeypatch.setattr(kl, "kda", kl.kda_plain)
+    (want_loss, want), chose_plain = loss_and_grads()
+    assert (chose, chose_plain) == (1, 0)
+    assert abs(float(loss) - float(want_loss)) < 1e-5 * abs(float(want_loss))
+    for name, g in flatten(want).items():
+        assert rel(flatten(grads)[name], g) < KIND_TOL, name
 
 
 def test_unit_lower_inverse_is_a_stable_blocked_substitution():

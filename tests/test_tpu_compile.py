@@ -172,64 +172,63 @@ def test_flash_attention_latent_heads_stream(one_chip, direction):
     )
 
 
+def _sized_f32(txt, ops, elements):
+    """The float32 results of ``ops`` instructions (a regular
+    expression over HLO opcodes) that hold ``elements`` numbers or more.
+    A ``bitcast`` moves nothing and is none of them."""
+    return [m for m in re.findall(rf"= f32\[([\d,]+)\]\S* (?:{ops})\(", txt)
+            if np.prod([int(n) for n in m.split(",")]) >= elements]
+
+
 @pytest.mark.parametrize("direction", ["forward", "backward"])
 def test_kda_kernels_at_the_cell_call(one_chip, direction):
-    """The gated delta rule of ``kimi_linear_lm`` at the cell
-    ``kimi-linear.pretrain_8k``'s call, ``1 x 8192 x 32 x 128``
-    (operands as the model's projections leave them, ``[B, L, H * D]``,
-    split into heads inside the program), forward and the gradient of a
-    sum in all five operands: Mosaic takes the kernels (one custom call
-    forward; the forward that saves the states and the backward in the
-    gradient) and no ``while`` of the XLA scan is left. The blocks are
-    rows of ``[B, L, H, D]`` as it lies in memory, so what XLA lays out
-    is one ``reshape`` an operand and result between that and the
-    projections' ``[B, L, H * D]`` (9 forward and backward, the cost of
-    the choice: PERF.md, PR 30), and no ``copy``, ``transpose`` or pad
-    besides."""
-    from mlapi_tpu.ops.pallas.kda import kda_kernels
+    """A KDA layer's call of ``kimi_linear_lm`` at the cell
+    ``kimi-linear.pretrain_8k``, ``1 x 8192`` positions, 32 heads of
+    128: the seven operands as the model's projections leave them
+    (``[B, L, H * D]``, ``beta [B, L, H]``, the norm's scale ``[D]``),
+    forward and the gradient of a sum in all seven. Mosaic takes the
+    kernels (one custom call forward; the forward that saves the states
+    and the backward in the gradient), no ``while`` of the XLA scan is
+    left, and XLA lays NOTHING out around them: the blocks are lane
+    slabs of the projections' own tiling and the per-head reductions are
+    the kernels', so no operand-sized ``copy``, ``transpose``, pad or
+    ``reshape`` is in the program (the rows layout paid 5 / 9
+    ``reshape``s here: PERF.md, PR 30)."""
+    from mlapi_tpu.ops.pallas.kda import kda_layer
 
     b, l, h, d = 1, 8192, 32, 128
     x = _shape((b, l, h * d), jnp.float32, one_chip)
     beta = _shape((b, l, h), jnp.float32, one_chip)
+    scale = _shape((d,), jnp.float32, one_chip)
+    args = (x, x, x, x, beta, x, scale)
 
-    def fwd(q, k, v, g, beta):
-        q, k, v, g = (a.reshape(b, l, h, d) for a in (q, k, v, g))
-        return kda_kernels(q, k, v, g, beta, compute_dtype="bfloat16",
-                           interpret=False).reshape(b, l, h * d)
+    def fwd(*a):
+        return kda_layer(*a, eps=1e-5, compute_dtype="bfloat16",
+                         interpret=False)
 
     if direction == "forward":
         fn = fwd
     else:
         def fn(*a):
             return jax.grad(lambda *a: jnp.sum(fwd(*a)),
-                            argnums=(0, 1, 2, 3, 4))(*a)
+                            argnums=tuple(range(7)))(*a)
 
-    txt = _compile(fn, x, x, x, x, beta).as_text()
+    txt = _compile(fn, *args).as_text()
     assert txt.count('custom_call_target="tpu_custom_call"') == (
         1 if direction == "forward" else 2
     )
-    def sized(ops):
-        return [m for m in re.findall(
-            rf"= f32\[([\d,]+)\]\S* (?:{ops})\(", txt)
-            if np.prod([int(n) for n in m.split(",")]) >= b * l * h * d]
-
-    assert not sized("copy|transpose|pad"), sized("copy|transpose|pad")
-    assert len(sized("reshape")) <= (5 if direction == "forward" else 9)
+    moved = _sized_f32(txt, "copy|transpose|pad|reshape", b * l * h * d)
+    assert not moved, moved
     assert " while(" not in txt
-    shapes = [o.shape for o in jax.tree.leaves(
-        jax.eval_shape(fn, x, x, x, x, beta))]
-    assert shapes == ([(b, l, h * d)] if direction == "forward"
-                      else [(b, l, h * d)] * 4 + [(b, l, h)])
+    shapes = [o.shape for o in jax.tree.leaves(jax.eval_shape(fn, *args))]
+    assert shapes == ([(b, l, h * d)] if direction == "forward" else
+                      [(b, l, h * d)] * 4 + [(b, l, h), (b, l, h * d), (d,)])
 
 
-def test_recomputed_blocks_run_each_forward_kernel_once(one_chip, monkeypatch):
-    """Two blocks of ``kimi_linear_lm`` at the published widths (KDA +
-    experts, MLA + experts; 1 x 8192), each under the model's
-    ``jax.checkpoint``: the compiled gradient holds ONE ``kda_fwd``, one
-    ``kda_bwd`` and three flash kernels (forward, dq, dk/dv). A bare
-    checkpoint made each forward kernel again in the backward pass (two
-    and four); the policy keeps what the producers name, and the chip's
-    compiler honours it at these shapes."""
+def _kimi_blocks(one_chip, monkeypatch, **layers):
+    """The compiled gradient, in the parameters, of ``kimi_linear_lm``
+    at the published widths cut to the given layers (1 x 8192, each
+    block under the model's ``jax.checkpoint`` policy), as text."""
     import json
 
     from mlapi_tpu.models import get_model, kimi_linear
@@ -240,21 +239,51 @@ def test_recomputed_blocks_run_each_forward_kernel_once(one_chip, monkeypatch):
                            "benchmark", "configs",
                            "kimi-linear-48b-a3b-ep32.json")) as f:
         kw = json.load(f)["program"]["model_kwargs"]
-    model = get_model("kimi_linear_lm", **dict(
-        kw, num_layers=2, kda_layers=[1], full_attn_layers=[2],
-        first_k_dense_replace=0, vocab_size=1024))
+    model = get_model("kimi_linear_lm", **dict(kw, vocab_size=1024, **layers))
     assert model.remat
     params = jax.tree.map(
         lambda a: _shape(a.shape, a.dtype, one_chip),
         jax.eval_shape(model.init, jax.random.key(0)))
     ids = _shape((1, 8192), jnp.int32, one_chip)
-    txt = _compile(
+    return _compile(
         jax.grad(lambda p, x: jnp.mean(model.apply(p, x))), params, ids
     ).as_text()
-    kernels = re.findall(
-        r"^\s*%?([a-z_]+?)[.\d]* = .*custom_call_target=\"tpu_custom_call\"",
-        txt, re.M)
+
+
+_KERNEL = r"^\s*%?([a-z_]+?)[.\d]* = .*custom_call_target=\"tpu_custom_call\""
+
+
+def test_recomputed_blocks_run_each_forward_kernel_once(one_chip, monkeypatch):
+    """Two blocks of ``kimi_linear_lm`` at the published widths (KDA +
+    experts, MLA + experts; 1 x 8192), each under the model's
+    ``jax.checkpoint``: the compiled gradient holds ONE ``kda_fwd``, one
+    ``kda_bwd`` and three flash kernels (forward, dq, dk/dv). A bare
+    checkpoint made each forward kernel again in the backward pass (two
+    and four); the policy keeps what the producers name, and the chip's
+    compiler honours it at these shapes."""
+    txt = _kimi_blocks(one_chip, monkeypatch, num_layers=2, kda_layers=[1],
+                       full_attn_layers=[2], first_k_dense_replace=0)
+    kernels = re.findall(_KERNEL, txt, re.M)
     assert sorted(kernels) == ["flash_attention"] * 3 + ["kda_bwd", "kda_fwd"]
+
+
+def test_kda_layer_has_no_layout_copy(one_chip, monkeypatch):
+    """One KDA block of ``kimi_linear_lm`` at the published widths (32
+    heads of 128, 1 x 8192; norm, projections, convolutions, gates, the
+    call, output projection, dense FFN) under the model's checkpoint
+    policy, gradient in the parameters. Between the projections and the
+    output projection everything XLA sees is elementwise on ``[B, L, H
+    * D]`` and the per-head reductions are the kernels', so the
+    compiled block holds no ``copy``, ``transpose`` or ``reshape`` of
+    an operand-sized float32 tensor around ``kda_fwd`` / ``kda_bwd``
+    (the parent of PR 35 compiled 16 ``copy`` and 18 ``reshape`` here),
+    and no ``[.., 32, 128]`` tensor at all."""
+    txt = _kimi_blocks(one_chip, monkeypatch, num_layers=1, kda_layers=[1],
+                       full_attn_layers=[], first_k_dense_replace=1)
+    assert sorted(re.findall(_KERNEL, txt, re.M)) == ["kda_bwd", "kda_fwd"]
+    moved = _sized_f32(txt, "copy|transpose|reshape", 8192 * 4096)
+    assert not moved, moved
+    assert not re.findall(r"\w+\[[\d,]*8192,32,128\]", txt)
 
 
 def test_flash_attention_layer_has_no_layout_copy(one_chip):

@@ -16,10 +16,14 @@ head geometry ``chip_smoke.py`` serves (BERT-base / GPT-2 small:
     python -m tools.chip_kernels --kda      # the gated delta rule
                                             # alone: kernels against
                                             # kda_chunked and the
-                                            # literal recurrence, and
-                                            # ms a call at the cell
+                                            # literal recurrence, the
+                                            # layer's seven-operand
+                                            # call against the plain
+                                            # path and ms a call of it
+                                            # at the cell
                                             # kimi-linear.pretrain_8k's
                                             # shapes beside kda_chunked
+                                            # plus XLA's norms
                                             # (--kda-tile 32 64 and
                                             # --kda-unroll 2 4 sweep
                                             # the kernel's two knobs)
@@ -266,22 +270,31 @@ def run(tiny: bool, tp: bool) -> list[dict]:
     return rows
 
 
-def run_kda(tiny: bool, timing: bool = True, tiles=(), unrolls=()) -> list[dict]:
-    """The gated delta rule (``ops/pallas/kda.py``). Agreement at small
-    sizes (2 rows x 2 heads of 128, 200 positions: a padded tail and
-    several carried states): float32 operands against the literal
-    recurrence (``benchmark/reference``), and at ``bfloat16`` products
-    the kernels beside ``kda_chunked``, each against the same oracle,
-    as norm of the difference over the oracle's norm, output and the
-    five gradients. With ``timing``, at the benchmark cell's call (1 x
-    8192 x 32 x 128), ms a call forward and forward + backward beside
-    ``kda_chunked``'s, one JSON line a variant."""
+def run_kda(tiny: bool, timing: bool = True, tiles=(),
+            unrolls=()) -> list[dict]:
+    """The gated delta rule (``ops/pallas/kda.py``). Agreement of the
+    bare rule at small sizes (2 rows x 2 heads of 128, 200 positions: a
+    padded tail and several carried states; the 4-D entry over the same
+    lane-slab kernels): float32 operands against the literal recurrence
+    (``benchmark/reference``), and at ``bfloat16`` products the kernels
+    beside ``kda_chunked``, each against the same oracle, as norm of
+    the difference over the oracle's norm, output and the five
+    gradients (without ``timing``, in the smoke, the bare kernels'
+    bfloat16 rows are left to the layer's). Then the layer's
+    seven-operand call (``kda_layer``: the
+    normalisations and the gate inside) at the benchmark cell's call (1
+    x 8192 x 32 x 128), float32 and bfloat16 products, each against the
+    plain path (``kda_plain``) in float32: one row a dtype, the worst of
+    ``y`` and the seven gradients. With ``timing``, ms a call of it
+    forward and forward + backward beside ``kda_plain``
+    (``kda_chunked`` plus XLA's normalisations), one JSON line a
+    variant."""
     import os
 
     import jax
     import jax.numpy as jnp
 
-    from mlapi_tpu.models.kimi_linear import kda_chunked
+    from mlapi_tpu.models.kimi_linear import kda_chunked, kda_plain
     from mlapi_tpu.ops.pallas import kda as kk
     from mlapi_tpu.utils.platform import pallas_interpret
 
@@ -305,77 +318,112 @@ def run_kda(tiny: bool, timing: bool = True, tiles=(), unrolls=()) -> list[dict]
                 jax.nn.sigmoid(jax.random.normal(ks[4], (b, l, h))),
                 jax.random.normal(ks[5], (b, l, h, d)))
 
-    def kernel(cdt):
-        return lambda *a: kk.kda_kernels(
-            *a, compute_dtype=cdt, interpret=interp)
+    def both(fn, args, probe):
+        """``fn``'s result and its cotangents under ``probe``, jitted
+        with the operands as ARGUMENTS: closed over, 134 MB arrays
+        become constants of the program (minutes of compiling)."""
+        def run(probe, *args):
+            y, vjp = jax.vjp(fn, *args)
+            return (y,) + vjp(probe)
 
-    def chunked(cdt):
-        return lambda *a: kda_chunked(*a, chunk=32, compute_dtype=cdt)
-
-    *args, probe = operands(2, 64 if tiny else 200, 2, 128)
-
-    def both(fn):
-        y, vjp = jax.vjp(fn, *args)
-        return (y,) + vjp(probe)
+        return jax.jit(run)(probe, *args)
 
     def rel(a, b):
         return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
 
-    want = jax.jit(lambda: both(ref.delta_rule))()
+    def row(name, e, tol, a, *others):
+        finite = all(bool(jnp.all(jnp.isfinite(x))) for x in (a, *others))
+        r = {"kernel": name, "rel_err": e, "tol": tol,
+             "shape": list(a.shape), "interpret": interp,
+             "within_tol": finite and e <= tol}
+        print(json.dumps(r), flush=True)
+        rows.append(r)
+
+    *args, probe = operands(2, 64 if tiny else 200, 2, 128)
+    want = both(ref.delta_rule, args, probe)
     # float32: rounding only. bfloat16 products: what kda_chunked
     # itself reads against the same oracle (0.003 at these sizes).
-    for tag, fn, tol in (("kernel-float32", kernel("float32"), 1e-4),
-                         ("kernel-bfloat16", kernel("bfloat16"), 2e-2),
-                         ("chunked-bfloat16", chunked("bfloat16"), 2e-2)):
-        got = jax.jit(lambda fn=fn: both(fn))()
+    variants = [
+        ("kernel-float32", lambda *a: kk.kda_kernels(
+            *a, compute_dtype="float32", interpret=interp), 1e-4),
+        ("kernel-bfloat16", lambda *a: kk.kda_kernels(
+            *a, compute_dtype="bfloat16", interpret=interp), 2e-2),
+        ("chunked-bfloat16", lambda *a: kda_chunked(
+            *a, chunk=32, compute_dtype="bfloat16"), 2e-2)]
+    if not timing:
+        # the smoke's time: bfloat16 products are held by the layer's
+        # row below, which compiles the same kernels
+        del variants[1]
+    for tag, fn, tol in variants:
+        got = both(fn, args, probe)
         for name, a, w in zip(("o", "dq", "dk", "dv", "dg", "dbeta"),
                               got, want):
-            e = rel(a, w)
-            row = {"kernel": f"kda-{tag}-{name}", "rel_err": e, "tol": tol,
-                   "shape": list(a.shape), "interpret": interp,
-                   "within_tol": bool(jnp.all(jnp.isfinite(a))) and e <= tol}
-            print(json.dumps(row), flush=True)
-            rows.append(row)
+            row(f"kda-{tag}-{name}", rel(a, w), tol, a)
+
+    # The cell's call, the seven operands as the model's projections
+    # leave them: [B, L, H*D] slabs, raw q and k.
+    b, l, h, d = (1, 128, 2, 128) if tiny else (1, 8192, 32, 128)
+    ks = jax.random.split(jax.random.key(1), 8)
+    wide = lambda i: jax.random.normal(ks[i], (b, l, h * d))  # noqa: E731
+    layer = (wide(0), wide(1), wide(2),
+             -jax.random.uniform(ks[3], (b, l, h * d), maxval=0.2),
+             jax.nn.sigmoid(jax.random.normal(ks[4], (b, l, h))),
+             jax.nn.sigmoid(wide(5)),
+             1.0 + 0.1 * jax.random.normal(ks[6], (d,)))
+    probe = wide(7)
+
+    def kernels(cdt):
+        return lambda *a: kk.kda_layer(
+            *a, eps=1e-5, compute_dtype=cdt, interpret=interp)
+
+    def plain(cdt):
+        return lambda *a: kda_plain(
+            *a, eps=1e-5, chunk=32, compute_dtype=cdt)
+
+    with jax.default_matmul_precision("highest"):
+        want = both(plain("float32"), layer, probe)
+    # 128 carried states and sums over 8,192 positions re-associated:
+    # float32 against float32 reads 1e-5, not 1e-6
+    for cdt, tol in (("float32", 1e-3), ("bfloat16", 2e-2)):
+        got = both(kernels(cdt), layer, probe)
+        errs = [rel(a, w) for a, w in zip(got, want)]
+        worst = max(range(8), key=errs.__getitem__)
+        row(f"kda-layer-{cdt}-worst-of-y-and-7-grads", errs[worst], tol,
+            got[worst], *got)
 
     if not timing:
         return rows
-    # The cell's call, operands as the model holds them ([B, L, H*D]
-    # projections split into heads inside the program).
-    b, l, h, d = (1, 128, 2, 128) if tiny else (1, 8192, 32, 128)
-    *flat, probe = (x.reshape(b, l, -1) for x in operands(b, l, h, d, 1))
-    beta = flat.pop()
 
-    def heads(fn):
-        return lambda q, k, v, g, beta: fn(
-            *(x.reshape(b, l, h, d) for x in (q, k, v, g)), beta
-        ).reshape(b, l, -1)
-
-    def timed(fn, n=2 if tiny else 10):
+    def timed(fn, *args, n=2 if tiny else 10):
         fn = jax.jit(fn)
-        jax.block_until_ready(fn(*flat, beta))
+        jax.block_until_ready(fn(*args))
         t0 = time.perf_counter()
         for _ in range(n):
-            out = fn(*flat, beta)
+            out = fn(*args)
         jax.block_until_ready(out)
         return (time.perf_counter() - t0) / n * 1e3
 
     def fwd_and_both(fn):
-        loss = lambda *a: jnp.sum(heads(fn)(*a) * probe)  # noqa: E731
-        return {"forward_ms": timed(heads(fn)),
+        def loss(probe, *a):
+            return jnp.sum(fn(*a) * probe)
+
+        return {"forward_ms": timed(fn, *layer),
                 "forward_backward_ms": timed(
-                    jax.grad(loss, argnums=(0, 1, 2, 3, 4)))}
+                    jax.grad(loss, argnums=tuple(range(1, 8))),
+                    probe, *layer)}
 
     line = {"timing": "kda", "shape": [b, l, h, d],
             "compute_dtype": "bfloat16", "what": "host clock around "
-            "drained calls, ms a call", "interpret": interp}
-    print(json.dumps({**line, "path": "kda_chunked",
-                      **fwd_and_both(chunked("bfloat16"))}), flush=True)
+            "drained calls, ms a call of the layer's seven-operand call",
+            "interpret": interp}
+    print(json.dumps({**line, "path": "kda_chunked + XLA norms",
+                      **fwd_and_both(plain("bfloat16"))}), flush=True)
     for tile in tiles or (kk._TILE,):
         for unroll in unrolls or (kk._UNROLL,):
             kk._TILE, kk._UNROLL = tile, unroll
             print(json.dumps({**line, "path": "kernels", "tile": tile,
                               "heads_side_by_side": unroll,
-                              **fwd_and_both(kernel("bfloat16"))}),
+                              **fwd_and_both(kernels("bfloat16"))}),
                   flush=True)
     return rows
 
