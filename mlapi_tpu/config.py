@@ -404,3 +404,38 @@ register_preset(
         eval_every=100,
     )
 )
+
+# The Laguna family at CPU widths: five layers of the published kinds
+# (full attention + dense, three sliding-window layers and one full
+# layer over sparse experts), 6 / 8 query heads over 2 K/V heads, a
+# window shorter than the row, partial YaRN rotary on the full layers,
+# 16 experts with 4 a token of which this model holds the first 8.
+# Training only: the serving CLI refuses the checkpoint.
+register_preset(
+    TrainConfig(
+        name="docs-laguna",
+        model="laguna_lm",
+        model_kwargs={
+            "vocab_size": 260, "hidden_size": 64, "num_layers": 5,
+            "layer_types": ["full_attention", "sliding_attention",
+                            "sliding_attention", "sliding_attention",
+                            "full_attention"],
+            "heads_per_layer": [6, 8, 8, 8, 6],
+            "mlp_layer_types": ["dense", "sparse", "sparse", "sparse",
+                                "sparse"],
+            "num_kv_heads": 2, "head_dim": 16, "sliding_window": 32,
+            "intermediate_size": 256, "num_experts": 16,
+            "num_experts_per_tok": 4, "moe_intermediate_size": 32,
+            "shared_expert_intermediate_size": 32,
+            "experts_held": [0, 8], "moe_tile": 32,
+            "compute_dtype": "float32",
+        },
+        dataset="docs_text",
+        dataset_kwargs={"seq_len": 128},
+        steps=200,
+        batch_size=16,
+        optimizer="adamw",
+        learning_rate=1e-3,
+        eval_every=100,
+    )
+)

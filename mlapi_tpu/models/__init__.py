@@ -43,9 +43,11 @@ from mlapi_tpu.models import bert as _bert  # noqa: E402,F401
 from mlapi_tpu.models import gpt as _gpt  # noqa: E402,F401
 from mlapi_tpu.models import llama as _llama  # noqa: E402,F401
 from mlapi_tpu.models import kimi_linear as _kimi_linear  # noqa: E402,F401
+from mlapi_tpu.models import laguna as _laguna  # noqa: E402,F401
 from mlapi_tpu.models.bert import BertClassifier  # noqa: E402,F401
 from mlapi_tpu.models.gpt import GptLM  # noqa: E402,F401
 from mlapi_tpu.models.kimi_linear import KimiLinearLM  # noqa: E402,F401
+from mlapi_tpu.models.laguna import LagunaLM  # noqa: E402,F401
 from mlapi_tpu.models.lora import LoraModel  # noqa: E402,F401
 from mlapi_tpu.models.quantized import QuantizedModel  # noqa: E402,F401
 from mlapi_tpu.models.linear import LinearClassifier  # noqa: E402,F401

@@ -30,6 +30,7 @@ unchanged.
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 
 import jax
@@ -45,39 +46,92 @@ def _rms_norm(x, scale, eps: float = 1e-6):
     return (x32 * inv * scale.astype(jnp.float32)).astype(x.dtype)
 
 
-def _rope(x, positions, theta: float):
-    """Rotate ``x [B, L, H, D]`` by per-row-and-position angles.
+def rope_inv_freq(theta: float, dims: int):
+    """Plain rotary frequencies of ``dims`` rotated lanes:
+    ``theta ** (-2i / dims)``, ``i < dims / 2``."""
+    half = dims // 2
+    return theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+
+
+def yarn_inv_freq(theta: float, dims: int, *, factor: float,
+                  original_max: int, beta_fast: float, beta_slow: float):
+    """YaRN frequencies of ``dims`` rotated lanes (HF
+    ``_compute_yarn_parameters``, its floor / ceil and clamp included):
+    with ``f_i`` the plain frequencies, ``lo`` / ``hi`` the lane pairs
+    whose wavelength makes ``beta_fast`` / ``beta_slow`` rotations in
+    ``original_max`` positions and ``ramp_i = clip((i - lo) / (hi -
+    lo), 0, 1)``: ``f_i / factor * ramp_i + f_i * (1 - ramp_i)``. The
+    attention factor is the caller's ``scale`` of :func:`rotate`."""
+    def pair_of(rotations):
+        return dims * math.log(original_max / (rotations * 2 * math.pi)) / (
+            2 * math.log(theta))
+
+    lo = max(math.floor(pair_of(beta_fast)), 0)
+    hi = min(math.ceil(pair_of(beta_slow)), dims - 1)
+    if lo == hi:
+        hi += 0.001
+    f = rope_inv_freq(theta, dims)
+    ramp = jnp.clip(
+        (jnp.arange(dims // 2, dtype=jnp.float32) - lo) / (hi - lo), 0, 1)
+    return f / factor * ramp + f * (1 - ramp)
+
+
+def rotate(x, positions, inv_freq, *, rot_dims: int | None = None,
+           scale: float = 1.0):
+    """Rotate the first ``rot_dims`` lanes (default: all) of ``x [B, L,
+    H, D]`` by per-row-and-position angles ``positions * inv_freq``
+    (``inv_freq [rot_dims / 2]``: plain, YaRN or any other table is
+    data here); ``cos`` and ``sin`` are multiplied by ``scale`` (YaRN's
+    attention factor); the lanes beyond ``rot_dims`` pass unrotated.
 
     ``positions``: ``[B, L]`` int32 effective positions (already
     n_pad-shifted and clamped by callers). rotate-half convention:
-    pairs are (x[..., :D/2], x[..., D/2:]).
+    lane ``i < rot_dims / 2`` pairs with lane ``i + rot_dims / 2``.
 
     Written as ``x * cos + rotate_half(x) * sin`` over the FULL lane
-    dim, with ``rotate_half`` a constant-index gather — deliberately
-    NOT the textbook slice-halves-and-concatenate. Under GSPMD,
-    slice+concat over a dim the ``model`` axis shards finer than one
-    KV head (GQA: ``wk`` is ``[h, kvh*hd]``; a TP degree above
-    ``kvh`` splits heads) MISCOMPILES on this jax/XLA version — the
-    partitioner returns scrambled values, wrong by O(1) even at
-    position 0 where rope is the identity (repro pinned in
+    dim, with ``rotate_half`` a product with a constant signed
+    permutation matrix — deliberately NOT the textbook
+    slice-halves-and-concatenate. Under GSPMD, slice+concat over a dim
+    the ``model`` axis shards finer than one KV head (GQA: ``wk`` is
+    ``[h, kvh*hd]``; a TP degree above ``kvh`` splits heads)
+    MISCOMPILES on this jax/XLA version — the partitioner returns
+    scrambled values, wrong by O(1) even at position 0 where rope is
+    the identity (repro pinned in
     tests/test_llama.py::test_rope_is_identity_at_position_zero_tp).
-    The gather formulation partitions correctly under every layout
-    and is arithmetically identical (same multiplies/adds per lane).
+    A product partitions correctly under every layout and is exact:
+    every output lane is plus or minus ONE input lane, at ``HIGHEST``
+    precision in any dtype. (A constant-index ``take`` is as exact,
+    but the TPU compiler lowers it to a gather between two transposes
+    of the whole operand: described-v5e compile at ``[1, 8192, 64,
+    128]``, PERF.md, PR 36.)
     """
     d = x.shape[-1]
-    half = d // 2
+    r = d if rot_dims is None else rot_dims
+    half = r // 2
     lane = jnp.arange(d)
-    inv_freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
-    # Per-lane angle: lane j pairs with lane (j + half) % d and both
+    turned = lane < r
+    # Per-lane angle: lane j pairs with lane (j + half) % r and both
     # use frequency j % half.
-    ang = positions.astype(jnp.float32)[..., None] * inv_freq[lane % half]
-    cos = jnp.cos(ang)[:, :, None, :].astype(x.dtype)  # [B, L, 1, D]
-    sin = jnp.sin(ang)[:, :, None, :].astype(x.dtype)
-    # rotate_half(x)[j] = -x[j + half] (j < half) else x[j - half].
-    perm = jnp.concatenate([lane[half:], lane[:half]])
-    sign = jnp.where(lane < half, -1.0, 1.0).astype(x.dtype)
-    xr = jnp.take(x, perm, axis=-1) * sign
+    ang = positions.astype(jnp.float32)[..., None] * jnp.where(
+        turned, inv_freq[lane % half], 0.0)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
+    cos = jnp.where(turned, cos, 1.0)[:, :, None, :].astype(x.dtype)
+    sin = jnp.where(turned, sin, 0.0)[:, :, None, :].astype(x.dtype)
+    # rotate_half(x)[j] = -x[j + half] (j < half), x[j - half]
+    # (half <= j < r), nothing beyond r.
+    src = jnp.where(lane < half, lane + half, lane - half)
+    sign = jnp.where(lane < half, -1.0, jnp.where(turned, 1.0, 0.0))
+    perm = (lane[:, None] == src[None, :]) * sign[None, :]
+    xr = jnp.einsum("blhd,de->blhe", x, perm.astype(x.dtype),
+                    precision=jax.lax.Precision.HIGHEST)
     return x * cos + xr * sin
+
+
+def _rope(x, positions, theta: float):
+    """Plain rotary over all lanes of ``x [B, L, H, D]``."""
+    return rotate(x, positions, rope_inv_freq(theta, x.shape[-1]))
 
 
 @register_model("llama_lm")

@@ -1017,10 +1017,16 @@ def _counted_flash(q, k, v, mask, causal, scale, block_q, block_k,
     """``_flash`` behind the two entry points, counted where the
     blocking is chosen: once a TRACE in ``utils.metrics.REGISTRY``
     (``flash.calls_traced``; ``flash.calls_row_blocked`` when the
-    one-tile kernels take the call). Nothing is counted per step."""
+    one-tile kernels take the call; ``flash.calls_windowed`` when it
+    carries a window; ``flash.calls_gqa`` when K/V heads are fewer than
+    query heads). Nothing is counted per step."""
     REGISTRY.counter("flash.calls_traced").inc()
     if _one_tile_heads(q, k, block_q, block_k, v):
         REGISTRY.counter("flash.calls_row_blocked").inc()
+    if window is not None:
+        REGISTRY.counter("flash.calls_windowed").inc()
+    if k.shape[2] != q.shape[2]:
+        REGISTRY.counter("flash.calls_gqa").inc()
     return _flash(
         q, k, v, mask.astype(jnp.float32), causal, scale, block_q, block_k,
         interpret, window,

@@ -172,6 +172,116 @@ def test_flash_attention_latent_heads_stream(one_chip, direction):
     )
 
 
+_KERNEL = r"^\s*%?([a-z_]+?)[.\d]* = .*custom_call_target=\"tpu_custom_call\""
+
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+@pytest.mark.parametrize("heads,window", [(64, 512), (48, None)])
+def test_flash_attention_laguna_calls_stream(one_chip, heads, window,
+                                             direction):
+    """The two attention calls of ``laguna_lm`` at the cell
+    ``laguna-xs2.pretrain_8k``: one row of 8,192 positions, heads of
+    128, 8 K/V heads under 64 query heads with a window of 512 (the
+    windowed grids: 2 of 16 k-tiles a q-tile, 2 of 16 q-tiles a k-tile)
+    and under 48 causal. The streaming kernels take both, K/V indexed
+    ``h // group`` and never repeated."""
+    b, l, kvh, d = 1, 8192, 8, 128
+    q = _shape((b, l, heads, d), jnp.bfloat16, one_chip)
+    kv = _shape((b, l, kvh, d), jnp.bfloat16, one_chip)
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True, window=window,
+                               interpret=False)
+
+    if direction == "forward":
+        fn = fwd
+    else:
+        def fn(q, k, v):
+            loss = lambda q, k, v: jnp.sum(  # noqa: E731
+                fwd(q, k, v).astype(jnp.float32) ** 2
+            )
+            return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    txt = _compile(fn, q, kv, kv).as_text()
+    assert txt.count('custom_call_target="tpu_custom_call"') == (
+        1 if direction == "forward" else 3)
+    if direction == "forward":  # no K or V made as wide as the queries
+        assert not re.findall(rf"\[{b},{heads},{l},{d}\]\S* broadcast\(", txt)
+    shapes = [o.shape for o in jax.tree.leaves(jax.eval_shape(fn, q, kv, kv))]
+    assert shapes == ([(b, l, heads, d)] if direction == "forward" else
+                      [(b, l, heads, d), (b, l, kvh, d), (b, l, kvh, d)])
+
+
+def _laguna_kwargs():
+    import json
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(__file__)),
+                           "benchmark", "configs",
+                           "laguna-xs2-ep8.json")) as f:
+        return json.load(f)["program"]["model_kwargs"]
+
+
+@pytest.mark.parametrize("kind", ["sliding_attention", "full_attention"])
+def test_laguna_block_at_published_widths(one_chip, monkeypatch, kind):
+    """One whole block of ``laguna_lm`` at the published widths (1 x
+    8192; norm, projections, rotary, the flash call, headwise gate,
+    output projection, router, 32 held experts, shared expert) under
+    the model's ``jax.checkpoint`` policy, gradient in the parameters:
+    three flash kernels (forward once, dq, dk/dv) and a rotation with
+    no gather."""
+    from mlapi_tpu.models import get_model, laguna
+
+    # code that asks the backend sees the CPU here: steer it in the test
+    monkeypatch.setattr(laguna, "pallas_interpret", lambda: False)
+    model = get_model("laguna_lm", **dict(
+        _laguna_kwargs(), vocab_size=1024, num_layers=1, layer_types=[kind],
+        heads_per_layer=[64 if kind == "sliding_attention" else 48],
+        mlp_layer_types=["sparse"]))
+    assert model.remat
+    params = jax.tree.map(
+        lambda a: _shape(a.shape, a.dtype, one_chip),
+        jax.eval_shape(model.init, jax.random.key(0)))
+    ids = _shape((1, 8192), jnp.int32, one_chip)
+    txt = _compile(
+        jax.grad(lambda p, x: jnp.mean(model.apply(p, x))), params, ids
+    ).as_text()
+    assert re.findall(_KERNEL, txt, re.M) == ["flash_attention"] * 3
+    assert not re.findall(r"\[[\d,]*8192,\d+,128\]\S* gather\(", txt)
+
+
+def test_laguna_step_fits_the_chip(one_chip, monkeypatch):
+    """The cell ``laguna-xs2.pretrain_8k``'s whole step as ``fit``
+    builds it (``make_train_step``, AdamW, 1 x 8192, all five layers at
+    the published widths): the chip's compiler takes it, it holds 15
+    flash kernels (five layers x forward, dq, dk/dv: no forward runs
+    twice) and weights + AdamW state + the step's temporaries stay
+    under the 15.75 GB a v5e reports as its limit (read here: 8.30 GB
+    of arguments, all aliased to the outputs, + 4.32 GB)."""
+    import optax
+
+    from mlapi_tpu.models import get_model, laguna
+    from mlapi_tpu.train.loop import make_train_step
+
+    monkeypatch.setattr(laguna, "pallas_interpret", lambda: False)
+    model = get_model("laguna_lm", **_laguna_kwargs())
+    tx = optax.adamw(1e-4)
+    shaped = lambda t: jax.tree.map(  # noqa: E731
+        lambda a: _shape(a.shape, a.dtype, one_chip), t)
+    params = jax.eval_shape(model.init, jax.random.key(0))
+    assert sum(int(np.prod(a.shape))
+               for a in jax.tree.leaves(params)) == 691_623_936
+    step = make_train_step(model.apply, tx, task="lm",
+                           stats_apply=model.apply_with_stats)
+    ids = _shape((1, 8192), jnp.int32, one_chip)
+    compiled = step.lower(shaped(params), shaped(jax.eval_shape(
+        tx.init, params)), ids, ids).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+    assert mem.alias_size_in_bytes > 0.99 * mem.argument_size_in_bytes
+    assert re.findall(_KERNEL, compiled.as_text(), re.M) == [
+        "flash_attention"] * 15
+
+
 def _sized_f32(txt, ops, elements):
     """The float32 results of ``ops`` instructions (a regular
     expression over HLO opcodes) that hold ``elements`` numbers or more.
@@ -249,8 +359,6 @@ def _kimi_blocks(one_chip, monkeypatch, **layers):
         jax.grad(lambda p, x: jnp.mean(model.apply(p, x))), params, ids
     ).as_text()
 
-
-_KERNEL = r"^\s*%?([a-z_]+?)[.\d]* = .*custom_call_target=\"tpu_custom_call\""
 
 
 def test_recomputed_blocks_run_each_forward_kernel_once(one_chip, monkeypatch):

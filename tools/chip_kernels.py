@@ -7,7 +7,10 @@ head geometry ``chip_smoke.py`` serves (BERT-base / GPT-2 small:
                                             # cache formats; flash also
                                             # at the benchmark cell's
                                             # shapes and at L = 1024,
-                                            # timed beside XLA's own;
+                                            # timed beside XLA's own,
+                                            # and streaming at 1 x 8192
+                                            # with 64/8 heads under a
+                                            # window and 48/8 causal;
                                             # the gated delta rule's
                                             # agreement rows
     python -m tools.chip_kernels --tp       # decode_attention_tp on a
@@ -227,6 +230,48 @@ def run(tiny: bool, tp: bool) -> list[dict]:
             "kernel_ms": timed_grad(kern),
             "xla_full_attention_ms": timed_grad(xla),
         }), flush=True)
+
+    # The streaming kernels at the calls of the cell
+    # laguna-xs2.pretrain_8k: 1 x 8192, 64 query heads over 8 K/V heads
+    # with a window of 512 (the windowed grids of all three kernels)
+    # and 48 over 8 causal, forward and gradients against _jnp_flash in
+    # float32 at the highest precision. The oracle holds [heads, L, L]
+    # scores, so it follows ONE K/V head's group (the last): that
+    # group's rows of out and dq and that head's dk, dv depend on no
+    # other group.
+    from mlapi_tpu.ops.pallas.flash_attention import _jnp_flash
+
+    sl, sd, skv = (256, 16, 2) if tiny else (8192, 128, 8)
+    sblocks = {"block_q": 64, "block_k": 64} if tiny else {}
+    for tag, sh, window in (
+        ("swa", 8 if tiny else 64, 64 if tiny else 512),
+        ("gqa", 6 if tiny else 48, None),
+    ):
+        group = sh // skv
+        sq = normal(1, sl, sh, sd)
+        sk, sv = normal(1, sl, skv, sd), normal(1, sl, skv, sd)
+        last = slice(sh - group, sh)
+
+        def kern(q, k, v):
+            return flash_attention(q, k, v, causal=True, window=window,
+                                   interpret=interp, **sblocks)
+
+        def ref(q, k, v):
+            with jax.default_matmul_precision("highest"):
+                return _jnp_flash(q, k, v, jnp.ones((1, sl), jnp.float32),
+                                  True, sd ** -0.5, window)[0]
+
+        part = [x.astype(jnp.float32) for x in
+                (sq[:, :, last], sk[:, :, -1:], sv[:, :, -1:])]
+        name = f"flash_attention-{tag}-{sh}over{skv}"
+        case(name, kern(sq, sk, sv)[:, :, last], jax.jit(ref)(*part))
+        g_kernel = jax.jit(jax.grad(loss(kern), argnums=(0, 1, 2)))(sq, sk, sv)
+        g_ref = jax.jit(jax.grad(loss(ref), argnums=(0, 1, 2)))(*part)
+        for nm, gk, gr, sel in zip("qkv", g_kernel, g_ref,
+                                   (last, slice(-1, None), slice(-1, None))):
+            norm = float(jnp.max(jnp.abs(gr))) or 1.0
+            case(f"{name}-grad-d{nm}",
+                 gk[:, :, sel].astype(jnp.float32) / norm, gr / norm)
 
     # The four cache-read kernels x both stored formats.
     n_pages = lk // page
