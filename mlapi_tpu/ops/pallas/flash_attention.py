@@ -38,19 +38,26 @@ of statistics) and sat between eight full-size layout copies a layer —
 
 **Longer sequences** stream (TPU: the grid is iterated sequentially,
 last dimension innermost; VMEM scratch persists across grid steps,
-which is what carries the online-softmax state between K tiles):
+which is what carries the online-softmax state between K tiles).
+A static tile schedule (``_tile_schedule``, built once a trace from
+the lengths, blocks, causality and window) classes every (q-tile,
+k-tile) pair as dead (no kept pair), masked (the causal diagonal or
+the window's edge cuts it) or full, and each kernel's last grid
+dimension walks the live pairs alone, from int32 tables handed to the
+index maps by scalar prefetch (``_walk``):
 
-- forward:   ``(B, H, L/block_q, L/block_k)`` — one q-tile's output
-  accumulates across the inner k-steps, written at the last k-step.
-- backward dq: ``(B, H, L/block_q, k-tiles)``; dq accumulates across
-  the (window-shrunken, when windowed) k-steps.
-- backward dk/dv: ``(B, KVH, L/block_k, group × q-tiles)`` — one kv
-  head's whole query group accumulates consecutively into its
-  KVH-wide dk/dv block (GQA-native; no repeated K/V in either pass),
-  with the inner q-range window-shrunken when windowed.
+- forward: ``(B, H, live tiles)``, q-major — a q-tile's live k-tiles
+  in ascending order, its output accumulating across them.
+- backward dq: ``(B, H, live tiles)``, the same walk.
+- backward dk/dv: ``(B, KVH, group × live tiles)``, k-major — for one
+  k-tile its kv head's query heads in turn, each head's live q-tiles
+  ascending, accumulating consecutively into the KVH-wide dk/dv block
+  (GQA-native; no repeated K/V in either pass).
 
-Causal masking skips whole tiles above the diagonal (``pl.when``
-predication), so causal attention does ~half the work.
+A dead tile is no grid step (no copy, no compute): causal attention
+does about half the work, a window O(L·window). A full tile runs the
+body without the positional mask; without a key mask (``mask=None``,
+the LM callers) neither the mask operand nor its multiply exists.
 
 Per-program VMEM is a few ``block×block`` f32 tiles (~2-3 MB at the
 default 512/512 blocks — measured 2x faster than 128/128 at L=8192
@@ -62,8 +69,9 @@ Longer sequences still belong to the sequence-parallel path
 (``mlapi_tpu.ops.ring_attention``).
 
 Layout convention matches ``mlapi_tpu.ops.attention``: ``q, k, v``
-are ``[B, L, H, D]``, ``mask`` is binary ``[B, L]`` over keys; fully
-masked query rows return zeros (all three attention impls agree).
+are ``[B, L, H, D]``, ``mask`` is binary ``[B, L]`` over keys or
+None; fully masked query rows return zeros (all three attention impls
+agree).
 Grouped-query attention is native in both passes: ``k``/``v`` may
 carry ``H / group`` heads and the kernels index kv head ``h //
 group`` — the repeated K/V tensor never exists in HBM.
@@ -77,6 +85,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -91,100 +100,128 @@ _NEG = -1e30
 _LANES = 128
 
 
-def _keep_tile(mask_ref, causal, qi, ki, block_q, block_k, shape,
+# Bits of a walk's flags table (``_walk``): the step's tile is cut by
+# the causal diagonal or the window's edge; the step opens / closes a
+# run of steps that revisit one output block.
+_MASKED, _FIRST, _LAST = 1, 2, 4
+
+
+def _keep_tile(mask_ref, positional, qi, ki, block_q, block_k, shape,
                window=None):
-    """Binary keep-mask for one (q-tile, k-tile) score block.
-    ``window`` (causal-only) keeps keys within the last ``window``
-    positions of each query: ``q_pos - k_pos < window``."""
-    keep = mask_ref[0, 0][None, :].astype(jnp.float32)
-    if causal:
+    """Binary keep-mask for one (q-tile, k-tile) score block: the key
+    mask's row (``mask_ref`` None: the caller gave none) times, when
+    ``positional``, causality and the window (keys within the last
+    ``window`` positions of each query: ``q_pos - k_pos < window``).
+    None where nothing is masked: every pair is kept."""
+    keep = None
+    if mask_ref is not None:
+        keep = mask_ref[0, 0][None, :].astype(jnp.float32)
+    if positional:
         q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
         k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-        keep = keep * (q_pos >= k_pos)
+        keep = _times(keep, q_pos >= k_pos)
         if window is not None:
-            keep = keep * (q_pos - k_pos < window)
+            keep = _times(keep, q_pos - k_pos < window)
     return keep
 
 
-def _live_k_tiles(block_q, block_k, window):
-    """Exact worst-case number of k-tiles any q-tile can see under a
-    causal window — enumerated over the gcd residue classes of q-tile
-    alignments (all static at trace time). Single source of truth for
-    the forward and dq shrunken grids."""
-    g = math.gcd(block_q, block_k)
-    best = 0
-    for r in range(0, block_k, g):
-        first = (r - window + 1) // block_k  # floor; may be < 0
-        last = (r + block_q - 1) // block_k
-        best = max(best, last - first + 1)
-    return best
+def _times(keep, cut):
+    """``keep * cut``, where a None ``keep`` keeps every pair."""
+    return cut.astype(jnp.float32) if keep is None else keep * cut
 
 
-def _live_q_tiles(block_q, block_k, window):
-    """Exact worst-case number of q-tiles any k-tile can feed (the
-    dkv grid's inner extent), offset from the k-tile's first live
-    q-tile ``(ki * block_k) // block_q``."""
-    g = math.gcd(block_q, block_k)
-    best = 0
-    for r in range(0, block_q, g):
-        best = max(best, (r + block_k + window - 2) // block_q + 1)
-    return best
+@functools.lru_cache(maxsize=None)
+def _tile_schedule(lq, lk, block_q, block_k, causal, window):
+    """The live (q-tile, k-tile) pairs, q-major, each with whether the
+    causal diagonal or the window's edge cuts it: ``((qi, ki, masked),
+    ...)``. A tile holds every distance ``q - k`` from ``lo`` to
+    ``hi``; causal attention keeps the distances in ``[0, window)``
+    (``window`` None: ``[0, inf)``), so a tile is dead when none of its
+    distances is kept and full when all are. Not causal: every tile is
+    full. Static at trace time; every q-tile and every k-tile of a
+    causal call keeps its diagonal, so every output block is written."""
+    top = math.inf if window is None else window - 1
+    tiles = []
+    for qi in range(lq // block_q):
+        for ki in range(lk // block_k):
+            lo = qi * block_q - (ki + 1) * block_k + 1
+            hi = (qi + 1) * block_q - 1 - ki * block_k
+            if not causal:
+                tiles.append((qi, ki, False))
+            elif hi >= 0 and lo <= top:
+                tiles.append((qi, ki, lo < 0 or hi > top))
+    return tuple(tiles)
 
 
-def _window_k_tile(qi, ki, block_q, block_k, nkw):
-    """Physical k-tile index for window-relative step ``ki`` of a
-    shrunken k-grid: the last ``nkw`` tiles ending at the q-tile's
-    diagonal tile. May be negative (caller clamps + skips)."""
-    last = (qi * block_q + block_q - 1) // block_k
-    return last - (nkw - 1) + ki
+@functools.lru_cache(maxsize=None)
+def _walk(tiles, group):
+    """One kernel's last grid dimension as four int32 tables for scalar
+    prefetch (SMEM: a few KB at L = 8192): step ``n`` works on q-tile
+    ``qi[n]`` against k-tile ``ki[n]``, for query head ``g[n]`` of a kv
+    head's group, with ``flags[n]`` (``_MASKED``, ``_FIRST``,
+    ``_LAST``). ``group`` 0: q-major, the forward's and dq's walk (a
+    q-tile's live k-tiles ascending; ``g`` is 0 and the head is a grid
+    dimension). Else k-major, dk/dv's: for each k-tile, the group's
+    query heads in turn and each head's live q-tiles ascending, all
+    revisiting the k-tile's dk/dv block."""
+    if group:
+        steps = [(qi, ki, g, m)
+                 for ki in sorted({t[1] for t in tiles})
+                 for g in range(group)
+                 for qi, kj, m in tiles if kj == ki]
+        run = [s[1] for s in steps]
+    else:
+        steps = [(qi, ki, 0, m) for qi, ki, m in tiles]
+        run = [s[0] for s in steps]
+    last = len(steps) - 1
+    flags = [
+        _MASKED * s[3]
+        | _FIRST * (n == 0 or run[n - 1] != run[n])
+        | _LAST * (n == last or run[n + 1] != run[n])
+        for n, s in enumerate(steps)
+    ]
+    qi, ki, g, _ = zip(*steps)
+    return tuple(np.asarray(c, np.int32) for c in (qi, ki, g, flags))
 
 
-def _tile_live(causal, window, qi, ki, block_q, block_k):
-    """Static-shape predicate: does this (q-tile, k-tile) pair contain
-    ANY attendable position? Causal skips tiles above the diagonal;
-    a window additionally skips tiles entirely older than the oldest
-    key any query in the tile can see. Windowed kernels normally
-    bypass this predicate — all three grids shrink to the live tiles
-    (``_live_k_tiles`` / ``_live_q_tiles``), so steady-state tiles do
-    O(window/block) steps in compute AND copies — and fall back to
-    the full grid + this predicate when the window covers most of the
-    sequence."""
-    live = (qi + 1) * block_q > ki * block_k if causal else True
-    if causal and window is not None:
-        live = jnp.logical_and(
-            live, (ki + 1) * block_k + window > qi * block_q + 1
-        )
-    return live
+def _tile_kinds(tiles):
+    """The static tile kinds (masked or not) a schedule holds."""
+    return tuple(sorted({m for *_, m in tiles}))
+
+
+def _by_kind(flags, kinds, body):
+    """``body(masked)`` for the step's tile kind: one body a kind the
+    walk holds, each under its predicate when it holds both."""
+    if len(kinds) == 1:
+        body(kinds[0])
+        return
+    masked = (flags & _MASKED) != 0
+    pl.when(masked)(lambda: body(True))
+    pl.when(jnp.logical_not(masked))(lambda: body(False))
+
+
+def _split_mask(refs, key_mask):
+    """``(mask_ref or None, the refs after it)``: the key mask is an
+    operand only when the caller gave one."""
+    return (refs[0], refs[1:]) if key_mask else (None, refs)
 
 
 def _fwd_kernel(
-    q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, m_s, l_s, acc_s,
-    *, scale, causal, block_q, block_k, window=None, windowed_grid=False,
+    qt, kt, gt, ft, q_ref, k_ref, v_ref, *refs, scale, block_q, block_k,
+    window, key_mask, kinds,
 ):
-    qi, ki = pl.program_id(2), pl.program_id(3)
-    nk = pl.num_programs(3)
+    del gt
+    mask_ref, (o_ref, lse_ref, m_s, l_s, acc_s) = _split_mask(refs, key_mask)
+    n = pl.program_id(2)
+    flags = ft[n]
 
-    @pl.when(ki == 0)
+    @pl.when((flags & _FIRST) != 0)
     def _init():
         m_s[:] = jnp.full_like(m_s, _NEG)
         l_s[:] = jnp.zeros_like(l_s)
         acc_s[:] = jnp.zeros_like(acc_s)
 
-    if windowed_grid:
-        # Shrunken k-grid: ki is WINDOW-RELATIVE. The physical k-tile
-        # is the same expression the BlockSpec index map uses; tiles
-        # whose unclamped index is negative are duplicates of tile 0
-        # (index maps can't go below 0) and must not contribute twice.
-        kb_raw = _window_k_tile(qi, ki, block_q, block_k, nk)
-        kb = jnp.maximum(kb_raw, 0)
-        run = kb_raw >= 0
-    else:
-        kb = ki
-        # Causal/window: tiles with no attendable position are skipped.
-        run = _tile_live(causal, window, qi, ki, block_q, block_k)
-
-    @pl.when(run)
-    def _step():
+    def _step(masked):
         q = q_ref[0, 0]
         k = k_ref[0, 0]
         v = v_ref[0, 0]
@@ -197,17 +234,20 @@ def _fwd_kernel(
             * scale
         )  # [block_q, block_k]
         keep = _keep_tile(
-            mask_ref, causal, qi, kb, block_q, block_k, s.shape, window
+            mask_ref, masked, qt[n], kt[n], block_q, block_k, s.shape, window
         )
-        s = s + (1.0 - keep) * _NEG
+        if keep is not None:
+            s = s + (1.0 - keep) * _NEG
 
         m_prev = m_s[:, :1]
         l_prev = l_s[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        # exp(NEG - NEG) == 1 on lanes with no valid key; * keep zeroes
-        # them so fully-masked rows come out 0, not NaN.
-        p = jnp.exp(s - m_new) * keep
+        p = jnp.exp(s - m_new)
+        if keep is not None:
+            # exp(NEG - NEG) == 1 on lanes with no valid key; * keep
+            # zeroes them so fully-masked rows come out 0, not NaN.
+            p = p * keep
         l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
         acc_s[:] = acc_s[:] * alpha + jax.lax.dot_general(
             p.astype(v.dtype), v,
@@ -217,7 +257,9 @@ def _fwd_kernel(
         m_s[:] = jnp.broadcast_to(m_new, m_s.shape)
         l_s[:] = jnp.broadcast_to(l_new, l_s.shape)
 
-    @pl.when(ki == nk - 1)
+    _by_kind(flags, kinds, _step)
+
+    @pl.when((flags & _LAST) != 0)
     def _finish():
         l = l_s[:, :1]
         o_ref[0, 0] = (acc_s[:] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
@@ -249,7 +291,7 @@ def _jnp_flash(q, k, v, mask, causal, scale, window=None):
         )
         * scale
     )
-    keep = mask.astype(jnp.float32)[:, None, None, :]
+    keep = _with_mask(mask, q, k).astype(jnp.float32)[:, None, None, :]
     if causal:
         lq, lk = q.shape[1], k.shape[1]
         dist = jnp.arange(lq)[:, None] - jnp.arange(lk)[None, :]
@@ -573,11 +615,40 @@ def _bwd_rows(q, k, v, mask, out, lse, g, g_lse, causal, scale, interpret,
     return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
 
+def _with_mask(mask, q, k):
+    """The key mask the one-tile kernels and ``_jnp_flash`` read: the
+    caller's, or all ones where it gave none."""
+    if mask is None:
+        return jnp.ones((q.shape[0], k.shape[1]), jnp.float32)
+    return mask
+
+
+def _stream_call(kernel, tables, grid, in_specs, out_specs, out_shape,
+                 scratch_shapes, interpret, **static):
+    """The ``pallas_call`` of one streaming kernel, as a function of the
+    kernel's own operands: its grid's last dimension walks ``tables``
+    (``_walk``), which ride ahead of them as scalar prefetch."""
+    call = pl.pallas_call(
+        functools.partial(kernel, **static),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(tables),
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=out_specs,
+            scratch_shapes=scratch_shapes,
+        ),
+        out_shape=out_shape,
+        interpret=interpret,
+    )
+    return lambda *operands: call(*tables, *operands)
+
+
 def _fwd(q, k, v, mask, causal, scale, block_q, block_k, interpret,
          window=None):
     hb = _one_tile_heads(q, k, block_q, block_k, v)
     if hb:
-        return _fwd_rows(q, k, v, mask, causal, scale, interpret, window, hb)
+        return _fwd_rows(q, k, v, _with_mask(mask, q, k), causal, scale,
+                         interpret, window, hb)
     b, lq, h, d = q.shape
     lk, dv = k.shape[1], v.shape[-1]
     # GQA: k/v may carry fewer heads than q (validated in _prepare);
@@ -585,75 +656,42 @@ def _fwd(q, k, v, mask, causal, scale, block_q, block_k, interpret,
     # streams its group's K/V block straight from HBM — no repeated
     # K/V tensor is ever materialised.
     group = h // k.shape[2]
+    tiles = _tile_schedule(lq, lk, block_q, block_k, causal, window)
+    # [B, L, H, D] -> [B, H, L, D]: heads become a grid dimension.
+    qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+
+    # Index maps take the grid indices, then the four tables.
+    def q_map(bi, hi, n, qi, ki, g, f):
+        return (bi, hi, qi[n], 0)
+
+    def kv_map(bi, hi, n, qi, ki, g, f):
+        return (bi, hi // group, ki[n], 0)
+
+    def mask_map(bi, hi, n, qi, ki, g, f):
+        return (bi, 0, ki[n])
+
     # [B, 1, L]: TPU lowering wants the last two block dims tile-
     # aligned or equal to the array dims; a (1, 1, block_k) block
     # satisfies that where a (1, block_k) block over [B, L] cannot
     # when B > 1.
-    mask3 = mask.astype(jnp.float32)[:, None, :]
-    # [B, L, H, D] -> [B, H, L, D]: heads become a grid dimension.
-    qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
-
-    nk_full = lk // block_k
-    # Sliding window: walk only the k-tiles a q-tile can see — the
-    # last nkw tiles ending at its diagonal tile. nkw is the EXACT
-    # worst case over q-tile alignments (enumerated over the
-    # gcd(block_q, block_k) residue classes — everything here is
-    # static at trace time), so for aligned blocks no q-tile pays a
-    # spare inner step. Early q-tiles whose unclamped tile index is
-    # negative still occupy their grid steps (the index map clamps to
-    # tile 0 and its copy happens; only the compute is skipped) — the
-    # O(L·window) claim is about the common steady-state q-tiles.
-    if causal and window is not None:
-        nkw = min(nk_full, _live_k_tiles(block_q, block_k, window))
-    else:
-        nkw = nk_full
-    windowed_grid = nkw < nk_full
-    grid = (b, h, lq // block_q, nkw)
-    q_spec = pl.BlockSpec(
-        (1, 1, block_q, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)
-    )
-    if windowed_grid:
-        def _kmap(bi, hi, qi, ki):
-            kb = _window_k_tile(qi, ki, block_q, block_k, nkw)
-            return (bi, hi // group, jnp.maximum(kb, 0), 0)
-
-        def _mmap(bi, hi, qi, ki):
-            kb = _window_k_tile(qi, ki, block_q, block_k, nkw)
-            return (bi, 0, jnp.maximum(kb, 0))
-
-        mask_spec = pl.BlockSpec((1, 1, block_k), _mmap)
-    else:
-        def _kmap(bi, hi, qi, ki):
-            return (bi, hi // group, ki, 0)
-
-        mask_spec = pl.BlockSpec(
-            (1, 1, block_k), lambda bi, hi, qi, ki: (bi, 0, ki)
-        )
-    # Scores run over the query/key width ``d``, values and the output
-    # over ``dv`` (the same unless the value heads are narrower).
-    k_spec = pl.BlockSpec((1, 1, block_k, d), _kmap)
-    v_spec = pl.BlockSpec((1, 1, block_k, dv), _kmap)
-    o_spec = pl.BlockSpec(
-        (1, 1, block_q, dv), lambda bi, hi, qi, ki: (bi, hi, qi, 0)
-    )
+    masks = () if mask is None else (mask[:, None, :],)
     # LSE rides as [B, H, L, 1]: Mosaic requires the last two block
     # dims tile-aligned (8, 128) or equal to the array dims; a
     # (1, 1, block_q) block over [B, H, L] fails that for H > 1,
     # while (1, 1, block_q, 1) passes (block_q % 8 == 0, trailing
     # 1 == array dim) and keeps the row state sublane-aligned.
-    lse_spec = pl.BlockSpec(
-        (1, 1, block_q, 1), lambda bi, hi, qi, ki: (bi, hi, qi, 0)
-    )
-
-    out, lse = pl.pallas_call(
-        functools.partial(
-            _fwd_kernel, scale=scale, causal=causal,
-            block_q=block_q, block_k=block_k, window=window,
-            windowed_grid=windowed_grid,
-        ),
-        grid=grid,
-        in_specs=[q_spec, k_spec, v_spec, mask_spec],
-        out_specs=[o_spec, lse_spec],
+    # Scores run over the query/key width ``d``, values and the output
+    # over ``dv`` (the same unless the value heads are narrower).
+    out, lse = _stream_call(
+        _fwd_kernel, _walk(tiles, 0), (b, h, len(tiles)),
+        [pl.BlockSpec((1, 1, block_q, d), q_map),
+         pl.BlockSpec((1, 1, block_k, d), kv_map),
+         pl.BlockSpec((1, 1, block_k, dv), kv_map)]
+        + [pl.BlockSpec((1, 1, block_k), mask_map)] * len(masks),
+        out_specs=[
+            pl.BlockSpec((1, 1, block_q, dv), q_map),
+            pl.BlockSpec((1, 1, block_q, 1), q_map),
+        ],
         out_shape=[
             _out_struct((b, h, lq, dv), q.dtype, q),
             _out_struct((b, h, lq, 1), jnp.float32, q),
@@ -663,132 +701,103 @@ def _fwd(q, k, v, mask, causal, scale, block_q, block_k, interpret,
             pltpu.VMEM((block_q, _LANES), jnp.float32),  # running sum l
             pltpu.VMEM((block_q, dv), jnp.float32),      # output acc
         ],
-        interpret=interpret,
-    )(qt, kt, vt, mask3)
+        interpret=interpret, scale=scale, block_q=block_q,
+        block_k=block_k, window=window, key_mask=mask is not None,
+        kinds=_tile_kinds(tiles),
+    )(qt, kt, vt, *masks)
     return out.transpose(0, 2, 1, 3), lse[..., 0]
 
 
-def _bwd_dq_kernel(
-    q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref, dq_ref,
-    dq_s, *, scale, causal, block_q, block_k, window=None,
-    windowed_grid=False,
-):
-    qi, kr = pl.program_id(2), pl.program_id(3)
-    nk = pl.num_programs(3)
+def _recompute_ds(q, k, v, do, lse, delta, keep, scale):
+    """One tile's probabilities and score gradients, recomputed from
+    the saved LSE. All matmuls take native-dtype (bf16) operands with
+    f32 accumulation — the MXU recipe; f32 lives only in the
+    softmax-recompute elementwise math. Masked lanes give exp(NEG -
+    lse), large but finite (lse >= NEG + log(eps)); * keep zeroes
+    them, so no NaN even for fully-masked rows."""
+    s = (
+        jax.lax.dot_general(
+            q, k,
+            dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        * scale
+    )  # [block_q, block_k]
+    if keep is not None:
+        s = s + (1.0 - keep) * _NEG
+    p = jnp.exp(s - lse)
+    if keep is not None:
+        p = p * keep
+    dp = jax.lax.dot_general(
+        do, v,
+        dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )  # [block_q, block_k]
+    return p, p * (dp - delta) * scale
 
-    @pl.when(kr == 0)
+
+def _bwd_dq_kernel(
+    qt, kt, gt, ft, q_ref, k_ref, v_ref, *refs, scale, block_q, block_k,
+    window, key_mask, kinds,
+):
+    del gt
+    mask_ref, (do_ref, lse_ref, delta_ref, dq_ref, dq_s) = _split_mask(
+        refs, key_mask)
+    n = pl.program_id(2)
+    flags = ft[n]
+
+    @pl.when((flags & _FIRST) != 0)
     def _init():
         dq_s[:] = jnp.zeros_like(dq_s)
 
-    if windowed_grid:
-        # Shrunken inner k-grid, same mapping as the forward.
-        kb_raw = _window_k_tile(qi, kr, block_q, block_k, nk)
-        ki = jnp.maximum(kb_raw, 0)
-        run = kb_raw >= 0
-    else:
-        ki = kr
-        run = _tile_live(causal, window, qi, ki, block_q, block_k)
-
-    @pl.when(run)
-    def _step():
+    def _step(masked):
         q = q_ref[0, 0]
         k = k_ref[0, 0]
-        v = v_ref[0, 0]
-        do = do_ref[0, 0]
-        lse = lse_ref[0, 0]                   # [block_q, 1] column
-        delta = delta_ref[0, 0]               # [block_q, 1] column
-
-        # All matmuls take native-dtype (bf16) operands with f32
-        # accumulation — the MXU recipe; f32 lives only in the
-        # softmax-recompute elementwise math.
-        s = (
-            jax.lax.dot_general(
-                q, k,
-                dimension_numbers=(((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            * scale
-        )
-        keep = _keep_tile(
-            mask_ref, causal, qi, ki, block_q, block_k, s.shape, window
-        )
-        s = s + (1.0 - keep) * _NEG
-        # Recompute probabilities from the saved LSE. Masked lanes give
-        # exp(NEG - lse) — large but finite (lse >= NEG + log(eps)) —
-        # then * keep zeroes them, so no NaN even for fully-masked rows.
-        p = jnp.exp(s - lse) * keep
-        dp = jax.lax.dot_general(
-            do, v,
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [block_q, block_k]
-        ds = p * (dp - delta) * scale
+        keep = _keep_tile(mask_ref, masked, qt[n], kt[n], block_q, block_k,
+                          (block_q, block_k), window)
+        # lse, delta: [block_q, 1] columns.
+        _, ds = _recompute_ds(q, k, v_ref[0, 0], do_ref[0, 0],
+                              lse_ref[0, 0], delta_ref[0, 0], keep, scale)
         dq_s[:] = dq_s[:] + jax.lax.dot_general(
             ds.astype(k.dtype), k,
             dimension_numbers=(((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
-    @pl.when(kr == nk - 1)
+    _by_kind(flags, kinds, _step)
+
+    @pl.when((flags & _LAST) != 0)
     def _finish():
         dq_ref[0, 0] = dq_s[:].astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(
-    q_ref, k_ref, v_ref, mask_ref, do_ref, lse_ref, delta_ref,
-    dk_ref, dv_ref, dk_s, dv_s, *, scale, causal, block_q, block_k,
-    window=None, nq_eff, nq_total, windowed_grid=False,
+    qt, kt, gt, ft, q_ref, k_ref, v_ref, *refs, scale, block_q, block_k,
+    window, key_mask, kinds,
 ):
-    """dk/dv for ONE kv head: the grid is (B, KVH, k-tiles, inner)
-    with inner = group * nq_eff — all of a kv head's query heads and
-    q-tiles accumulate consecutively into its dk/dv block (the
-    revisit pattern Pallas requires), which is what makes the
-    backward GQA-native with no repeated K/V tensor. With a window,
-    nq_eff is the exact per-k-tile live q-tile bound and the q index
-    map offsets from the k-tile's first live q-tile."""
-    ki, gq = pl.program_id(2), pl.program_id(3)
-    n_inner = pl.num_programs(3)
+    """dk/dv for ONE kv head: the grid is (B, KVH, group × live tiles)
+    and the k-major walk brings all of a kv head's query heads and
+    live q-tiles for one k-tile consecutively into its dk/dv block (the
+    revisit pattern Pallas requires), which is what makes the backward
+    GQA-native with no repeated K/V tensor."""
+    del gt
+    mask_ref, (do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_s,
+               dv_s) = _split_mask(refs, key_mask)
+    n = pl.program_id(2)
+    flags = ft[n]
 
-    @pl.when(gq == 0)
+    @pl.when((flags & _FIRST) != 0)
     def _init():
         dk_s[:] = jnp.zeros_like(dk_s)
         dv_s[:] = jnp.zeros_like(dv_s)
 
-    qr = gq % nq_eff
-    if windowed_grid:
-        qt_raw = (ki * block_k) // block_q + qr
-        qi = jnp.minimum(qt_raw, nq_total - 1)
-        run = jnp.logical_and(
-            qt_raw < nq_total,
-            _tile_live(causal, window, qi, ki, block_q, block_k),
-        )
-    else:
-        qi = qr
-        run = _tile_live(causal, window, qi, ki, block_q, block_k)
-
-    @pl.when(run)
-    def _step():
+    def _step(masked):
         q = q_ref[0, 0]
-        k = k_ref[0, 0]
-        v = v_ref[0, 0]
         do = do_ref[0, 0]
-        lse = lse_ref[0, 0]                   # [block_q, 1] column
-        delta = delta_ref[0, 0]               # [block_q, 1] column
-
-        # Native-dtype matmul operands, f32 accumulation (MXU recipe).
-        s = (
-            jax.lax.dot_general(
-                q, k,
-                dimension_numbers=(((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            * scale
-        )
-        keep = _keep_tile(
-            mask_ref, causal, qi, ki, block_q, block_k, s.shape, window
-        )
-        s = s + (1.0 - keep) * _NEG
-        p = jnp.exp(s - lse) * keep            # [block_q, block_k]
+        keep = _keep_tile(mask_ref, masked, qt[n], kt[n], block_q, block_k,
+                          (block_q, block_k), window)
+        p, ds = _recompute_ds(q, k_ref[0, 0], v_ref[0, 0], do,
+                              lse_ref[0, 0], delta_ref[0, 0], keep, scale)
         # dv += pᵀ · dO ; dk += dsᵀ · q — contractions over the q dim,
         # no explicit transpose materialised.
         dv_s[:] = dv_s[:] + jax.lax.dot_general(
@@ -796,19 +805,15 @@ def _bwd_dkv_kernel(
             dimension_numbers=(((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        dp = jax.lax.dot_general(
-            do, v,
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta) * scale
         dk_s[:] = dk_s[:] + jax.lax.dot_general(
             ds.astype(q.dtype), q,
             dimension_numbers=(((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
-    @pl.when(gq == n_inner - 1)
+    _by_kind(flags, kinds, _step)
+
+    @pl.when((flags & _LAST) != 0)
     def _finish():
         dk_ref[0, 0] = dk_s[:].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_s[:].astype(dv_ref.dtype)
@@ -819,14 +824,13 @@ def _bwd(q, k, v, mask, out, lse, g, causal, scale, block_q, block_k,
     hb = _one_tile_heads(q, k, block_q, block_k, v)
     if hb:
         return _bwd_rows(
-            q, k, v, mask, out, lse, g, g_lse, causal, scale, interpret,
-            window, hb,
+            q, k, v, _with_mask(mask, q, k), out, lse, g, g_lse, causal,
+            scale, interpret, window, hb,
         )
     b, lq, h, d = q.shape
     lk, dv = k.shape[1], v.shape[-1]
     kvh = k.shape[2]
     group = h // kvh
-    mask3 = mask.astype(jnp.float32)[:, None, :]
     qt, ot, gt = (x.transpose(0, 2, 1, 3) for x in (q, out, g))
     kt, vt = (x.transpose(0, 2, 1, 3) for x in (k, v))  # [B, KVH, L, D]
     # delta_i = Σ_d dO_i · O_i — one cheap fused elementwise+reduce in
@@ -840,116 +844,57 @@ def _bwd(q, k, v, mask, out, lse, g, causal, scale, block_q, block_k,
     if g_lse is not None:
         delta = delta - g_lse.astype(jnp.float32)
     # Row vectors ride as [B, H, L, 1] (same Mosaic tiling reason as
-    # the forward's LSE output — see _fwd's lse_spec comment).
-    lse4 = lse[..., None]
-    delta4 = delta[..., None]
-
-    nq = lq // block_q
-    nk_full = lk // block_k
-    windowed = causal and window is not None
-
-    # -- dq: q-tiles accumulate over (a shrunken set of) k-tiles ------
-    nkq = (
-        min(nk_full, _live_k_tiles(block_q, block_k, window))
-        if windowed
-        else nk_full
+    # the forward's LSE output — see the comment in _fwd).
+    rows = (lse[..., None], delta[..., None])
+    masks = () if mask is None else (mask[:, None, :],)
+    tiles = _tile_schedule(lq, lk, block_q, block_k, causal, window)
+    static = dict(
+        interpret=interpret, scale=scale, block_q=block_q, block_k=block_k,
+        window=window, key_mask=mask is not None, kinds=_tile_kinds(tiles),
     )
-    dq_windowed = nkq < nk_full
 
-    def _kb(qi, kr):
-        if dq_windowed:
-            return jnp.maximum(_window_k_tile(qi, kr, block_q, block_k, nkq), 0)
-        return kr
+    # -- dq: the forward's q-major walk -------------------------------
+    def q_map(bi, hi, n, qi, ki, g, f):
+        return (bi, hi, qi[n], 0)
 
-    q_spec = pl.BlockSpec(
-        (1, 1, block_q, d), lambda bi, hi, qi, kr: (bi, hi, qi, 0)
-    )
+    def kv_map(bi, hi, n, qi, ki, g, f):
+        return (bi, hi // group, ki[n], 0)
+
+    def mask_map(bi, hi, n, qi, ki, g, f):
+        return (bi, 0, ki[n])
+
     # q, k and dq are ``d`` wide; v, dO and dv ``dv`` wide.
-    do_spec = pl.BlockSpec(
-        (1, 1, block_q, dv), lambda bi, hi, qi, kr: (bi, hi, qi, 0)
-    )
-    k_spec, v_spec = (
-        pl.BlockSpec(
-            (1, 1, block_k, w),
-            lambda bi, hi, qi, kr: (bi, hi // group, _kb(qi, kr), 0),
-        )
-        for w in (d, dv)
-    )
-    mask_spec = pl.BlockSpec(
-        (1, 1, block_k), lambda bi, hi, qi, kr: (bi, 0, _kb(qi, kr))
-    )
-    row_spec = pl.BlockSpec(
-        (1, 1, block_q, 1), lambda bi, hi, qi, kr: (bi, hi, qi, 0)
-    )
-
-    dq = pl.pallas_call(
-        functools.partial(
-            _bwd_dq_kernel, scale=scale, causal=causal,
-            block_q=block_q, block_k=block_k, window=window,
-            windowed_grid=dq_windowed,
-        ),
-        grid=(b, h, nq, nkq),
-        in_specs=[q_spec, k_spec, v_spec, mask_spec, do_spec, row_spec,
-                  row_spec],
+    q_spec = pl.BlockSpec((1, 1, block_q, d), q_map)
+    row_spec = pl.BlockSpec((1, 1, block_q, 1), q_map)
+    dq = _stream_call(
+        _bwd_dq_kernel, _walk(tiles, 0), (b, h, len(tiles)),
+        [q_spec, pl.BlockSpec((1, 1, block_k, d), kv_map),
+         pl.BlockSpec((1, 1, block_k, dv), kv_map)]
+        + [pl.BlockSpec((1, 1, block_k), mask_map)] * len(masks)
+        + [pl.BlockSpec((1, 1, block_q, dv), q_map), row_spec, row_spec],
         out_specs=q_spec,
         out_shape=_out_struct(qt.shape, q.dtype, q),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        interpret=interpret,
-    )(qt, kt, vt, mask3, gt, lse4, delta4)
+        **static,
+    )(qt, kt, vt, *masks, gt, *rows)
 
-    # -- dk/dv: GQA-native grid (B, KVH, k-tiles, group * q-tiles) ----
-    # Every (query head, q-tile) of one kv head accumulates
-    # CONSECUTIVELY into its dk/dv block — the revisit pattern Pallas
-    # requires — so no repeated K/V tensor is needed. With a window,
-    # the inner q-range shrinks to the exact per-alignment bound of
-    # live q-tiles, offset from each k-tile's first.
-    nq_eff = (
-        min(nq, _live_q_tiles(block_q, block_k, window))
-        if windowed
-        else nq
-    )
-    dkv_windowed = nq_eff < nq
+    # -- dk/dv: the k-major walk over each kv head's group ------------
+    def hq_map(bi, kvi, n, qi, ki, g, f):
+        return (bi, kvi * group + g[n], qi[n], 0)
 
-    def _hq(kvi, gq):
-        return kvi * group + gq // nq_eff
+    def k_map(bi, kvi, n, qi, ki, g, f):
+        return (bi, kvi, ki[n], 0)
 
-    def _qt(ki, gq):
-        if dkv_windowed:
-            return jnp.minimum(
-                (ki * block_k) // block_q + gq % nq_eff, nq - 1
-            )
-        return gq % nq_eff
-
-    q_spec_T, do_spec_T = (
-        pl.BlockSpec(
-            (1, 1, block_q, w),
-            lambda bi, kvi, ki, gq: (bi, _hq(kvi, gq), _qt(ki, gq), 0),
-        )
-        for w in (d, dv)
-    )
-    k_spec_T, v_spec_T = (
-        pl.BlockSpec(
-            (1, 1, block_k, w), lambda bi, kvi, ki, gq: (bi, kvi, ki, 0)
-        )
-        for w in (d, dv)
-    )
-    mask_spec_T = pl.BlockSpec(
-        (1, 1, block_k), lambda bi, kvi, ki, gq: (bi, 0, ki)
-    )
-    row_spec_T = pl.BlockSpec(
-        (1, 1, block_q, 1),
-        lambda bi, kvi, ki, gq: (bi, _hq(kvi, gq), _qt(ki, gq), 0),
-    )
-    dk, dv = pl.pallas_call(
-        functools.partial(
-            _bwd_dkv_kernel, scale=scale, causal=causal,
-            block_q=block_q, block_k=block_k, window=window,
-            nq_eff=nq_eff, nq_total=nq, windowed_grid=dkv_windowed,
-        ),
-        grid=(b, kvh, nk_full, group * nq_eff),
-        in_specs=[q_spec_T, k_spec_T, v_spec_T, mask_spec_T, do_spec_T,
-                  row_spec_T, row_spec_T],
-        out_specs=[k_spec_T, v_spec_T],
+    k_spec, v_spec = (pl.BlockSpec((1, 1, block_k, w), k_map) for w in (d, dv))
+    row_spec_t = pl.BlockSpec((1, 1, block_q, 1), hq_map)
+    tables = _walk(tiles, group)
+    dk, dv = _stream_call(
+        _bwd_dkv_kernel, tables, (b, kvh, len(tables[0])),
+        [pl.BlockSpec((1, 1, block_q, d), hq_map), k_spec, v_spec]
+        + [pl.BlockSpec((1, 1, block_k), mask_map)] * len(masks)
+        + [pl.BlockSpec((1, 1, block_q, dv), hq_map), row_spec_t,
+           row_spec_t],
+        out_specs=[k_spec, v_spec],
         out_shape=[
             _out_struct(kt.shape, k.dtype, q),
             _out_struct(vt.shape, v.dtype, q),
@@ -958,8 +903,8 @@ def _bwd(q, k, v, mask, out, lse, g, causal, scale, block_q, block_k,
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, dv), jnp.float32),
         ],
-        interpret=interpret,
-    )(qt, kt, vt, mask3, gt, lse4, delta4)
+        **static,
+    )(qt, kt, vt, *masks, gt, *rows)
 
     return (
         dq.transpose(0, 2, 1, 3),
@@ -1006,7 +951,7 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, window, res, g):
         q, k, v, mask, out, lse, g_o, causal, scale, block_q, block_k,
         interpret, g_lse=g_lse, window=window,
     )
-    return dq, dk, dv, jnp.zeros_like(mask)
+    return dq, dk, dv, None if mask is None else jnp.zeros_like(mask)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -1019,17 +964,24 @@ def _counted_flash(q, k, v, mask, causal, scale, block_q, block_k,
     (``flash.calls_traced``; ``flash.calls_row_blocked`` when the
     one-tile kernels take the call; ``flash.calls_windowed`` when it
     carries a window; ``flash.calls_gqa`` when K/V heads are fewer than
-    query heads). Nothing is counted per step."""
+    query heads; when the call streams, ``flash.tiles_live`` and
+    ``flash.tiles_masked`` by its tile schedule's sizes, a head's).
+    Nothing is counted per step."""
     REGISTRY.counter("flash.calls_traced").inc()
     if _one_tile_heads(q, k, block_q, block_k, v):
         REGISTRY.counter("flash.calls_row_blocked").inc()
+    else:
+        tiles = _tile_schedule(q.shape[1], k.shape[1], block_q, block_k,
+                               causal, window)
+        REGISTRY.counter("flash.tiles_live").inc(len(tiles))
+        REGISTRY.counter("flash.tiles_masked").inc(
+            sum(m for *_, m in tiles))
     if window is not None:
         REGISTRY.counter("flash.calls_windowed").inc()
     if k.shape[2] != q.shape[2]:
         REGISTRY.counter("flash.calls_gqa").inc()
     return _flash(
-        q, k, v, mask.astype(jnp.float32), causal, scale, block_q, block_k,
-        interpret, window,
+        q, k, v, mask, causal, scale, block_q, block_k, interpret, window,
     )
 
 
@@ -1043,8 +995,10 @@ def _fit_block(requested: int, length: int) -> int:
 def _prepare(q, k, v, mask, causal, scale, block_q, block_k,
              window=None):
     """Shared wrapper preamble: validation, scale default, block
-    clamping, default mask. Returns (mask, scale, block_q, block_k)."""
-    b, lq, h, d = q.shape
+    clamping, the key mask as float32 (None stays None: the streaming
+    kernels then carry no mask operand). Returns (mask, scale, block_q,
+    block_k)."""
+    _, lq, h, d = q.shape
     lk = k.shape[1]
     if causal and lq != lk:
         raise ValueError(
@@ -1077,8 +1031,8 @@ def _prepare(q, k, v, mask, causal, scale, block_q, block_k,
     # degrade to the nearest dividing halving rather than erroring.
     block_q = _fit_block(block_q, lq)
     block_k = _fit_block(block_k, lk)
-    if mask is None:
-        mask = jnp.ones((b, lk), jnp.float32)
+    if mask is not None:
+        mask = mask.astype(jnp.float32)
     return mask, scale, block_q, block_k
 
 

@@ -367,35 +367,57 @@ def test_randomized_differential_sweep():
         )
 
 
-def test_window_grid_covers_every_live_tile():
-    """White-box: the shrunken k-grid's physical tiles must cover
-    every tile containing an attendable key, for all q-tiles and a
-    sweep of (window, block) combinations."""
-    from mlapi_tpu.ops.pallas.flash_attention import _window_k_tile
+# (block_q, block_k, window, L, causal): the six windowed cases the
+# shrunken grids were checked on, then causal alone and not causal.
+SCHEDULES = [
+    (16, 16, 8, 64, True), (16, 16, 16, 64, True), (32, 16, 24, 128, True),
+    (16, 32, 40, 128, True), (32, 32, 32, 256, True), (16, 16, 50, 128, True),
+    (32, 16, None, 128, True), (16, 32, None, 64, False),
+]
 
-    for bq, bk, window, l in [
-        (16, 16, 8, 64), (16, 16, 16, 64), (32, 16, 24, 128),
-        (16, 32, 40, 128), (32, 32, 32, 256), (16, 16, 50, 128),
-    ]:
-        from mlapi_tpu.ops.pallas.flash_attention import _live_k_tiles
 
-        nk_full = l // bk
-        nkw = min(nk_full, _live_k_tiles(bq, bk, window))
-        for qi in range(l // bq):
-            visited = {
-                max(0, int(_window_k_tile(qi, ki, bq, bk, nkw)))
-                for ki in range(nkw)
-                if int(_window_k_tile(qi, ki, bq, bk, nkw)) >= 0
-            }
-            # Tiles that contain at least one key some query attends:
-            need = set()
-            for qp in range(qi * bq, (qi + 1) * bq):
-                lo, hi = max(0, qp - window + 1), qp
-                need |= {t for t in range(lo // bk, hi // bk + 1)}
-            assert need <= visited, (
-                f"bq={bq} bk={bk} window={window} qi={qi}: "
-                f"missing tiles {sorted(need - visited)}"
-            )
+@pytest.mark.parametrize("order", ["forward", "dq", "dkv"])
+@pytest.mark.parametrize("bq,bk,window,l,causal", SCHEDULES)
+def test_tile_schedule_walks_every_kept_pair_once(bq, bk, window, l, causal,
+                                                  order):
+    """White-box: the walk a kernel's last grid dimension takes. Every
+    kept (q, k) pair lies in exactly one step's tile (a query head's,
+    for dk/dv's walk over a group of 3); no step's tile is dead; a tile
+    not flagged masked keeps every pair; the steps that revisit one
+    output block are consecutive, open and close where the flags say,
+    and come in the order the rectangular grids had (q-major for the
+    forward and dq, k-major over the group's heads for dk/dv)."""
+    from mlapi_tpu.ops.pallas.flash_attention import (
+        _FIRST, _LAST, _MASKED, _tile_schedule, _walk,
+    )
+
+    group = 3 if order == "dkv" else 0
+    qi, ki, g, flags = _walk(
+        _tile_schedule(l, l, bq, bk, causal, window), group)
+    dist = np.arange(l)[:, None] - np.arange(l)[None, :]
+    kept = (dist >= 0) & (dist < (window or l)) if causal else dist == dist
+    seen = np.zeros((max(group, 1), l, l), np.int32)
+    for t in range(len(qi)):
+        rows = slice(qi[t] * bq, (qi[t] + 1) * bq)
+        cols = slice(ki[t] * bk, (ki[t] + 1) * bk)
+        tile = kept[rows, cols]
+        assert tile.any(), f"step {t}: dead tile ({qi[t]}, {ki[t]})"
+        assert bool(flags[t] & _MASKED) == (not tile.all()), f"step {t}"
+        seen[g[t], rows, cols] += tile
+    np.testing.assert_array_equal(seen, np.broadcast_to(kept, seen.shape))
+    # The order, and the runs that revisit one output block.
+    steps = list(zip(qi, ki, g))
+    key = (lambda s: (s[1], s[2], s[0])) if group else (lambda s: s)
+    assert steps == sorted(steps, key=key)
+    run = ki if group else qi
+    edges = np.flatnonzero(np.diff(run)) + 1
+    assert len(set(run)) == len(edges) + 1  # each run is one block of steps
+    first = np.zeros(len(run), bool)
+    first[np.r_[0, edges]] = True
+    last = np.zeros(len(run), bool)
+    last[np.r_[edges - 1, len(run) - 1]] = True
+    np.testing.assert_array_equal((flags & _FIRST) != 0, first)
+    np.testing.assert_array_equal((flags & _LAST) != 0, last)
 
 
 def test_window_with_mismatched_blocks_matches_reference():
@@ -606,6 +628,123 @@ def test_multi_tile_sequences_keep_the_streaming_kernels():
         _oracle(q, k, v, mask, causal=True) * w), argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(got, want):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
+
+
+# name: (H, KVH, causal, window) at 1 x 64 positions in tiles of 16:
+# four q-tiles, with full, masked and dead tiles where causal.
+STREAMED = {
+    "causal": (2, 2, True, None),
+    "window": (2, 2, True, 40),
+    "gqa-6-over-1": (6, 1, True, None),
+    "not-causal": (2, 2, False, None),
+}
+
+
+def _streamed_grads(q, k, v, mask, causal, window):
+    """(out, lse) and the gradient of a sum over both in q, k, v, of
+    the streaming kernels at tiles of 16."""
+    from mlapi_tpu.ops.pallas import flash_attention_with_lse
+
+    def run(q, k, v):
+        return flash_attention_with_lse(
+            q, k, v, mask, causal=causal, window=window, block_q=16,
+            block_k=16, interpret=True)
+
+    def loss(q, k, v):
+        out, lse = run(q, k, v)
+        return jnp.sum(out * jnp.cos(out)) + jnp.sum(jnp.sin(lse))
+
+    return (*run(q, k, v), *jax.grad(loss, argnums=(0, 1, 2))(q, k, v))
+
+
+@pytest.mark.parametrize("name", sorted(STREAMED))
+def test_no_mask_is_an_all_ones_mask(name):
+    """``mask=None`` drops the mask operand and, on full tiles, every
+    mask multiply; an all-ones mask keeps them. Where every factor is
+    1 each of those is an exact identity, so out, lse, dq, dk and dv
+    agree to float32 rounding, and both with ``_jnp_flash``. (To the
+    last bit where the CPU code has no fused multiply-add,
+    ``XLA_FLAGS=--xla_cpu_max_isa=SSE4_2``; with FMAs XLA contracts
+    the two programs' products apart by an ulp here and there.)"""
+    from mlapi_tpu.ops.pallas.flash_attention import _jnp_flash
+
+    h, kvh, causal, window = STREAMED[name]
+    ks = jax.random.split(jax.random.key(43), 3)
+    q = jax.random.normal(ks[0], (1, 64, h, 8))
+    k, v = (jax.random.normal(x, (1, 64, kvh, 8)) for x in ks[1:])
+    bare = _streamed_grads(q, k, v, None, causal, window)
+    ones = _streamed_grads(q, k, v, jnp.ones((1, 64)), causal, window)
+    for a, b in zip(bare, ones):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6,
+                                   atol=1e-6)
+
+    def oracle(q, k, v):
+        return _jnp_flash(q, k, v, None, causal, 8 ** -0.5, window)
+
+    def loss(q, k, v):
+        out, lse = oracle(q, k, v)
+        return jnp.sum(out * jnp.cos(out)) + jnp.sum(jnp.sin(lse))
+
+    want = (*oracle(q, k, v), *jax.grad(loss, argnums=(0, 1, 2))(q, k, v))
+    for a, b in zip(bare, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_padding_mask_zeroes_keys_in_full_tiles(causal):
+    """A key mask still counts on tiles the schedule calls full: keys
+    16-31 (all of k-tile 1, which the last two q-tiles see whole) get
+    no weight and no gradient, and the rest agrees with the oracle."""
+    ks = jax.random.split(jax.random.key(44), 3)
+    q, k, v = (jax.random.normal(x, (1, 64, 2, 8)) for x in ks)
+    mask = jnp.asarray((np.arange(64) // 16 != 1)[None], jnp.float32)
+    w = jax.random.normal(jax.random.key(45), q.shape)
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v) * w)
+
+    def kern(q, k, v):
+        return flash_attention(q, k, v, mask, causal=causal, block_q=16,
+                               block_k=16, interpret=True)
+
+    def ref(q, k, v):
+        return _oracle(q, k, v, mask, causal=causal)
+
+    np.testing.assert_allclose(np.asarray(kern(q, k, v)),
+                               np.asarray(ref(q, k, v)), atol=2e-5)
+    got = jax.grad(loss(kern), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
+    for dkv in got[1:]:
+        assert np.abs(np.asarray(dkv)[:, 16:32]).max() == 0.0
+
+
+def _tile_counts():
+    from mlapi_tpu.utils.metrics import REGISTRY
+
+    c = REGISTRY.snapshot()["counters"]
+    return (c.get("flash.tiles_live", 0), c.get("flash.tiles_masked", 0))
+
+
+def test_tile_counters_rise_once_per_traced_streaming_call():
+    """``flash.tiles_live`` / ``flash.tiles_masked`` rise by a head's
+    schedule once a TRACE of a streaming call: 1 x 96 causal in tiles
+    of 16 is 21 live tiles, the 6 on the diagonal masked; under a
+    window of 16, 11, all masked; a one-tile call adds nothing."""
+    q = jax.random.normal(jax.random.key(46), (1, 96, 2, 8))  # shapes of its own
+    blocks = dict(block_q=16, block_k=16, interpret=True)
+    live, masked = _tile_counts()
+    flash_attention(q, q, q, causal=True, **blocks)
+    assert _tile_counts() == (live + 21, masked + 6)
+    flash_attention(q, q, q, causal=True, **blocks)
+    assert _tile_counts() == (live + 21, masked + 6)
+    from mlapi_tpu.ops.pallas import flash_attention_with_lse
+
+    flash_attention_with_lse(q, q, q, causal=True, window=16, **blocks)
+    assert _tile_counts() == (live + 32, masked + 17)
+    flash_attention(q[:, :24], q[:, :24], q[:, :24], interpret=True)
+    assert _tile_counts() == (live + 32, masked + 17)
 
 
 @pytest.mark.parametrize("wrap", ["no_checkpoint", "bare_checkpoint"])
