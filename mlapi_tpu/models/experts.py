@@ -154,7 +154,8 @@ grouped_ffn.defvjp(_grouped_fwd, _grouped_bwd)
 def moe(p, x, *, k: int, held: tuple, tile: int, scale: float,
         compute_dtype):
     """The held experts' part plus the shared expert of ``x [B, L, H]``,
-    and the layer's ``(pairs here, fullest held expert's pairs)``.
+    and the layer's ``(pairs here, fullest held expert's pairs, tiles
+    the grouped loop runs)``.
     ``p``: ``router [H, E]``, ``experts`` (``gate``, ``up`` ``[n, H,
     I]``, ``down [n, I, H]`` of the ``held = (first, n)`` experts),
     ``shared`` (a SwiGLU), and ``router_bias [E]`` where the family
@@ -183,26 +184,33 @@ def moe(p, x, *, k: int, held: tuple, tile: int, scale: float,
             tile_expert, n_tiles, tile, k)
     with jax.named_scope("moe.shared"):
         y = y.reshape(b, l, hid) + ffn(p["shared"], x, cdt)
-    return y, (jnp.sum(counts), jnp.max(counts))
+    return y, (jnp.sum(counts), jnp.max(counts), n_tiles)
 
 
-def load_stats(loads, count: int, routed: int) -> dict:
+def load_stats(loads, count: int, routed: int, tile: int) -> dict:
     """A step's expert load as device scalars, from every layer's
-    ``(pairs here, fullest held expert's pairs)`` (a dense layer's is
-    ``(0, 0)``): ``moe.pairs_routed`` (``routed``: tokens x experts a
-    token x expert layers), ``moe.pairs_here`` (those whose expert is
-    held here), ``moe.expert_load_max`` (the fullest held expert's
-    pairs in any layer) and ``moe.load_max_over_mean`` (the least even
-    layer's fullest held expert over its mean held expert: 1 even, at
-    most ``count``, the number held)."""
-    here = fullest = jnp.zeros((), jnp.int32)
+    ``(pairs here, fullest held expert's pairs, tiles run)`` (a dense
+    layer's is ``(0, 0, 0)``): ``moe.pairs_routed`` (``routed``: tokens
+    x experts a token x expert layers), ``moe.pairs_here`` (those whose
+    expert is held here), ``moe.expert_load_max`` (the fullest held
+    expert's pairs in any layer), ``moe.load_max_over_mean`` (the least
+    even layer's fullest held expert over its mean held expert: 1 even,
+    at most ``count``, the number held), ``moe.tiles_run`` (the trips of
+    the grouped loops, one forward's: the recomputation and the
+    backward run the same) and ``moe.rows_run`` (``tiles_run x tile``:
+    the rows their products compute, ``pairs_here`` of them a pair and
+    the rest padding paid for in full)."""
+    here = fullest = tiles = jnp.zeros((), jnp.int32)
     uneven = jnp.zeros((), jnp.float32)
-    for pairs, top in loads:
+    for pairs, top, trips in loads:
         here, fullest = here + pairs, jnp.maximum(fullest, top)
         uneven = jnp.maximum(uneven, top * count / jnp.maximum(pairs, 1))
+        tiles = tiles + trips
     return {
         "moe.pairs_routed": jnp.asarray(routed, jnp.int32),
         "moe.pairs_here": here.astype(jnp.int32),
         "moe.expert_load_max": fullest.astype(jnp.int32),
         "moe.load_max_over_mean": uneven.astype(jnp.float32),
+        "moe.tiles_run": tiles.astype(jnp.int32),
+        "moe.rows_run": (tiles * tile).astype(jnp.int32),
     }

@@ -54,9 +54,10 @@ by ``routed_scaling_factor``, plus the shared expert; ``experts_held =
 Training only: there is no ``prefill_core`` / ``decode_step`` /
 ``init_cache`` for a recurrent state and a latent cache (ROADMAP A3,
 A4); the serving CLI refuses such a checkpoint (``serving_refusal``).
-``apply_with_stats`` hands ``make_train_step`` four device scalars a
+``apply_with_stats`` hands ``make_train_step`` six device scalars a
 step beside the logits (``moe.pairs_routed``, ``moe.pairs_here``,
-``moe.expert_load_max``, ``moe.load_max_over_mean``).
+``moe.expert_load_max``, ``moe.load_max_over_mean``, ``moe.tiles_run``,
+``moe.rows_run``).
 """
 
 from __future__ import annotations
@@ -502,7 +503,8 @@ class KimiLinearLM:
 
     def _moe(self, p, x):
         """The held experts' part plus the shared expert, and the
-        layer's ``(pairs here, fullest held expert's pairs)``."""
+        layer's ``(pairs here, fullest held expert's pairs, tiles
+        run)``."""
         return experts.moe(
             p, x, k=self.num_experts_per_token, held=self.held,
             tile=self.moe_tile, scale=self.routed_scaling_factor,
@@ -518,7 +520,7 @@ class KimiLinearLM:
         xn = _rms_norm(x, layer["ffn_norm"], self.rms_norm_eps)
         zero = jnp.zeros((), jnp.int32)
         if kind == "dense":
-            return x + self._ffn(layer["mlp"], xn), (zero, zero)
+            return x + self._ffn(layer["mlp"], xn), (zero, zero, zero)
         y, load = self._moe(layer["moe"], xn)
         return x + y, load
 
@@ -526,7 +528,8 @@ class KimiLinearLM:
         """``[B, L]`` ids -> ``[B, L, V]`` float32 logits, and the
         step's expert load as device scalars (``experts.load_stats``:
         ``moe.pairs_routed``, ``moe.pairs_here``,
-        ``moe.expert_load_max``, ``moe.load_max_over_mean``)."""
+        ``moe.expert_load_max``, ``moe.load_max_over_mean``,
+        ``moe.tiles_run``, ``moe.rows_run``)."""
         cdt = jnp.dtype(self.compute_dtype)
         x = params["embed"][token_ids].astype(jnp.float32)
         loads = []
@@ -546,7 +549,8 @@ class KimiLinearLM:
                 params["lm_head"], cdt)
         moe_layers = sum(kind == "moe" for _, kind in self.layer_kinds)
         routed = token_ids.size * self.num_experts_per_token * moe_layers
-        return logits, experts.load_stats(loads, self.held[1], routed)
+        return logits, experts.load_stats(loads, self.held[1], routed,
+                                          self.moe_tile)
 
     def apply(self, params: dict, token_ids) -> jax.Array:
         return self.apply_with_stats(params, token_ids)[0]

@@ -38,7 +38,7 @@ count)`` the chip's share of an expert-parallel layer.
 Training only: the serving cache and decode kernels have no window and
 one head count for every layer; the serving CLI refuses such a
 checkpoint (``serving_refusal``). ``apply_with_stats`` hands
-``make_train_step`` the four ``moe.*`` device scalars a step that
+``make_train_step`` the six ``moe.*`` device scalars a step that
 ``kimi_linear_lm`` does.
 """
 
@@ -233,7 +233,8 @@ class LagunaLM:
 
     def _moe(self, p, x):
         """The held experts' part plus the shared expert, and the
-        layer's ``(pairs here, fullest held expert's pairs)``."""
+        layer's ``(pairs here, fullest held expert's pairs, tiles
+        run)``."""
         return experts.moe(
             p, x, k=self.num_experts_per_tok, held=self.held,
             tile=self.moe_tile, scale=self.moe_routed_scaling_factor,
@@ -247,7 +248,7 @@ class LagunaLM:
         xn = _rms_norm(x, layer["ffn_norm"], self.rms_norm_eps)
         zero = jnp.zeros((), jnp.int32)
         if self.mlp_layer_types[n] == "dense":
-            return x + self._ffn(layer["mlp"], xn), (zero, zero)
+            return x + self._ffn(layer["mlp"], xn), (zero, zero, zero)
         y, load = self._moe(layer["moe"], xn)
         return x + y, load
 
@@ -255,7 +256,8 @@ class LagunaLM:
         """``[B, L]`` ids -> ``[B, L, V]`` float32 logits, and the
         step's expert load as ``kimi_linear_lm`` reports it:
         ``moe.pairs_routed``, ``moe.pairs_here``,
-        ``moe.expert_load_max``, ``moe.load_max_over_mean``."""
+        ``moe.expert_load_max``, ``moe.load_max_over_mean``,
+        ``moe.tiles_run``, ``moe.rows_run``."""
         cdt = jnp.dtype(self.compute_dtype)
         x = params["embed"][token_ids].astype(jnp.float32)
         loads = []
@@ -274,7 +276,8 @@ class LagunaLM:
         sparse = sum(kind == "sparse"
                      for kind in self.mlp_layer_types[:self.num_layers])
         routed = token_ids.size * self.num_experts_per_tok * sparse
-        return logits, experts.load_stats(loads, self.held[1], routed)
+        return logits, experts.load_stats(loads, self.held[1], routed,
+                                          self.moe_tile)
 
     def apply(self, params: dict, token_ids) -> jax.Array:
         return self.apply_with_stats(params, token_ids)[0]

@@ -623,11 +623,14 @@ def _with_mask(mask, q, k):
     return mask
 
 
-def _stream_call(kernel, tables, grid, in_specs, out_specs, out_shape,
+def _stream_call(kernel, name, tables, grid, in_specs, out_specs, out_shape,
                  scratch_shapes, interpret, **static):
     """The ``pallas_call`` of one streaming kernel, as a function of the
     kernel's own operands: its grid's last dimension walks ``tables``
-    (``_walk``), which ride ahead of them as scalar prefetch."""
+    (``_walk``), which ride ahead of them as scalar prefetch. ``name``
+    is what the chip's trace calls the kernel (``flash_attention_fwd``,
+    ``_dq``, ``_dkv``: one pass each, all under the ``flash_attention``
+    prefix)."""
     call = pl.pallas_call(
         functools.partial(kernel, **static),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -639,6 +642,7 @@ def _stream_call(kernel, tables, grid, in_specs, out_specs, out_shape,
         ),
         out_shape=out_shape,
         interpret=interpret,
+        name=name,
     )
     return lambda *operands: call(*tables, *operands)
 
@@ -683,7 +687,8 @@ def _fwd(q, k, v, mask, causal, scale, block_q, block_k, interpret,
     # Scores run over the query/key width ``d``, values and the output
     # over ``dv`` (the same unless the value heads are narrower).
     out, lse = _stream_call(
-        _fwd_kernel, _walk(tiles, 0), (b, h, len(tiles)),
+        _fwd_kernel, "flash_attention_fwd", _walk(tiles, 0),
+        (b, h, len(tiles)),
         [pl.BlockSpec((1, 1, block_q, d), q_map),
          pl.BlockSpec((1, 1, block_k, d), kv_map),
          pl.BlockSpec((1, 1, block_k, dv), kv_map)]
@@ -867,7 +872,8 @@ def _bwd(q, k, v, mask, out, lse, g, causal, scale, block_q, block_k,
     q_spec = pl.BlockSpec((1, 1, block_q, d), q_map)
     row_spec = pl.BlockSpec((1, 1, block_q, 1), q_map)
     dq = _stream_call(
-        _bwd_dq_kernel, _walk(tiles, 0), (b, h, len(tiles)),
+        _bwd_dq_kernel, "flash_attention_dq", _walk(tiles, 0),
+        (b, h, len(tiles)),
         [q_spec, pl.BlockSpec((1, 1, block_k, d), kv_map),
          pl.BlockSpec((1, 1, block_k, dv), kv_map)]
         + [pl.BlockSpec((1, 1, block_k), mask_map)] * len(masks)
@@ -889,7 +895,8 @@ def _bwd(q, k, v, mask, out, lse, g, causal, scale, block_q, block_k,
     row_spec_t = pl.BlockSpec((1, 1, block_q, 1), hq_map)
     tables = _walk(tiles, group)
     dk, dv = _stream_call(
-        _bwd_dkv_kernel, tables, (b, kvh, len(tables[0])),
+        _bwd_dkv_kernel, "flash_attention_dkv", tables,
+        (b, kvh, len(tables[0])),
         [pl.BlockSpec((1, 1, block_q, d), hq_map), k_spec, v_spec]
         + [pl.BlockSpec((1, 1, block_k), mask_map)] * len(masks)
         + [pl.BlockSpec((1, 1, block_q, dv), hq_map), row_spec_t,
@@ -964,18 +971,10 @@ def _counted_flash(q, k, v, mask, causal, scale, block_q, block_k,
     (``flash.calls_traced``; ``flash.calls_row_blocked`` when the
     one-tile kernels take the call; ``flash.calls_windowed`` when it
     carries a window; ``flash.calls_gqa`` when K/V heads are fewer than
-    query heads; when the call streams, ``flash.tiles_live`` and
-    ``flash.tiles_masked`` by its tile schedule's sizes, a head's).
-    Nothing is counted per step."""
+    query heads). Nothing is counted per step."""
     REGISTRY.counter("flash.calls_traced").inc()
     if _one_tile_heads(q, k, block_q, block_k, v):
         REGISTRY.counter("flash.calls_row_blocked").inc()
-    else:
-        tiles = _tile_schedule(q.shape[1], k.shape[1], block_q, block_k,
-                               causal, window)
-        REGISTRY.counter("flash.tiles_live").inc(len(tiles))
-        REGISTRY.counter("flash.tiles_masked").inc(
-            sum(m for *_, m in tiles))
     if window is not None:
         REGISTRY.counter("flash.calls_windowed").inc()
     if k.shape[2] != q.shape[2]:

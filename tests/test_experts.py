@@ -101,9 +101,10 @@ def test_selection_bias_is_optional_and_never_a_weight(with_bias):
         p["router_bias"] = jnp.zeros((16,)).at[4:8].set(5.0)
     x = jax.random.normal(rng[4], (2, 24, 32))
     kw = dict(k=4, held=(4, 4), tile=8, scale=2.5, compute_dtype="float32")
-    y, (pairs, fullest) = experts.moe(p, x, **kw)
+    y, (pairs, fullest, tiles) = experts.moe(p, x, **kw)
     if with_bias:
         assert int(pairs) == 2 * 24 * 4 and int(fullest) == 2 * 24
+        assert int(tiles) == 4 * 2 * 24 // 8
         g = jax.grad(lambda p: jnp.sum(experts.moe(p, x, **kw)[0] ** 2))(p)
         assert float(jnp.max(jnp.abs(g["router_bias"]))) == 0.0
         assert float(jnp.max(jnp.abs(g["router"]))) > 0.0
@@ -114,9 +115,35 @@ def test_selection_bias_is_optional_and_never_a_weight(with_bias):
 
 def test_load_stats_names_and_bounds():
     stats = experts.load_stats(
-        [(jnp.int32(0), jnp.int32(0)), (jnp.int32(40), jnp.int32(25)),
-         (jnp.int32(64), jnp.int32(16))], 4, 400)
+        [(jnp.int32(0), jnp.int32(0), jnp.int32(0)),
+         (jnp.int32(40), jnp.int32(25), jnp.int32(7)),
+         (jnp.int32(64), jnp.int32(16), jnp.int32(8))], 4, 400, 8)
     got = {k: float(v) for k, v in stats.items()}
     assert got == {"moe.pairs_routed": 400.0, "moe.pairs_here": 104.0,
                    "moe.expert_load_max": 25.0,
-                   "moe.load_max_over_mean": 2.5}
+                   "moe.load_max_over_mean": 2.5, "moe.tiles_run": 15.0,
+                   "moe.rows_run": 120.0}
+
+
+@pytest.mark.parametrize("per_expert,tile,tiles", [
+    ([257, 0, 5], 256, 3),   # 257 pairs: two tiles; an empty expert: none
+    ([0, 0, 0], 256, 0),
+    ([256, 1, 0], 256, 2),
+    ([8, 9, 16], 8, 5),
+])
+def test_tiles_run_is_the_plans_closed_form(per_expert, tile, tiles):
+    """``moe.tiles_run`` of a layer planned from hand-built ``idx`` is
+    the sum over held experts of ``ceil(pairs / tile)`` and
+    ``moe.rows_run`` that times ``tile``; pairs routed to experts not
+    held (ids 3 and up here) plan nothing."""
+    flat = np.concatenate([np.full(n, e) for e, n in enumerate(per_expert)]
+                          + [np.full(11, 3), np.full(6, 7)])
+    idx = jnp.asarray(np.random.default_rng(tile).permutation(
+        np.pad(flat, (0, -len(flat) % 4), constant_values=5)).reshape(-1, 4))
+    _, _, n_tiles, counts = jax.jit(
+        lambda i: experts.plan(i, 0, 3, tile))(idx)
+    assert list(np.asarray(counts)) == per_expert
+    stats = experts.load_stats(
+        [(jnp.sum(counts), jnp.max(counts), n_tiles)], 3, idx.size, tile)
+    assert int(stats["moe.tiles_run"]) == tiles
+    assert int(stats["moe.rows_run"]) == tiles * tile

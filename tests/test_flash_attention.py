@@ -720,31 +720,53 @@ def test_padding_mask_zeroes_keys_in_full_tiles(causal):
         assert np.abs(np.asarray(dkv)[:, 16:32]).max() == 0.0
 
 
-def _tile_counts():
-    from mlapi_tpu.utils.metrics import REGISTRY
-
-    c = REGISTRY.snapshot()["counters"]
-    return (c.get("flash.tiles_live", 0), c.get("flash.tiles_masked", 0))
-
-
 def test_tile_counters_rise_once_per_traced_streaming_call():
-    """``flash.tiles_live`` / ``flash.tiles_masked`` rise by a head's
-    schedule once a TRACE of a streaming call: 1 x 96 causal in tiles
-    of 16 is 21 live tiles, the 6 on the diagonal masked; under a
-    window of 16, 11, all masked; a one-tile call adds nothing."""
-    q = jax.random.normal(jax.random.key(46), (1, 96, 2, 8))  # shapes of its own
-    blocks = dict(block_q=16, block_k=16, interpret=True)
-    live, masked = _tile_counts()
-    flash_attention(q, q, q, causal=True, **blocks)
-    assert _tile_counts() == (live + 21, masked + 6)
-    flash_attention(q, q, q, causal=True, **blocks)
-    assert _tile_counts() == (live + 21, masked + 6)
-    from mlapi_tpu.ops.pallas import flash_attention_with_lse
+    """The sizes of a head's tile schedule, which the kernels' names and
+    ``_tile_schedule`` now give in place of a counter: 1 x 96 causal in
+    tiles of 16 is 21 live tiles, the 6 on the diagonal masked; under a
+    window of 16, 11, all masked; not causal, all 36 full."""
+    from mlapi_tpu.ops.pallas.flash_attention import _tile_schedule
 
-    flash_attention_with_lse(q, q, q, causal=True, window=16, **blocks)
-    assert _tile_counts() == (live + 32, masked + 17)
-    flash_attention(q[:, :24], q[:, :24], q[:, :24], interpret=True)
-    assert _tile_counts() == (live + 32, masked + 17)
+    def sizes(causal, window):
+        tiles = _tile_schedule(96, 96, 16, 16, causal, window)
+        return len(tiles), sum(m for *_, m in tiles)
+
+    assert sizes(True, None) == (21, 6)
+    assert sizes(True, 16) == (11, 11)
+    assert sizes(False, None) == (36, 0)
+
+
+_PASSES = ("flash_attention_fwd", "flash_attention_dq", "flash_attention_dkv")
+
+
+@pytest.mark.parametrize("call", [
+    dict(length=96, window=None, block_q=16, block_k=16),
+    dict(length=96, window=16, block_q=16, block_k=16),
+    dict(length=24, window=None),  # the default blocks cover it: one tile
+])
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+def test_streaming_kernels_carry_their_pass_names(count_primitives, call,
+                                                  direction):
+    """A streaming call's ``pallas_call``s are named by their pass
+    (``flash_attention_fwd``, ``_dq``, ``_dkv``: the names the chip's
+    trace prints, each under the ``flash_attention`` prefix), one of
+    each in a differentiated call; a one-tile call keeps its kernels
+    unnamed, so it carries none of the three."""
+    call = dict(call)
+    q = jax.random.normal(jax.random.key(47), (1, call.pop("length"), 2, 8))
+
+    def attend(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=True, **call)
+
+    fn = attend if direction == "forward" else jax.grad(
+        lambda *a: jnp.sum(attend(*a)), argnums=(0, 1, 2))
+    counts = count_primitives(jax.make_jaxpr(fn)(q, q, q).jaxpr)
+    got = tuple(counts[name] for name in _PASSES)
+    if "block_q" not in call:
+        assert got == (0, 0, 0)
+        assert counts["_fwd_rows_kernel"] == 1
+    else:
+        assert got == ((1, 0, 0) if direction == "forward" else (1, 1, 1))
 
 
 @pytest.mark.parametrize("wrap", ["no_checkpoint", "bare_checkpoint"])

@@ -170,6 +170,19 @@ def test_whole_model_matches_reference(flat, ids):
         assert rel(got[k], g) < 5e-4, k
 
 
+def test_stats_count_the_expert_loops_tiles(flat, ids):
+    """``apply_with_stats`` counts the grouped loops' trips and rows:
+    ``moe.rows_run == moe.tiles_run x moe_tile >= moe.pairs_here``, and
+    the padding is less than a tile for each held expert of each of the
+    four expert layers."""
+    model = get_model("kimi_linear_lm", **KW)
+    _, stats = jax.jit(model.apply_with_stats)(ref.nested(flat), ids)
+    tiles, rows = int(stats["moe.tiles_run"]), int(stats["moe.rows_run"])
+    here = int(stats["moe.pairs_here"])
+    assert rows == tiles * KW["moe_tile"] >= here > 0
+    assert rows - here < 4 * KW["experts_held"][1] * KW["moe_tile"]
+
+
 def test_bfloat16_program_is_told_from_8_bit_products(flat, ids):
     """The configuration's precision (bfloat16 products, float32
     accumulation) against the reference, beside the reference's own
@@ -464,8 +477,8 @@ def test_recomputed_block_keeps_kernels_and_routing(wide_heads, remat):
     leaf by leaf."""
     counts, grads = wide_heads[remat]
     assert (counts["kda_fwd"], counts["kda_bwd"]) == (2, 2)
-    assert (counts["_fwd_kernel"], counts["_bwd_dq_kernel"],
-            counts["_bwd_dkv_kernel"]) == (1, 1, 1)
+    assert (counts["flash_attention_fwd"], counts["flash_attention_dq"],
+            counts["flash_attention_dkv"]) == (1, 1, 1)
     assert (counts["top_k"], counts["sort"]) == (2, 2)
     plain, want = wide_heads[False]
     assert (counts["dot_general"] > plain["dot_general"]) == remat
@@ -484,7 +497,7 @@ def test_narrow_heads_still_recompute_the_xla_scan(count_primitives):
     (counts, grads), (plain, want) = both[True], both[False]
     for c in (counts, plain):
         assert c["kda_fwd"] == c["kda_bwd"] == 0
-        assert (c["top_k"], c["sort"], c["_fwd_kernel"]) == (2, 2, 1)
+        assert (c["top_k"], c["sort"], c["flash_attention_fwd"]) == (2, 2, 1)
     assert counts["scan"] == plain["scan"] + 2 * 2
     for name, g in grads.items():
         assert rel(g, want[name]) < KIND_TOL or not np.any(want[name]), name
@@ -561,7 +574,7 @@ def test_four_shares_add_up_to_the_uncut_layer(flat):
             model = get_model(
                 "kimi_linear_lm", **{**KW, "experts_held": [4 * s, 4]})
             held = {k: v[4 * s:4 * s + 4] for k, v in experts.items()}
-            y, (pairs_here, _) = model._moe(
+            y, (pairs_here, _, _) = model._moe(
                 {**router, "experts": held, "shared": shared}, x)
             total = total + (y - shared_part)
             here += int(pairs_here)
@@ -583,7 +596,7 @@ def test_no_token_is_dropped_under_the_worst_imbalance(flat):
     lp["router_bias"] = jnp.asarray(bias)
     x = jax.random.normal(jax.random.key(5), (2, L, 64))
     with jax.default_matmul_precision("highest"):
-        y, (pairs, fullest) = jax.jit(model._moe)(lp, x)
+        y, (pairs, fullest, _) = jax.jit(model._moe)(lp, x)
     y_ref, pairs_ref = ref.moe(lp, x, c)
     assert int(pairs) == int(fullest) == int(pairs_ref) == 2 * L
     assert rel(y, y_ref) < 1e-5
@@ -622,8 +635,9 @@ def test_fit_trains_the_preset_and_reports_expert_load(trained):
     stats = report["model_stats"]
     snap = REGISTRY.snapshot()
     for name in ("moe.pairs_routed", "moe.pairs_here", "moe.expert_load_max",
-                 "moe.load_max_over_mean"):
+                 "moe.load_max_over_mean", "moe.tiles_run", "moe.rows_run"):
         assert snap["gauges"][name] == stats[name] > 0
+    assert stats["moe.rows_run"] == 32 * stats["moe.tiles_run"]  # the preset's tile
     # 16 rows x 128 tokens x 4 experts a token x 4 expert layers
     assert stats["moe.pairs_routed"] == 16 * 128 * 4 * 4
     assert stats["moe.expert_load_max"] <= stats["moe.pairs_here"] \
@@ -685,4 +699,4 @@ def test_step_has_a_fourth_output_only_for_a_model_with_stats(with_stats):
     if with_stats:
         assert set(out[3]) == {
             "moe.pairs_routed", "moe.pairs_here", "moe.expert_load_max",
-            "moe.load_max_over_mean"}
+            "moe.load_max_over_mean", "moe.tiles_run", "moe.rows_run"}

@@ -206,6 +206,19 @@ def test_whole_model_matches_reference_in_float32(flat, ids):
         assert rel(got[k], g) < 5e-4, k
 
 
+def test_stats_count_the_expert_loops_tiles(flat, ids):
+    """``apply_with_stats`` counts the grouped loops' trips and rows:
+    ``moe.rows_run == moe.tiles_run x moe_tile >= moe.pairs_here``, and
+    the padding is less than a tile for each held expert of each of the
+    four expert layers."""
+    model = get_model("laguna_lm", **KW)
+    _, stats = jax.jit(model.apply_with_stats)(ref.nested(flat), ids)
+    tiles, rows = int(stats["moe.tiles_run"]), int(stats["moe.rows_run"])
+    here = int(stats["moe.pairs_here"])
+    assert rows == tiles * KW["moe_tile"] >= here > 0
+    assert rows - here < 4 * KW["experts_held"][1] * KW["moe_tile"]
+
+
 def _grad_turn(got, r, worst=False):
     """The benchmark's number: the median (or the worst) over leaves of
     the part of the gradient's error that stands perpendicular to the
@@ -399,7 +412,7 @@ def test_four_shares_add_up_to_the_uncut_layer(flat):
             model = get_model(
                 "laguna_lm", **{**KW, "experts_held": [4 * s, 4]})
             held = {k: v[4 * s:4 * s + 4] for k, v in experts.items()}
-            y, (pairs_here, _) = model._moe(
+            y, (pairs_here, _, _) = model._moe(
                 {**router, "experts": held, "shared": shared}, x)
             total = total + (y - shared_part)
             here += int(pairs_here)

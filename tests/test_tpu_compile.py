@@ -173,6 +173,8 @@ def test_flash_attention_latent_heads_stream(one_chip, direction):
 
 
 _KERNEL = r"^\s*%?([a-z_]+?)[.\d]* = .*custom_call_target=\"tpu_custom_call\""
+# a differentiated streaming call's three kernels, as the chip names them
+_FLASH = ["flash_attention_dkv", "flash_attention_dq", "flash_attention_fwd"]
 
 
 @pytest.mark.parametrize("direction", ["forward", "backward"])
@@ -245,7 +247,7 @@ def test_laguna_block_at_published_widths(one_chip, monkeypatch, kind):
     txt = _compile(
         jax.grad(lambda p, x: jnp.mean(model.apply(p, x))), params, ids
     ).as_text()
-    assert re.findall(_KERNEL, txt, re.M) == ["flash_attention"] * 3
+    assert sorted(re.findall(_KERNEL, txt, re.M)) == _FLASH
     assert not re.findall(r"\[[\d,]*8192,\d+,128\]\S* gather\(", txt)
 
 
@@ -278,8 +280,8 @@ def test_laguna_step_fits_the_chip(one_chip, monkeypatch):
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
     assert mem.alias_size_in_bytes > 0.99 * mem.argument_size_in_bytes
-    assert re.findall(_KERNEL, compiled.as_text(), re.M) == [
-        "flash_attention"] * 15
+    assert sorted(re.findall(_KERNEL, compiled.as_text(), re.M)) == sorted(
+        _FLASH * 5)
 
 
 def _sized_f32(txt, ops, elements):
@@ -372,7 +374,7 @@ def test_recomputed_blocks_run_each_forward_kernel_once(one_chip, monkeypatch):
     txt = _kimi_blocks(one_chip, monkeypatch, num_layers=2, kda_layers=[1],
                        full_attn_layers=[2], first_k_dense_replace=0)
     kernels = re.findall(_KERNEL, txt, re.M)
-    assert sorted(kernels) == ["flash_attention"] * 3 + ["kda_bwd", "kda_fwd"]
+    assert sorted(kernels) == _FLASH + ["kda_bwd", "kda_fwd"]
 
 
 def test_kda_layer_has_no_layout_copy(one_chip, monkeypatch):
