@@ -12,9 +12,14 @@ held experts only, the router keeps its published width, and the layer
 adds its own experts' part; what the absent experts would add is left
 out and the partial sum goes on (docs/DESIGN.md section 30). No token
 is dropped: the pairs routed here are sorted by expert into tiles of
-``tile`` rows (:func:`plan`) and a loop whose trip count is the number
-of tiles IN USE runs them (:func:`grouped_ffn`), so the work grows with
-the pairs here while every shape stays static.
+``tile`` rows (:func:`plan`) and only the tiles IN USE are computed, so
+the work grows with the pairs here while every shape stays static
+(:func:`grouped_ffn`: a loop whose trip count is the number of tiles in
+use). Where ``ops/pallas/grouped_ffn.py`` ``takes`` the widths, its
+Pallas kernel, which walks the plan's tiles, runs the backward in place
+of the loop's, which stays as its reference (``moe.calls_traced`` /
+``moe.calls_kernel`` in ``utils.metrics.REGISTRY`` say once a trace
+which was chosen).
 """
 
 from __future__ import annotations
@@ -22,6 +27,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
+
+from mlapi_tpu.ops.pallas import grouped_ffn as kernels
+from mlapi_tpu.utils.metrics import REGISTRY
+from mlapi_tpu.utils.platform import pallas_interpret
 
 _HI = jax.lax.Precision.HIGHEST
 # What moe() names for a recomputing block to keep: the router's scores
@@ -88,13 +97,16 @@ def _expert_tile(xs, wg, wu, wd, e):
     return (wg, wu, wd), (a, b, h), jnp.dot(h, wd, **f32)
 
 
-def _grouped(x, wflat, wg, wu, wd, rows, tile_expert, n_tiles, tile, k):
+def _grouped(x, wflat, wg, wu, wd, rows, tile_expert, n_tiles, tile, k,
+             kernel):
     """``y[t] = sum over the pairs (t, j) in rows of wflat[pair] *
     E_e(x[t])``, ``E(x) = (silu(x wg) * (x wu)) wd``: the held experts'
     part of the layer. ``x [T, H]`` and ``wg, wu [n, H, I]``, ``wd [n,
     I, H]`` in the compute dtype; ``wflat [T * k]`` float32. The loop
     runs ``n_tiles`` tiles (a value, not a shape): the work follows the
-    pairs that are here."""
+    pairs that are here. ``kernel``: the backward by the Pallas kernel
+    of ``ops/pallas/grouped_ffn.py`` (the forward is this loop either
+    way)."""
     def body(t, y):
         _, _, tok, wt = _tile_rows(rows, wflat, t, tile, k)
         _, _, o = _expert_tile(x[tok], wg, wu, wd, tile_expert[t])
@@ -104,17 +116,24 @@ def _grouped(x, wflat, wg, wu, wd, rows, tile_expert, n_tiles, tile, k):
         0, n_tiles, body, jnp.zeros(x.shape, jnp.float32))
 
 
-grouped_ffn = jax.custom_vjp(_grouped, nondiff_argnums=(8, 9))
+grouped_ffn = jax.custom_vjp(_grouped, nondiff_argnums=(8, 9, 10))
 
 
-def _grouped_fwd(x, wflat, wg, wu, wd, rows, tile_expert, n_tiles, tile, k):
-    y = _grouped(x, wflat, wg, wu, wd, rows, tile_expert, n_tiles, tile, k)
+def _grouped_fwd(x, wflat, wg, wu, wd, rows, tile_expert, n_tiles, tile, k,
+                 kernel):
+    y = _grouped(x, wflat, wg, wu, wd, rows, tile_expert, n_tiles, tile, k,
+                 kernel)
     return y, (x, wflat, wg, wu, wd, rows, tile_expert, n_tiles)
 
 
-def _grouped_bwd(tile, k, res, dy):
+def _grouped_bwd(tile, k, kernel, res, dy):
     x, wflat, wg, wu, wd, rows, tile_expert, n_tiles = res
     cdt = x.dtype
+    if kernel:
+        dx, dwf, dwg, dwu, dwd = kernels.grouped_ffn_bwd(
+            x, dy, wflat, wg, wu, wd, rows, tile_expert, n_tiles, tile=tile,
+            k=k, interpret=pallas_interpret())
+        return (dx.astype(cdt), dwf, dwg, dwu, dwd, None, None, None)
     f32 = dict(preferred_element_type=jnp.float32)
 
     def body(t, carry):
@@ -155,7 +174,7 @@ def moe(p, x, *, k: int, held: tuple, tile: int, scale: float,
         compute_dtype):
     """The held experts' part plus the shared expert of ``x [B, L, H]``,
     and the layer's ``(pairs here, fullest held expert's pairs, tiles
-    the grouped loop runs)``.
+    the grouped product runs)``.
     ``p``: ``router [H, E]``, ``experts`` (``gate``, ``up`` ``[n, H,
     I]``, ``down [n, I, H]`` of the ``held = (first, n)`` experts),
     ``shared`` (a SwiGLU), and ``router_bias [E]`` where the family
@@ -178,10 +197,14 @@ def moe(p, x, *, k: int, held: tuple, tile: int, scale: float,
         w = chosen / jnp.sum(chosen, axis=-1, keepdims=True) * scale
     with jax.named_scope("moe.experts"):
         e = p["experts"]
+        REGISTRY.counter("moe.calls_traced").inc()
+        kernel = kernels.takes(hid, e["gate"].shape[-1], tile, cdt)
+        if kernel:
+            REGISTRY.counter("moe.calls_kernel").inc()
         y = grouped_ffn(
             x2.astype(cdt), w.reshape(-1), e["gate"].astype(cdt),
             e["up"].astype(cdt), e["down"].astype(cdt), rows,
-            tile_expert, n_tiles, tile, k)
+            tile_expert, n_tiles, tile, k, kernel)
     with jax.named_scope("moe.shared"):
         y = y.reshape(b, l, hid) + ffn(p["shared"], x, cdt)
     return y, (jnp.sum(counts), jnp.max(counts), n_tiles)

@@ -1,7 +1,9 @@
 """``models/experts.py``, the sparse-expert layer that ``kimi_linear_lm``
 and ``laguna_lm`` share: the routing plan's invariants, the grouped
-product against a dense sum over experts (values and gradients), and
-the optional selection bias."""
+product against a dense sum over experts (values and gradients), the
+backward's Pallas kernel (``ops/pallas/grouped_ffn.py``) against the XLA
+loop's (interpreter), the choice between them by shapes, and the
+optional selection bias."""
 
 import jax
 import jax.numpy as jnp
@@ -9,6 +11,8 @@ import numpy as np
 import pytest
 
 from mlapi_tpu.models import experts, kimi_linear, laguna
+from mlapi_tpu.ops.pallas import grouped_ffn as gk
+from mlapi_tpu.utils.metrics import REGISTRY
 
 
 def _rel(a, b):
@@ -64,7 +68,7 @@ def test_grouped_product_is_the_dense_sum_over_held_experts(cdt):
         rows, tile_expert, n_tiles, _ = experts.plan(idx, first, count, tile)
         return jnp.sum(jnp.sin(experts.grouped_ffn(
             x.astype(dt), w.reshape(-1), wg.astype(dt), wu.astype(dt),
-            wd.astype(dt), rows, tile_expert, n_tiles, tile, k)))
+            wd.astype(dt), rows, tile_expert, n_tiles, tile, k, False)))
 
     def dense(x, w, wg, wu, wd):
         y = 0.0
@@ -147,3 +151,147 @@ def test_tiles_run_is_the_plans_closed_form(per_expert, tile, tiles):
         [(jnp.sum(counts), jnp.max(counts), n_tiles)], 3, idx.size, tile)
     assert int(stats["moe.tiles_run"]) == tiles
     assert int(stats["moe.rows_run"]) == tiles * tile
+
+
+# Widths the kernel takes (a token's row of whole float32 tiles), small
+# enough for the interpreter: 512 tokens x 4 choices of 32 experts, 8
+# held (ids 8-15), tiles of 128.
+T, K, E, FIRST, COUNT, HID, INTER, TILE = 512, 4, 32, 8, 8, 1024, 128, 128
+LOADS = {
+    # pairs a held expert: one over three tiles whose last is mostly
+    # padding (44 of 128), one over two (the second holds ONE pair), two
+    # with no pair, one with a single pair
+    "mixed": [300, 0, 129, 5, 64, 0, 1, 200],
+    "collapse": [0, 0, 0, T, 0, 0, 0, 0],     # every pair here on one expert
+    "none": [0] * COUNT,                      # n_tiles 0
+    "random": None,                           # distinct uniform choices
+}
+
+
+def _routed(load, seed=3):
+    """``idx [T, K]``: each held expert gets the tokens ``LOADS[load]``
+    says (distinct tokens), every other choice an expert not held."""
+    rng = np.random.default_rng(seed)
+    per = LOADS[load]
+    if per is None:
+        return jnp.asarray(np.stack([rng.permutation(E)[:K] for _ in range(T)]))
+    choices = [[] for _ in range(T)]
+    for e, c in enumerate(per):
+        for t in rng.choice(T, c, replace=False):
+            choices[t].append(FIRST + e)
+    others = [e for e in range(E) if not FIRST <= e < FIRST + COUNT]
+    for ch in choices:
+        assert len(ch) <= K
+        ch += [int(o) for o in rng.choice(others, K - len(ch), replace=False)]
+        rng.shuffle(ch)
+    return jnp.asarray(choices, jnp.int32)
+
+
+def _operands(seed, inter):
+    ks = jax.random.split(jax.random.key(seed), 6)
+    x = jax.random.normal(ks[0], (T, HID))
+    w = jax.random.uniform(ks[1], (T * K,))
+    wg, wu = (0.1 * jax.random.normal(r, (COUNT, HID, inter)) for r in ks[2:4])
+    wd = 0.1 * jax.random.normal(ks[4], (COUNT, inter, HID))
+    return (x, w, wg, wu, wd), jax.random.normal(ks[5], (T, HID))
+
+
+def _both_backwards(load, cdt, inter, seed):
+    """``y`` and the gradients of ``x``, the routing weights and the
+    three kernels, by the kernel's backward and by the loop's, over the
+    plan of ``load``."""
+    idx = _routed(load)
+    rows, tile_expert, n_tiles, counts = experts.plan(idx, FIRST, COUNT, TILE)
+    if LOADS[load] is not None:
+        assert list(np.asarray(counts)) == LOADS[load]
+    ops, probe = _operands(seed, inter)
+    dt = jnp.dtype(cdt)
+
+    def run(kernel):
+        def f(x, w, wg, wu, wd):
+            return experts.grouped_ffn(
+                x.astype(dt), w, wg.astype(dt), wu.astype(dt), wd.astype(dt),
+                rows, tile_expert, n_tiles, TILE, K, kernel)
+
+        y, vjp = jax.vjp(f, *ops)
+        return (y,) + vjp(probe)
+
+    return (jax.jit(lambda: run(True))(), jax.jit(lambda: run(False))(),
+            int(n_tiles))
+
+
+@pytest.mark.parametrize("cdt", ["float32", "bfloat16"])
+@pytest.mark.parametrize("load", list(LOADS))
+def test_grouped_kernel_backward_matches_the_loop(load, cdt):
+    """The backward by the Pallas kernel (``ops/pallas/grouped_ffn.py``,
+    interpreter) against the XLA loop's over the same plan: the
+    gradients of ``x``, the routing weights and the three kernels, as
+    norm of the difference over the loop's norm (``y`` is the loop's
+    either way). With no pair here every result is zero."""
+    got, want, n_tiles = _both_backwards(load, cdt, INTER, seed=4)
+    names = ("y", "dx", "dw", "dwg", "dwu", "dwd")
+    if load == "none":
+        assert n_tiles == 0
+        for name, a in zip(names, got):
+            assert not np.any(np.asarray(a, np.float32)), name
+        return
+    tol = 1e-5 if cdt == "float32" else 5e-3
+    for name, a, b in zip(names, got, want):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        assert np.all(np.isfinite(np.asarray(a, np.float32))), name
+        assert _rel(a, b) < tol, (name, _rel(a, b))
+
+
+def test_grouped_kernel_splits_the_intermediate_width(monkeypatch):
+    """Where an expert's blocks would not fit VMEM whole, the kernel runs
+    the intermediate width in blocks, one pass over the tiles a block:
+    ``dx`` gathers each pass's part in its float32 rows, the routing
+    weights' gradient is summed over the passes, each block of the
+    weight gradients is its own. Here two blocks of 128 against the
+    loop, float32, on the mixed load."""
+    monkeypatch.setattr(gk, "_block", lambda h, i, tile, size: i // 2)
+    got, want, _ = _both_backwards("mixed", "float32", 256, seed=8)
+    for a, b in zip(got, want):
+        assert _rel(a, b) < 1e-5
+
+
+def test_shapes_choose_between_the_kernel_and_the_loop(monkeypatch):
+    """``moe`` takes the kernel's backward where a token's row is whole
+    float32 tiles (hidden a multiple of 1024, Laguna's 2048), the
+    intermediate width whole 128-lane columns and the tile whole 8-row
+    groups, and the XLA loop's elsewhere: at the rehearsal widths
+    (hidden 64, tile 16) and at kimi's hidden 2304. Read from
+    ``moe.calls_traced`` / ``moe.calls_kernel``, counted once a trace;
+    both paths' gradients agree."""
+    def counts():
+        c = REGISTRY.snapshot()["counters"]
+        return c.get("moe.calls_traced", 0), c.get("moe.calls_kernel", 0)
+
+    assert gk.takes(HID, INTER, TILE) and gk.takes(2048, 512, 256)
+    assert not gk.takes(2304, 1024, 256)
+    assert not gk.takes(64, 32, 16) and not gk.takes(1024, 128, 12)
+    for hid, inter, tile, kernel in ((HID, INTER, TILE, 1), (64, 32, 16, 0)):
+        rng = jax.random.split(jax.random.key(6), 5)
+        p = {"router": 0.3 * jax.random.normal(rng[0], (hid, E)),
+             "experts": {
+                 "gate": 0.1 * jax.random.normal(rng[1], (COUNT, hid, inter)),
+                 "up": 0.1 * jax.random.normal(rng[2], (COUNT, hid, inter)),
+                 "down": 0.1 * jax.random.normal(rng[3], (COUNT, inter, hid))},
+             "shared": {"gate": jnp.zeros((hid, inter)),
+                        "up": jnp.zeros((hid, inter)),
+                        "down": jnp.zeros((inter, hid))}}
+        x = jax.random.normal(rng[4], (2, 128, hid))
+        kw = dict(k=K, held=(FIRST, COUNT), tile=tile, scale=2.5,
+                  compute_dtype="float32")
+        before = counts()
+        fn = jax.jit(jax.grad(lambda p: jnp.sum(experts.moe(p, x, **kw)[0] ** 2)))
+        got = fn(p)
+        fn(p)  # a second call of the same trace counts nothing
+        after = counts()
+        assert (after[0] - before[0], after[1] - before[1]) == (1, kernel)
+        with monkeypatch.context() as m:
+            m.setattr(gk, "takes", lambda *a: False)
+            want = jax.jit(jax.grad(
+                lambda p: jnp.sum(experts.moe(p, x, **kw)[0] ** 2)))(p)
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            assert _rel(a, b) < 1e-4

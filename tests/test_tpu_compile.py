@@ -175,6 +175,24 @@ def test_flash_attention_latent_heads_stream(one_chip, direction):
 _KERNEL = r"^\s*%?([a-z_]+?)[.\d]* = .*custom_call_target=\"tpu_custom_call\""
 # a differentiated streaming call's three kernels, as the chip names them
 _FLASH = ["flash_attention_dkv", "flash_attention_dq", "flash_attention_fwd"]
+# a differentiated expert layer's kernel (the backward); the name of the
+# program's operation carries the transforms around it
+_EXPERTS = ["grouped_ffn_bwd"]
+
+
+def _kernels(txt):
+    """The Pallas kernels of a compiled program by the names they were
+    given (``name=`` of the ``pallas_call``), sorted."""
+    found = re.findall(_KERNEL, txt, re.M)
+    return sorted(next((k for k in _FLASH + _EXPERTS if k in n), n)
+                  for n in found)
+
+
+def _in_scope(txt, scope):
+    """The instructions of a compiled program whose ``op_name`` holds
+    ``scope`` (a ``jax.named_scope``)."""
+    return [line for line in txt.splitlines()
+            if re.search(rf'op_name="[^"]*{re.escape(scope)}[/"]', line)]
 
 
 @pytest.mark.parametrize("direction", ["forward", "backward"])
@@ -229,12 +247,16 @@ def test_laguna_block_at_published_widths(one_chip, monkeypatch, kind):
     8192; norm, projections, rotary, the flash call, headwise gate,
     output projection, router, 32 held experts, shared expert) under
     the model's ``jax.checkpoint`` policy, gradient in the parameters:
-    three flash kernels (forward once, dq, dk/dv) and a rotation with
-    no gather."""
-    from mlapi_tpu.models import get_model, laguna
+    three flash kernels (forward once, dq, dk/dv), the expert layer's
+    backward kernel, a rotation with no gather, and in the expert
+    layer's backward (``moe.experts`` under ``transpose``) no ``while``
+    loop and no scatter into a ``[8192, 2048]`` operand: the rows move
+    inside the kernel."""
+    from mlapi_tpu.models import experts, get_model, laguna
 
     # code that asks the backend sees the CPU here: steer it in the test
     monkeypatch.setattr(laguna, "pallas_interpret", lambda: False)
+    monkeypatch.setattr(experts, "pallas_interpret", lambda: False)
     model = get_model("laguna_lm", **dict(
         _laguna_kwargs(), vocab_size=1024, num_layers=1, layer_types=[kind],
         heads_per_layer=[64 if kind == "sliding_attention" else 48],
@@ -247,8 +269,14 @@ def test_laguna_block_at_published_widths(one_chip, monkeypatch, kind):
     txt = _compile(
         jax.grad(lambda p, x: jnp.mean(model.apply(p, x))), params, ids
     ).as_text()
-    assert sorted(re.findall(_KERNEL, txt, re.M)) == _FLASH
+    assert _kernels(txt) == sorted(_FLASH + _EXPERTS)
     assert not re.findall(r"\[[\d,]*8192,\d+,128\]\S* gather\(", txt)
+    back = [line for line in _in_scope(txt, "moe.experts")
+            if "transpose(" in line]
+    assert any("tpu_custom_call" in line for line in back)
+    assert not [line for line in back if " while(" in line]
+    assert not [line for line in back
+                if re.search(r"= \w+\[8192,2048\]\S* scatter\(", line)]
 
 
 def test_laguna_step_fits_the_chip(one_chip, monkeypatch):
@@ -256,15 +284,17 @@ def test_laguna_step_fits_the_chip(one_chip, monkeypatch):
     builds it (``make_train_step``, AdamW, 1 x 8192, all five layers at
     the published widths): the chip's compiler takes it, it holds 15
     flash kernels (five layers x forward, dq, dk/dv: no forward runs
-    twice) and weights + AdamW state + the step's temporaries stay
-    under the 15.75 GB a v5e reports as its limit (read here: 8.30 GB
-    of arguments, all aliased to the outputs, + 4.32 GB)."""
+    twice) and the four expert layers' backward kernels, and weights +
+    AdamW state + the step's temporaries stay under the 15.75 GB a v5e
+    reports as its limit (read here: 8.30 GB of arguments, all aliased
+    to the outputs, + 4.32 GB)."""
     import optax
 
-    from mlapi_tpu.models import get_model, laguna
+    from mlapi_tpu.models import experts, get_model, laguna
     from mlapi_tpu.train.loop import make_train_step
 
     monkeypatch.setattr(laguna, "pallas_interpret", lambda: False)
+    monkeypatch.setattr(experts, "pallas_interpret", lambda: False)
     model = get_model("laguna_lm", **_laguna_kwargs())
     tx = optax.adamw(1e-4)
     shaped = lambda t: jax.tree.map(  # noqa: E731
@@ -280,8 +310,7 @@ def test_laguna_step_fits_the_chip(one_chip, monkeypatch):
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
     assert mem.alias_size_in_bytes > 0.99 * mem.argument_size_in_bytes
-    assert sorted(re.findall(_KERNEL, compiled.as_text(), re.M)) == sorted(
-        _FLASH * 5)
+    assert _kernels(compiled.as_text()) == sorted(_FLASH * 5 + _EXPERTS * 4)
 
 
 def _sized_f32(txt, ops, elements):
@@ -394,6 +423,57 @@ def test_kda_layer_has_no_layout_copy(one_chip, monkeypatch):
     moved = _sized_f32(txt, "copy|transpose|reshape", 8192 * 4096)
     assert not moved, moved
     assert not re.findall(r"\w+\[[\d,]*8192,32,128\]", txt)
+
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+def test_grouped_ffn_kernel_at_the_laguna_cell(one_chip, monkeypatch,
+                                               direction):
+    """The expert layer of the cell ``laguna-xs2.pretrain_8k``'s grouped
+    product (``models.experts.grouped_ffn`` with the kernel's backward):
+    8,192 tokens x 8 choices, tiles of 256, planned over 32 held experts
+    of 2048 x 512, forward and the gradient of a sum in ``x``, the
+    routing weights and the three kernels. The forward is the XLA loop
+    and holds no kernel; the gradient is the kernel alone (the sum's
+    gradient needs nothing the forward makes), Mosaic takes it in the
+    VMEM limit with the intermediate width in one block, and no XLA
+    ``while`` loop is left outside the plan. Kimi's hidden 2304 is no
+    whole number of float32 tiles a token's row: it keeps the loop's
+    backward."""
+    from mlapi_tpu.models import experts
+    from mlapi_tpu.ops.pallas import grouped_ffn as gk
+
+    t, k, tile, count, hid, inter = 8192, 8, 256, 32, 2048, 512
+    assert gk._block(hid, inter, tile, 2) == inter
+    assert not gk.takes(2304, 1024, tile)
+
+    def fwd(x, idx, w, wg, wu, wd):
+        rows, tile_expert, n_tiles, _ = jax.named_call(
+            experts.plan, name="plan")(idx, 0, count, tile)
+        return experts.grouped_ffn(x, w, wg, wu, wd, rows, tile_expert,
+                                   n_tiles, tile, k, True)
+
+    fn = fwd
+    if direction == "backward":
+        def fn(x, idx, *a):
+            return jax.grad(lambda *a: jnp.sum(fwd(a[0], idx, *a[1:])),
+                            argnums=(0, 1, 2, 3, 4))(x, *a)
+
+    args = (_shape((t, hid), jnp.bfloat16, one_chip),
+            _shape((t, k), jnp.int32, one_chip),
+            _shape((t * k,), jnp.float32, one_chip),
+            _shape((count, hid, inter), jnp.bfloat16, one_chip),
+            _shape((count, hid, inter), jnp.bfloat16, one_chip),
+            _shape((count, inter, hid), jnp.bfloat16, one_chip))
+    monkeypatch.setattr(experts, "pallas_interpret", lambda: False)
+    txt = _compile(fn, *args).as_text()
+    assert _kernels(txt) == ([] if direction == "forward" else _EXPERTS)
+    outside_plan = [line for line in txt.splitlines()
+                    if " while(" in line and "plan" not in line]
+    assert bool(outside_plan) == (direction == "forward")
+    shapes = [o.shape for o in jax.tree.leaves(jax.eval_shape(fn, *args))]
+    assert shapes == ([(t, hid)] if direction == "forward" else
+                      [(t, hid), (t * k,), (count, hid, inter),
+                       (count, hid, inter), (count, inter, hid)])
 
 
 def test_flash_attention_layer_has_no_layout_copy(one_chip):

@@ -12,7 +12,8 @@ head geometry ``chip_smoke.py`` serves (BERT-base / GPT-2 small:
                                             # with 64/8 heads under a
                                             # window and 48/8 causal;
                                             # the gated delta rule's
-                                            # agreement rows
+                                            # and the experts' backward
+                                            # kernel's agreement rows
     python -m tools.chip_kernels --tp       # decode_attention_tp on a
                                             # (1, 4) mesh vs the
                                             # unsharded kernel
@@ -30,6 +31,15 @@ head geometry ``chip_smoke.py`` serves (BERT-base / GPT-2 small:
                                             # (--kda-tile 32 64 and
                                             # --kda-unroll 2 4 sweep
                                             # the kernel's two knobs)
+    python -m tools.chip_kernels --experts  # the held experts' grouped
+                                            # product's backward: the
+                                            # kernel against the XLA
+                                            # loop at the Laguna cell's
+                                            # expert layer (8,192 tokens
+                                            # x 8 of 256, 32 held of
+                                            # 2048 x 512), max error and
+                                            # ms a call forward and
+                                            # backward
     python -m tools.chip_kernels --tiny     # CPU rehearsal sizes
 
 ``interpret`` follows the one rule (``utils.platform.
@@ -473,11 +483,98 @@ def run_kda(tiny: bool, timing: bool = True, tiles=(),
     return rows
 
 
+def run_experts(tiny: bool, timing: bool = True) -> list[dict]:
+    """The backward of the held experts' grouped product by its Pallas
+    kernel (``ops/pallas/grouped_ffn.py``) against the XLA loop's
+    (``models.experts.grouped_ffn`` with ``kernel`` off), at the
+    expert layer of the cell ``laguna-xs2.pretrain_8k``: 8,192 tokens,
+    each routed to 8 of 256 experts uniformly at random (distinct),
+    planned over the 32 held (2048 x 512, an eighth of the pairs),
+    bfloat16 products. Agreement: the five gradients of ``sum(y *
+    probe)`` as max error over the loop's largest value, one row each
+    (``y`` is the loop's on both paths). With ``timing``, ms a call of
+    the forward and of that gradient (the backward alone: it needs
+    nothing the forward makes, as in the model, whose recomputation
+    leaves the forward out), and the two over the tiles in use: what
+    ``moe_us_per_tile.train`` reads a trip."""
+    import jax
+    import jax.numpy as jnp
+
+    from mlapi_tpu.models import experts
+    from mlapi_tpu.utils.platform import pallas_interpret
+
+    interp = pallas_interpret()
+    rows_out: list[dict] = []
+    t, k, n_experts, tile, count, hid, inter = (
+        (512, 4, 32, 128, 8, 1024, 128) if tiny
+        else (8192, 8, 256, 256, 32, 2048, 512))
+    ks = jax.random.split(jax.random.key(7), 7)
+    _, idx = jax.lax.top_k(jax.random.normal(ks[0], (t, n_experts)), k)
+    plan = jax.jit(lambda i: experts.plan(i, 0, count, tile)[:3])(idx)
+    x = jax.random.normal(ks[1], (t, hid), jnp.bfloat16)
+    w = jax.random.uniform(ks[2], (t * k,))
+    wg, wu = (0.05 * jax.random.normal(r, (count, hid, inter), jnp.bfloat16)
+              for r in ks[3:5])
+    wd = 0.05 * jax.random.normal(ks[5], (count, inter, hid), jnp.bfloat16)
+    probe = jax.random.normal(ks[6], (t, hid))
+    ops = (x, w, wg, wu, wd)
+
+    def path(kernel):
+        def fn(x, w, wg, wu, wd, *plan):
+            return experts.grouped_ffn(x, w, wg, wu, wd, *plan, tile, k,
+                                       kernel)
+        return fn
+
+    def grad(fn):
+        return jax.jit(jax.grad(
+            lambda x, w, wg, wu, wd, probe, *plan: jnp.sum(
+                fn(x, w, wg, wu, wd, *plan) * probe),
+            argnums=(0, 1, 2, 3, 4)))
+
+    got, want = (grad(path(kernel))(*ops, probe, *plan)
+                 for kernel in (True, False))
+    for name, a, b in zip(("dx", "dw", "dwg", "dwu", "dwd"), got, want):
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        scale = float(jnp.max(jnp.abs(b))) or 1.0
+        e = float(jnp.max(jnp.abs(a - b))) / scale
+        finite = bool(jnp.all(jnp.isfinite(a)))
+        r = {"kernel": f"grouped_ffn-laguna-{name}", "max_abs_err": e,
+             "tol": TOL, "shape": list(a.shape), "interpret": interp,
+             "within_tol": finite and e <= TOL}
+        print(json.dumps(r), flush=True)
+        rows_out.append(r)
+    if not timing:
+        return rows_out
+
+    def timed(fn, *args, n=2 if tiny else 10):
+        jax.block_until_ready(fn(*args))
+        t0 = time.perf_counter()
+        for _ in range(n):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        return (time.perf_counter() - t0) / n * 1e3
+
+    tiles = int(plan[2])
+    f = timed(jax.jit(path(False)), *ops, *plan)
+    line = {"timing": "grouped_ffn-laguna", "tokens": t, "k": k,
+            "held": count, "hidden": hid, "intermediate": inter,
+            "tile": tile, "tiles_in_use": tiles, "interpret": interp,
+            "what": "host clock around drained calls, ms a call; the "
+            "forward is the loop on either path", "forward_ms": f}
+    for name, kernel in (("kernel_backward", True), ("xla_loop", False)):
+        b = timed(grad(path(kernel)), *ops, probe, *plan)
+        print(json.dumps({**line, "path": name, "backward_ms": b,
+                          "us_per_tile": 1e3 * (f + b) / max(tiles, 1)}),
+              flush=True)
+    return rows_out
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser("tools.chip_kernels")
     ap.add_argument("--tiny", action="store_true")
     ap.add_argument("--tp", action="store_true")
     ap.add_argument("--kda", action="store_true")
+    ap.add_argument("--experts", action="store_true")
     ap.add_argument("--kda-tile", type=int, nargs="*", default=())
     ap.add_argument("--kda-unroll", type=int, nargs="*", default=())
     args = ap.parse_args(argv)
@@ -492,10 +589,13 @@ def main(argv=None) -> int:
     enable_compile_cache()
     if args.kda:
         rows = run_kda(args.tiny, True, args.kda_tile, args.kda_unroll)
+    elif args.experts:
+        rows = run_experts(args.tiny)
     else:
         rows = run(args.tiny, args.tp)
         if not args.tp:
             rows += run_kda(args.tiny, timing=False)
+            rows += run_experts(args.tiny, timing=False)
     bad = [r["kernel"] for r in rows if not r["within_tol"]]
     print(json.dumps({
         "kernels": len(rows), "failed": bad, "tol": TOL,
