@@ -65,6 +65,27 @@ def chip_or_exit(job: dict) -> dict:
     return device
 
 
+def mesh_or_none(cfg: dict):
+    """The mesh ``fit`` gets: None where the configuration's ``mesh``
+    holds one device (``mesh_for_config([1, 1])`` would give a (1, 1)
+    mesh, not None, and a meshed ``fit`` is another program), else
+    ``mesh_for_config`` of it. A child that sees fewer devices than the
+    configuration's ``chips`` stops at once, as without a chip."""
+    import math
+
+    import jax
+
+    if math.prod(cfg["mesh"]) == 1:
+        return None
+    if len(jax.devices()) < cfg["chips"]:
+        print(f"benchmark: {len(jax.devices())} devices visible, the "
+              f"configuration needs {cfg['chips']}", flush=True)
+        raise SystemExit(NO_CHIP)
+    from mlapi_tpu.parallel.mesh import mesh_for_config
+
+    return mesh_for_config(cfg["mesh"])
+
+
 def memory_peak_bytes() -> dict | None:
     """The fullest chip's peak, as the backend reports it. On a TPU
     ``peak_bytes_in_use`` counts live arrays only; what a running

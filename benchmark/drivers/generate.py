@@ -335,7 +335,8 @@ def run(ctx: dict) -> dict:
     return {
         "checks": checks, "attempted": summary["attempted"],
         "failed": summary["failed"], "device": device, "trace": trace,
-        "peak": peak, "config": config, "cell": cell,
+        "peak": peak, "chips": ctx["entry"]["chips"],
+        "config": config, "cell": cell,
         "end_to_end": {
             # run.py keeps the ones BENCHMARK.json lists for the cell
             **{k: summary[k] for k in ("out_tokens_per_s", "ttft_p95_ms",

@@ -137,7 +137,7 @@ def run(ctx: dict) -> dict:
     os.makedirs(work)
     env = harness.cache_env(os.environ)
     if args.rehearse:
-        env["JAX_PLATFORMS"] = "cpu"
+        env = harness.rehearsal_env(env, ctx["entry"]["chips"])
     job = make_job(args, cell, config, work)
     trace_dir = job["trace_dir"]
     job_path = os.path.join(work, "job.json")
@@ -200,6 +200,7 @@ def run(ctx: dict) -> dict:
     return {
         "checks": checks, "attempted": prog["steps"], "failed": 0,
         "device": device, "trace": trace, "peak": peak,
+        "chips": ctx["entry"]["chips"],
         "config": config, "cell": cell,
         "end_to_end": {
             "train_tokens_per_s": tokens / window if window > 0 else None,

@@ -24,6 +24,12 @@ def plant_train(fault: str, loop_module) -> None:
             def broken(params, opt_state, x, y):
                 h = x.shape[0] // 2
                 return step(params, opt_state, x[:h], y[:h])
+        elif fault == "quarter_batch":
+            # the first of four shards alone: the update a replica of a
+            # (4, 1) mesh applies where its gradient is not all-reduced
+            def broken(params, opt_state, x, y):
+                q = x.shape[0] // 4
+                return step(params, opt_state, x[:q], y[:q])
         else:
             raise ValueError(f"unknown training fault {fault!r}")
         broken.lower = step.lower

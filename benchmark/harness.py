@@ -91,6 +91,25 @@ def device_gate(device: dict, chips: int, peaks: dict, rehearse: bool) -> dict:
     return peak
 
 
+def cell_peak(run: dict) -> dict:
+    """The peak rates of all the chips the cell runs on: what a reader
+    divides by where its work is the whole step's (the global batch's)
+    and its time one device's (``trace_reduce`` times are per device
+    plane). One chip's ``peaks.json`` entry times ``run["chips"]``."""
+    return {k: v * run["chips"] for k, v in run["peak"].items()}
+
+
+def rehearsal_env(env: dict, chips: int) -> dict:
+    """A CPU rehearsal's child environment: the CPU platform, with as
+    many virtual devices as the cell has chips unless ``XLA_FLAGS``
+    already sets a count."""
+    env = dict(env, JAX_PLATFORMS="cpu")
+    flag = "--xla_force_host_platform_device_count"
+    if chips > 1 and flag not in env.get("XLA_FLAGS", ""):
+        env["XLA_FLAGS"] = f"{env.get('XLA_FLAGS', '')} {flag}={chips}".strip()
+    return env
+
+
 def nearest_rank(sorted_vals: list, q: float):
     """Nearest-rank quantile of an ascending list (copied from
     ``mlapi_tpu/utils/metrics.py``): the smallest value with at least

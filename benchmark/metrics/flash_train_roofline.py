@@ -23,7 +23,7 @@ def read(run):
     heads = cfg["num_attention_heads"]
     args = (w["batch_size"], heads, w["seq_len"], cfg["hidden_size"] // heads)
     need = sum(
-        opcount.roofline_seconds(c["flops"], c["bytes"], run["peak"])
+        opcount.roofline_seconds(c["flops"], c["bytes"], harness.cell_peak(run))
         for c in (opcount.flash_call(*args, causal=False, backward=False),
                   opcount.flash_call(*args, causal=False, backward=True)))
     return 100.0 * need * cfg["num_hidden_layers"] * steps / (fwd + bwd)

@@ -5,6 +5,7 @@ recurrence needs; recomputation not counted), times the KDA layers and
 the steps, over the device time of the operations under the
 ``kda.core`` scope (``scope_time.py``)."""
 
+import harness
 import opcount_kimi_linear as oc
 
 
@@ -17,7 +18,7 @@ def read(run):
     args = (w["batch_size"], lin["num_heads"], w["seq_len"],
             lin["head_dim"], lin["head_dim"])
     need = sum(
-        oc.roofline_seconds(c["flops"], c["bytes"], run["peak"])
+        oc.roofline_seconds(c["flops"], c["bytes"], harness.cell_peak(run))
         for c in (oc.kda_core(*args, backward=False),
                   oc.kda_core(*args, backward=True)))
     layers = sum(m == "kda" for m, _ in oc.layer_kinds(cfg))

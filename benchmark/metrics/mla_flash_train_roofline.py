@@ -26,7 +26,7 @@ def read(run):
             cfg["qk_nope_head_dim"] + cfg["qk_rope_head_dim"],
             cfg["v_head_dim"])
     need = sum(
-        oc.roofline_seconds(c["flops"], c["bytes"], run["peak"])
+        oc.roofline_seconds(c["flops"], c["bytes"], harness.cell_peak(run))
         for c in (oc.mla_flash_call(*args, causal=True, backward=False),
                   oc.mla_flash_call(*args, causal=True, backward=True)))
     layers = sum(m == "mla" for m, _ in oc.layer_kinds(cfg))

@@ -26,5 +26,6 @@ def read(run):
     cfg = run["config"]
     call = opcount.decode_attention_call(rows * ctx, cfg["n_head"],
                                          cfg["n_embd"] // cfg["n_head"])
-    need = opcount.roofline_seconds(call["flops"], call["bytes"], run["peak"])
+    need = opcount.roofline_seconds(call["flops"], call["bytes"],
+                                    harness.cell_peak(run))
     return 100.0 * need / (sec / n)

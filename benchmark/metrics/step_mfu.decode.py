@@ -38,4 +38,4 @@ def read(run):
     rows, ctx = shape
     work = opcount.gpt2_decode_step(run["config"], rows, rows * ctx)
     return 100.0 * opcount.roofline_seconds(
-        work["flops"], work["bytes"], run["peak"]) / s
+        work["flops"], work["bytes"], harness.cell_peak(run)) / s

@@ -1,9 +1,10 @@
-"""The whole train step's share of the chip's peak in a Laguna cell,
+"""The whole train step's share of the chips' peak in a Laguna cell,
 idle time included: operations one step needs
 (``opcount_laguna.train_step``: forward + backward, the held experts by
 the pairs that were here, attention by the pairs its mask keeps, no
 recompute, optimizer not counted) times the step executions in the
-traced window, over the window, over the peak bf16 rate."""
+traced window, over the window, over the peak bf16 rate of all the
+cell's chips (the work is the global batch's, the count one device's)."""
 
 import harness
 import opcount_laguna
@@ -24,4 +25,5 @@ def read(run):
     work = opcount_laguna.train_step(
         run["config"], w["batch_size"], w["seq_len"],
         stats.get("moe.pairs_here", 0) / stats["steps"])
-    return 100.0 * work["flops"] * n / tr["window_s"] / run["peak"]["bf16_flops_per_s"]
+    peak = harness.cell_peak(run)["bf16_flops_per_s"]
+    return 100.0 * work["flops"] * n / tr["window_s"] / peak

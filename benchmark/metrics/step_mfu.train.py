@@ -1,8 +1,9 @@
-"""The whole train step's share of the chip's peak, idle time
+"""The whole train step's share of the chips' peak, idle time
 included: operations one step needs (``opcount.bert_train_step``:
 forward + backward, no recompute, optimizer not counted) times the
 step executions in the traced window, over the window, over the peak
-bf16 rate."""
+bf16 rate of all the cell's chips (the work is the global batch's,
+the count one device's)."""
 
 import harness
 import opcount
@@ -20,4 +21,5 @@ def read(run):
         return None
     w = run["window"]
     work = opcount.bert_train_step(run["config"], w["batch_size"], w["seq_len"])
-    return 100.0 * work["flops"] * n / tr["window_s"] / run["peak"]["bf16_flops_per_s"]
+    peak = harness.cell_peak(run)["bf16_flops_per_s"]
+    return 100.0 * work["flops"] * n / tr["window_s"] / peak

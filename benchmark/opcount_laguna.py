@@ -7,6 +7,7 @@ keys.
 
 from __future__ import annotations
 
+import harness
 import opcount
 
 
@@ -125,7 +126,7 @@ def flash_share(run: dict, kind: str, scope: str):
     if not sc or not sc.get("steps") or not sc["seconds"].get(scope):
         return None
     cfg, w = run["config"], run["window"]
-    need = sum(roofline_seconds(c["flops"], c["bytes"], run["peak"])
+    need = sum(roofline_seconds(c["flops"], c["bytes"], harness.cell_peak(run))
                for c in layer_calls(cfg, kind, w["batch_size"], w["seq_len"]))
     n = sum(k == kind for k, _, _ in layers(cfg))
     return 100.0 * need * n * sc["steps"] / sc["seconds"][scope]

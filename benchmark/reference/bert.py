@@ -192,8 +192,11 @@ def train_steps(params, batches, cfg, *, precision="float32", block=32,
     ``fault`` plants one of the faults of "How correct is decided" in
     the reference put in the program's place, to read what it does to
     the numbers (never in a benchmark run): ``"drop_half"`` leaves
-    half of each batch out and takes the mean over the rest;
-    ``"state_unchanged"`` returns the state as it came."""
+    half of each batch out and takes the mean over the rest,
+    ``"quarter_batch"`` keeps the first quarter of each batch (one
+    replica's shard of a four-way data-parallel step whose gradient is
+    not all-reduced); ``"state_unchanged"`` returns the state as it
+    came."""
     hp = tuple(sorted(dict(ADAMW if hp is None else hp).items()))
     cfg_items = hashable(cfg)
     p = params
@@ -202,9 +205,9 @@ def train_steps(params, batches, cfg, *, precision="float32", block=32,
     losses, grad_norms, grad_sample = [], None, None
     for t, (ids, labels) in enumerate(batches, start=1):
         ids, labels = jnp.asarray(ids), jnp.asarray(labels)
-        if fault == "drop_half":
-            half = ids.shape[0] // 2
-            ids, labels = ids[:half], labels[:half]
+        if fault in ("drop_half", "quarter_batch"):
+            keep = ids.shape[0] // (2 if fault == "drop_half" else 4)
+            ids, labels = ids[:keep], labels[:keep]
         elif fault not in (None, "state_unchanged"):
             raise ValueError(fault)
         blk = min(block, ids.shape[0])

@@ -24,7 +24,7 @@ CELLS = os.path.join(os.path.dirname(__file__), "cells")
 
 
 def rehearse(cell: str, seed: int, fault: str | None = None,
-             cells: str | None = None) -> dict:
+             cells: str | None = None, devices: int = 1) -> dict:
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("BENCH_TEST_FAULT", None)
     env.pop("BENCH_TEST_CELLS", None)
@@ -39,6 +39,7 @@ def rehearse(cell: str, seed: int, fault: str | None = None,
     assert r.returncode == 3, r.stderr[-3000:]
     line = json.loads(r.stdout.strip().splitlines()[-1])
     assert line["rehearsal"] is True and line["correct"] is False
+    assert line["device"]["count"] == devices
     return line["checks"]
 
 
@@ -56,13 +57,28 @@ def test_sound_training_run_passes(sound_train):
     assert over_limit(sound_train) == []
 
 
-@pytest.mark.parametrize("fault,number", [
-    ("state_unchanged", "delta_norm_gap"),
-    ("drop_half", "delta_norm_gap"),
+def test_sound_data_parallel_run_passes():
+    """The four-chip cell rehearsed on a (4, 1) mesh of four virtual
+    CPU devices (the driver asks for as many as the cell has chips):
+    the step sharded over ``data``, its gradient all-reduced, compares
+    clean against the one-device reference."""
+    assert over_limit(rehearse("bert-base.finetune_dp4", 5, devices=4)) == []
+
+
+@pytest.mark.parametrize("cell,fault,number", [
+    ("bert-base.finetune", "state_unchanged", "delta_norm_gap"),
+    ("bert-base.finetune", "drop_half", "delta_norm_gap"),
+    # one replica's update where the gradient is not all-reduced
+    ("bert-base.finetune_dp4", "quarter_batch", "delta_norm_gap"),
 ])
-def test_training_fault_is_caught(fault, number):
-    checks = rehearse("bert-base.finetune", 5, fault)
+def test_training_fault_is_caught(cell, fault, number):
+    checks = rehearse(cell, 5, fault, devices=cell_chips(cell))
     assert number in over_limit(checks), checks
+
+
+def cell_chips(cell: str) -> int:
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        return {w["name"]: w["chips"] for w in json.load(f)["workloads"]}[cell]
 
 
 def test_training_control_and_faults_end_as_not_correct(sound_train):
@@ -71,13 +87,13 @@ def test_training_control_and_faults_end_as_not_correct(sound_train):
 
     rows = {r["reading"]: r for r in control_train.readings(
         "bert-base.finetune", 5, True,
-        ["int8_all", "drop_half", "state_unchanged"])}
+        ["int8_all", "drop_half", "quarter_batch", "state_unchanged"])}
     assert not any(r["correct"] for r in rows.values()), rows
     assert "grad_dir_gap_median" in over_limit(rows["int8_all"]["checks"])
     assert rows["int8_all"]["grad_dir_gap_median"] >= 2 * \
         sound_train["grad_dir_gap_median"]["value"]
-    assert "delta_norm_gap" in over_limit(rows["drop_half"]["checks"])
-    assert "delta_norm_gap" in over_limit(rows["state_unchanged"]["checks"])
+    for fault in ("drop_half", "quarter_batch", "state_unchanged"):
+        assert "delta_norm_gap" in over_limit(rows[fault]["checks"]), fault
 
 
 def test_unlisted_cell_is_refused_outside_a_test():

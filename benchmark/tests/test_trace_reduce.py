@@ -59,3 +59,74 @@ def test_union_and_self_times():
     assert sum(own.values()) == 11  # every nanosecond once
     assert trace_reduce.op_name("%fusion.12 = bf16[8]{0} fusion(...)") == "fusion.12"
     assert trace_reduce.op_family("fusion.12") == "fusion"
+
+
+def test_one_plane_counts_are_the_events():
+    """On one device plane a count is the number of events, as before
+    counts became per plane: every reading of a one-chip cell stays."""
+    trace = trace_reduce.load(FIXTURE)
+    (plane,) = trace["devices"].values()
+    got = trace_reduce.reduce(trace)
+    for table, key in (("modules", "modules"), ("ops", "ops")):
+        raw = {}
+        for name, _, _ in plane[key]:
+            k = name if table == "modules" else trace_reduce.op_name(name)
+            raw[k] = raw.get(k, 0) + 1
+        assert {k: v["count"] for k, v in got[table].items()} == raw
+
+
+def _four_planes(steps=3, step_s=0.090, allreduce_s=0.004):
+    """Four device planes, each running ``steps`` executions of the
+    train step back to back: a fusion, then an all-reduce."""
+    devices = {}
+    for d in range(4):
+        ops, mods = [], []
+        for i in range(steps):
+            t = 1.0 + i * step_s + d * 1e-5
+            mods.append(("jit_step(7)", t, t + step_s))
+            ops.append(("%fusion.3 = bf16[8] fusion()", t,
+                        t + step_s - allreduce_s))
+            ops.append(("%all-reduce.204 = (bf16[8]) all-reduce()",
+                        t + step_s - allreduce_s, t + step_s))
+        devices[f"/device:TPU:{d}"] = {"ops": ops, "modules": mods}
+    return {"devices": devices, "host": {}}
+
+
+def test_four_planes_read_one_device():
+    """On a four-chip trace a time over a count is one device's: the
+    step reads its own 90 ms (summed counts read 22.5), the all-reduce
+    its 4 ms, and the whole step's FLOPs go over four chips' peak."""
+    import harness
+
+    reduced = trace_reduce.reduce(_four_planes())
+    assert reduced["device_planes"] == 4
+    assert reduced["modules"]["jit_step(7)"]["count"] == 3
+    assert reduced["ops"]["all-reduce.204"]["count"] == 3
+    cfg = harness.load_json("configs", "bert-base-sst2-dp4.json")
+    peak = harness.load_json("peaks.json")["devices"]["TPU v5 lite"]
+    run = {"trace": reduced, "config": cfg, "peak": peak, "chips": 4,
+           "window": {"batch_size": 512, "seq_len": 128}}
+
+    def read(name):
+        return harness.load_module(
+            os.path.join(harness.BENCH_DIR, "metrics", name + ".py"),
+            "m_" + name.replace(".", "_")).read(run)
+
+    assert read("step_device_ms.train") == pytest.approx(90.0)
+    assert read("allreduce_device_ms.train") == pytest.approx(4.0)
+    import opcount
+
+    flops = opcount.bert_train_step(cfg, 512, 128)["flops"]
+    assert read("step_mfu.train") == pytest.approx(
+        100 * flops * 3 / reduced["window_s"] / (4 * 197e12))
+    assert read("step_mfu.train") < 100
+
+
+def test_allreduce_reader_finds_nothing_on_one_device(reduced):
+    import harness
+
+    reader = harness.load_module(os.path.join(
+        harness.BENCH_DIR, "metrics", "allreduce_device_ms.train.py"),
+        "m_allreduce")
+    assert reader.read({"trace": reduced}) is None
+    assert reader.read({}) is None

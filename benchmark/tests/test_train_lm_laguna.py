@@ -183,7 +183,7 @@ def test_readers_on_a_made_up_run():
 
     peak = harness.load_json("peaks.json")["devices"]["TPU v5 lite"]
     run = {
-        "config": config(), "peak": peak,
+        "config": config(), "peak": peak, "chips": 1,
         "window": {"batch_size": 1, "seq_len": 8192},
         "scopes": {"steps": 2.0, "seconds": {
             "attn.sliding": 0.3, "attn.sliding.core": 0.06,

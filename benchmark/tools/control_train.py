@@ -6,8 +6,8 @@ that every reading ends as ``correct`` true or false. Not part of a
 benchmark run.
 
     python benchmark/tools/control_train.py <cell> [--tiny] [--program]
-        [--what int8_all,int8,drop_half,state_unchanged] [--what-on N]
-        [--by-leaf] <seed> [<seed> ...]
+        [--what int8_all,int8,drop_half,quarter_batch,state_unchanged]
+        [--what-on N] [--by-leaf] <seed> [<seed> ...]
 
 One process for all seeds (the step compiles once): the program's
 seeds first, then the references. For each seed:
@@ -20,7 +20,7 @@ seeds first, then the references. For each seed:
 - each name of ``--what`` is the reference put in the program's
   place: ``int8_all`` (the CONTROL: every product of the step on the
   int8 grid), ``int8`` (the forward products only), or a fault
-  (``drop_half``, ``state_unchanged``).
+  (``drop_half``, ``quarter_batch``, ``state_unchanged``).
 
 Prints one JSON line per reading. ``--tiny`` runs the rehearsal widths
 on the CPU.
@@ -43,7 +43,7 @@ for p in (BENCH, os.path.dirname(BENCH)):
 import harness  # noqa: E402
 import traffic  # noqa: E402
 
-FAULTS = ("drop_half", "state_unchanged")
+FAULTS = ("drop_half", "quarter_batch", "state_unchanged")
 
 
 def load(cell_name: str, tiny: bool):

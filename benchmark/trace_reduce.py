@@ -146,8 +146,12 @@ def self_times(events: list) -> list:
 
 def reduce(trace: dict, *, top: int = 10, gaps_examined: int = 200) -> dict:
     """Busy union, idle share, per-program and per-operation device
-    time, and the idle gaps by owner. ``busy_s`` is averaged over the
-    device planes; gaps are read on the first plane. A program is
+    time, and the idle gaps by owner. ``busy_s``, and each program's
+    and operation's ``seconds`` and ``count``, are per device plane:
+    summed over the planes and divided by their number, so that on a
+    mesh a time over a count is one device's (a count need not be a
+    whole number where the planes' counts differ); gaps are read on
+    the first plane. A program is
     keyed by its full event name (``jit__run(<hash>)``: the program's
     jitted functions share one Python name today, the hash tells them
     apart) and carries the self time of the operation families that
@@ -220,9 +224,9 @@ def reduce(trace: dict, *, top: int = 10, gaps_examined: int = 200) -> dict:
         "busy_s": busy,
         "idle_share": 1.0 - busy / (t1 - t0) if t1 > t0 else None,
         "device_planes": n,
-        "ops": {k: {"seconds": v[0] / n, "count": v[1], "text": v[2][:400]}
-                for k, v in per_op.items()},
-        "modules": {k: {"seconds": v["seconds"] / n, "count": v["count"],
+        "ops": {k: {"seconds": v[0] / n, "count": v[1] / n,
+                    "text": v[2][:400]} for k, v in per_op.items()},
+        "modules": {k: {"seconds": v["seconds"] / n, "count": v["count"] / n,
                         "ops": {f: sec / n for f, sec in v["ops"].items()}}
                     for k, v in per_module.items()},
         "breakdown": {
@@ -236,10 +240,11 @@ def reduce(trace: dict, *, top: int = 10, gaps_examined: int = 200) -> dict:
 
 def pattern_time(reduced: dict, table: str, pattern: str, *,
                  has_op: str | None = None,
-                 lacks_op: str | None = None) -> tuple[float, int]:
-    """Device seconds and event count of the entries of ``table``
-    (``"ops"`` or ``"modules"``) whose name — for an operation also
-    its HLO text — matches the regular expression ``pattern``. For
+                 lacks_op: str | None = None) -> tuple[float, float]:
+    """Device seconds and event count, a device plane, of the entries
+    of ``table`` (``"ops"`` or ``"modules"``) whose name — for an
+    operation also its HLO text — matches the regular expression
+    ``pattern``. For
     programs, ``has_op`` / ``lacks_op`` keep those in whose executions
     an operation family matching the expression ran / did not run.
     (0, 0) when nothing matches."""

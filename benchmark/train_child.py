@@ -34,7 +34,7 @@ def main(job_path: str) -> int:
     import numpy as np
 
     from child_common import (CompileCounter, chip_or_exit, flatten,
-                              memory_peak_bytes, unflatten)
+                              memory_peak_bytes, mesh_or_none, unflatten)
     import traffic
     from reference import bert as ref
 
@@ -44,6 +44,7 @@ def main(job_path: str) -> int:
     compiles = CompileCounter()
     device = chip_or_exit(job)
     cfg, cell, seed = job["config"], job["cell"], job["seed"]
+    mesh = mesh_or_none(cfg)
     prog = cfg["program"]
     t = cell["traffic"]
     model = get_model(prog["model"], **prog["model_kwargs"])
@@ -147,7 +148,7 @@ def main(job_path: str) -> int:
             model, Splits, steps=10 ** 9, batch_size=t["batch_size"],
             learning_rate=prog["learning_rate"], optimizer=prog["optimizer"],
             weight_decay=prog.get("weight_decay", 0.0),
-            seed=seed & 0x7FFFFFFF, mesh=None, init_params=params0,
+            seed=seed & 0x7FFFFFFF, mesh=mesh, init_params=params0,
         )
     except WindowClosed:
         pass

@@ -45,12 +45,13 @@ def main(job_path: str) -> int:
     import numpy as np
 
     from child_common import (CompileCounter, chip_or_exit, flatten,
-                              memory_peak_bytes, unflatten)
+                              memory_peak_bytes, mesh_or_none, unflatten)
     from mlapi_tpu.train import loop
 
     ref = importlib.import_module("reference." + cfg["reference"])
     compiles = CompileCounter()
     device = chip_or_exit(job)
+    mesh = mesh_or_none(cfg)
     t = cell["traffic"]
     gen_module, gen_name = t["generator"].rsplit(".", 1)
     x, y = getattr(importlib.import_module(gen_module), gen_name)(
@@ -160,7 +161,7 @@ def main(job_path: str) -> int:
             model, Splits, steps=10 ** 9, batch_size=t["batch_size"],
             learning_rate=prog["learning_rate"], optimizer=prog["optimizer"],
             weight_decay=prog.get("weight_decay", 0.0),
-            seed=seed & 0x7FFFFFFF, mesh=None, init_params=params0,
+            seed=seed & 0x7FFFFFFF, mesh=mesh, init_params=params0,
         )
     except WindowClosed:
         pass
