@@ -95,8 +95,9 @@ from mlapi_tpu.utils.metrics import REGISTRY
 # Python float (not a jax scalar — kernels may not capture traced
 # constants); same finite large-negative as mlapi_tpu.ops.attention.NEG.
 _NEG = -1e30
-# Scratch lane width: TPU vector lanes are 128 wide; the row-state
-# scratch (m, l) is kept lane-replicated so reads/writes stay aligned.
+# Scratch lane width: TPU vector lanes are 128 wide; the forward's
+# row-state scratch (m, l) is kept lane-replicated, and read and written
+# whole (``_spread``).
 _LANES = 128
 
 
@@ -206,10 +207,31 @@ def _split_mask(refs, key_mask):
     return (refs[0], refs[1:]) if key_mask else (None, refs)
 
 
+def _spread(row, width):
+    """A lane-replicated ``[n, 128]`` row statistic laid over ``width``
+    lanes: whole copies of its vregs where ``width`` is a multiple of
+    128, a lane slice where it is narrower (small test blocks), else a
+    broadcast of its first column."""
+    if width % _LANES == 0:
+        return jnp.tile(row, (1, width // _LANES))
+    if width < _LANES:
+        return row[:, :width]
+    return jnp.broadcast_to(row[:, :1], (row.shape[0], width))
+
+
 def _fwd_kernel(
     qt, kt, gt, ft, q_ref, k_ref, v_ref, *refs, scale, block_q, block_k,
     window, key_mask, kinds,
 ):
+    """One step of the online softmax. The running max ``m`` and sum
+    ``l`` stay ``[block_q, 128]`` lane-replicated from end to end: read
+    whole, combined with a row's ``[block_q, 1]`` max or sum by a
+    broadcast that ends in full vregs, laid over the tile and the
+    accumulator by whole-vreg copies (``_spread``) and stored whole.
+    Sliced to a ``[block_q, 1]`` column and broadcast back every step,
+    the same arithmetic took 14.4 ms of a TPU v5e's time a call where
+    this takes 6.9 (1 x 8192, 48/8 heads of 128, causal; ``PERF.md``
+    section 5), with results equal bit for bit."""
     del gt
     mask_ref, (o_ref, lse_ref, m_s, l_s, acc_s) = _split_mask(refs, key_mask)
     n = pl.program_id(2)
@@ -239,29 +261,28 @@ def _fwd_kernel(
         if keep is not None:
             s = s + (1.0 - keep) * _NEG
 
-        m_prev = m_s[:, :1]
-        l_prev = l_s[:, :1]
+        m_prev = m_s[:]                                # [block_q, 128]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
+        p = jnp.exp(s - _spread(m_new, block_k))
         if keep is not None:
             # exp(NEG - NEG) == 1 on lanes with no valid key; * keep
             # zeroes them so fully-masked rows come out 0, not NaN.
             p = p * keep
-        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        acc_s[:] = acc_s[:] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v,
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_s[:] = jnp.broadcast_to(m_new, m_s.shape)
-        l_s[:] = jnp.broadcast_to(l_new, l_s.shape)
+        l_s[:] = alpha * l_s[:] + jnp.sum(p, axis=-1, keepdims=True)
+        acc_s[:] = acc_s[:] * _spread(alpha, acc_s.shape[1]) + (
+            jax.lax.dot_general(
+                p.astype(v.dtype), v,
+                dimension_numbers=(((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ))
+        m_s[:] = m_new
 
     _by_kind(flags, kinds, _step)
 
     @pl.when((flags & _LAST) != 0)
     def _finish():
-        l = l_s[:, :1]
+        l = _spread(l_s[:], acc_s.shape[1])
         o_ref[0, 0] = (acc_s[:] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
         # [block_q, 1] column write — sublane-aligned, no relayout.
         lse_ref[0, 0] = m_s[:, :1] + jnp.log(jnp.maximum(l_s[:, :1], 1e-30))

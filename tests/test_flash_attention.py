@@ -690,6 +690,68 @@ def test_no_mask_is_an_all_ones_mask(name):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
 
 
+# name: (lengths, L, H, KVH, D, DV, causal, window, block) for the
+# streaming forward's row state, which ``_spread`` lays over tiles and
+# the accumulator by whole-vreg copies (a block of 256: two a tile;
+# values 128 wide: the accumulator's own lanes) or by a lane slice.
+FORWARD = {
+    "causal": ([512], 512, 2, 2, 16, 16, True, None, 256),
+    "window-of-one-block": ([256], 256, 2, 2, 16, 16, True, 128, 128),
+    "window-of-two-blocks": ([512], 512, 2, 2, 16, 16, True, 256, 128),
+    "gqa-6-over-2-values-128": ([256], 256, 6, 2, 128, 128, True, None, 128),
+    "latent-24-over-16": ([256], 256, 2, 2, 24, 16, True, None, 128),
+    "key-mask-fully-masked-row": ([200, 0], 256, 2, 2, 16, 16, False, None,
+                                  128),
+}
+
+
+@pytest.mark.parametrize("name", sorted(FORWARD))
+def test_streaming_forward_matches_jnp_flash(name):
+    """The streaming forward's ``out`` and ``lse`` against
+    ``_jnp_flash``, and the gradients of a loss on both (the backward
+    recomputes its probabilities from that ``lse``), in tiles that hold
+    full, masked and dead pairs. A batch row with no key to attend
+    comes out 0 with no gradient, nothing is NaN, and the rest agrees
+    (the twin's plain autodiff gives NaN through such a row)."""
+    from mlapi_tpu.ops.pallas import flash_attention_with_lse
+    from mlapi_tpu.ops.pallas.flash_attention import _jnp_flash
+
+    lengths, l, h, kvh, d, dv, causal, window, block = FORWARD[name]
+    b = len(lengths)
+    ks = jax.random.split(jax.random.key(len(name)), 3)
+    q = jax.random.normal(ks[0], (b, l, h, d))
+    k = jax.random.normal(ks[1], (b, l, kvh, d))
+    v = jax.random.normal(ks[2], (b, l, kvh, dv))
+    mask = jnp.asarray(np.arange(l)[None, :] < np.array(lengths)[:, None],
+                       jnp.float32)
+    seen = np.array(lengths) > 0
+
+    def kernel(q, k, v):
+        return flash_attention_with_lse(
+            q, k, v, mask, causal=causal, window=window, block_q=block,
+            block_k=block, interpret=True)
+
+    def twin(q, k, v):
+        return _jnp_flash(q, k, v, mask, causal, d ** -0.5, window)
+
+    def loss(fn):
+        def f(q, k, v):
+            o, lse = fn(q, k, v)
+            return jnp.sum(o * jnp.cos(o)) + jnp.sum(jnp.sin(lse))
+        return f
+
+    got = (*kernel(q, k, v), *jax.grad(loss(kernel), argnums=(0, 1, 2))(
+        q, k, v))
+    want = (*twin(q, k, v), *jax.grad(loss(twin), argnums=(0, 1, 2))(q, k, v))
+    for n, (a, b_) in enumerate(zip(got, want)):
+        a, b_ = np.asarray(a), np.asarray(b_)
+        assert np.isfinite(a).all()
+        np.testing.assert_allclose(a[seen], b_[seen],
+                                   atol=2e-5 if n < 2 else 1e-4)
+        if n != 1:  # out and the gradients; the LSE there is about NEG
+            assert not a[~seen].any()
+
+
 @pytest.mark.parametrize("causal", [True, False])
 def test_padding_mask_zeroes_keys_in_full_tiles(causal):
     """A key mask still counts on tiles the schedule calls full: keys

@@ -136,6 +136,22 @@ def test_flash_attention_bert_base(one_chip, direction, batch):
     assert not re.findall(r"f32\[[\d,]+,1\]", txt)
 
 
+_KERNEL = r"^\s*%?([a-z_]+?)[.\d]* = .*custom_call_target=\"tpu_custom_call\""
+# a differentiated streaming call's three kernels, as the chip names them
+_FLASH = ["flash_attention_dkv", "flash_attention_dq", "flash_attention_fwd"]
+# a differentiated expert layer's kernel (the backward); the name of the
+# program's operation carries the transforms around it
+_EXPERTS = ["grouped_ffn_bwd"]
+
+
+def _kernels(txt):
+    """The Pallas kernels of a compiled program by the names they were
+    given (``name=`` of the ``pallas_call``), sorted."""
+    found = re.findall(_KERNEL, txt, re.M)
+    return sorted(next((k for k in _FLASH + _EXPERTS if k in n), n)
+                  for n in found)
+
+
 @pytest.mark.parametrize("direction", ["forward", "backward"])
 def test_flash_attention_latent_heads_stream(one_chip, direction):
     """The latent-attention call of ``kimi_linear_lm`` at the cell
@@ -161,31 +177,16 @@ def test_flash_attention_latent_heads_stream(one_chip, direction):
             return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
 
     compiled = _compile(fn, qk, qk, v)
-    assert compiled.as_text().count(
-        'custom_call_target="tpu_custom_call"'
-    ) == (1 if direction == "forward" else 3)
+    # each pass under its own name, within the scoped VMEM it is given
+    # (a kernel over its limit is refused here)
+    assert _kernels(compiled.as_text()) == (
+        ["flash_attention_fwd"] if direction == "forward" else _FLASH)
     out = jax.eval_shape(fn, qk, qk, v)
     shapes = [o.shape for o in jax.tree.leaves(out)]
     assert shapes == (
         [(b, l, h, 128)] if direction == "forward"
         else [(b, l, h, 192), (b, l, h, 192), (b, l, h, 128)]
     )
-
-
-_KERNEL = r"^\s*%?([a-z_]+?)[.\d]* = .*custom_call_target=\"tpu_custom_call\""
-# a differentiated streaming call's three kernels, as the chip names them
-_FLASH = ["flash_attention_dkv", "flash_attention_dq", "flash_attention_fwd"]
-# a differentiated expert layer's kernel (the backward); the name of the
-# program's operation carries the transforms around it
-_EXPERTS = ["grouped_ffn_bwd"]
-
-
-def _kernels(txt):
-    """The Pallas kernels of a compiled program by the names they were
-    given (``name=`` of the ``pallas_call``), sorted."""
-    found = re.findall(_KERNEL, txt, re.M)
-    return sorted(next((k for k in _FLASH + _EXPERTS if k in n), n)
-                  for n in found)
 
 
 def _in_scope(txt, scope):
@@ -223,8 +224,8 @@ def test_flash_attention_laguna_calls_stream(one_chip, heads, window,
             return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
 
     txt = _compile(fn, q, kv, kv).as_text()
-    assert txt.count('custom_call_target="tpu_custom_call"') == (
-        1 if direction == "forward" else 3)
+    assert _kernels(txt) == (
+        ["flash_attention_fwd"] if direction == "forward" else _FLASH)
     if direction == "forward":  # no K or V made as wide as the queries
         assert not re.findall(rf"\[{b},{heads},{l},{d}\]\S* broadcast\(", txt)
     shapes = [o.shape for o in jax.tree.leaves(jax.eval_shape(fn, q, kv, kv))]

@@ -40,6 +40,20 @@ head geometry ``chip_smoke.py`` serves (BERT-base / GPT-2 small:
                                             # 2048 x 512), max error and
                                             # ms a call forward and
                                             # backward
+    python -m tools.chip_kernels --flash-fwd
+                                            # the streaming flash
+                                            # forward alone at the
+                                            # three calls of the two
+                                            # 8k-token cells: device ms
+                                            # a call of the kernel
+                                            # flash_attention_fwd from
+                                            # a profiler trace, the host
+                                            # clock around the whole
+                                            # call, and digests of out
+                                            # and lse (run it once more
+                                            # with PYTHONPATH at another
+                                            # checkout to compare two
+                                            # trees bit for bit)
     python -m tools.chip_kernels --tiny     # CPU rehearsal sizes
 
 ``interpret`` follows the one rule (``utils.platform.
@@ -569,12 +583,109 @@ def run_experts(tiny: bool, timing: bool = True) -> list[dict]:
     return rows_out
 
 
+# The streaming forward's calls in the cells laguna-xs2.pretrain_8k
+# (full: 48/8 heads causal; sliding: 64/8 under a window of 512) and
+# kimi-linear.pretrain_8k (latent: 32 heads, scores over 192, values
+# over 128): name -> (heads, kv heads, d, dv, window).
+FLASH_FWD_CALLS = {
+    "laguna-full": (48, 8, 128, 128, None),
+    "laguna-sliding": (64, 8, 128, 128, 512),
+    "kimi-latent": (32, 32, 192, 128, None),
+}
+
+
+def _kernel_device_ms(trace_dir: str, name: str, calls: int):
+    """Device ms a call of the operations named ``name`` (and its
+    compiler numbers) in the profiler trace under ``trace_dir``, summed
+    over the TPU planes' ``XLA Ops`` lines; None where none ran (the
+    CPU's interpreter runs no kernel)."""
+    import glob
+    import os
+    import re
+
+    from jax.profiler import ProfileData
+
+    hits = sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb")))
+    if not hits:
+        return None
+    op = re.compile(rf"^%?{re.escape(name)}([.\-_]\d+)?\s*=")
+    ns = sum(e.duration_ns
+             for plane in ProfileData.from_file(hits[-1]).planes
+             if re.match(r"^/device:TPU:\d+$", plane.name)
+             for line in plane.lines if line.name == "XLA Ops"
+             for e in line.events if op.match(e.name))
+    return ns * 1e-6 / calls if ns else None
+
+
+def run_flash_fwd(tiny: bool) -> list[dict]:
+    """The streaming flash forward (``flash_attention_with_lse``, no
+    gradient) at ``FLASH_FWD_CALLS``, 1 x 8,192 positions, bfloat16
+    operands from a fixed seed, the default blocks. A line a call:
+    device ms a call of ``flash_attention_fwd`` (a profiler trace
+    around 10 calls), host ms a call around 20 drained calls (the
+    kernel and the layout copies around it), and sha256 digests of
+    ``out`` and ``lse`` as they come back. Prints no agreement row:
+    the plain run has the streaming kernels' rows."""
+    import hashlib
+    import tempfile
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mlapi_tpu.ops.pallas import flash_attention_with_lse
+    from mlapi_tpu.utils.platform import pallas_interpret
+
+    interp = pallas_interpret()
+    l = 256 if tiny else 8192
+    blocks = {"block_q": 64, "block_k": 64} if tiny else {}
+    for tag, (h, kvh, d, dv, window) in FLASH_FWD_CALLS.items():
+        if tiny:
+            h, kvh, d, dv = h // 8 or 1, kvh // 8 or 1, d // 8, dv // 8
+            window = window and 64
+        rng = np.random.default_rng(0)
+        q, k, v = (jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+                   for shape in ((1, l, h, d), (1, l, kvh, d),
+                                 (1, l, kvh, dv)))
+        fn = jax.jit(lambda q, k, v: flash_attention_with_lse(
+            q, k, v, causal=True, window=window, interpret=interp, **blocks))
+        out, lse = jax.block_until_ready(fn(q, k, v))
+        n = 2 if tiny else 20
+        t0 = time.perf_counter()
+        for _ in range(n):
+            res = fn(q, k, v)
+        jax.block_until_ready(res)
+        host_ms = (time.perf_counter() - t0) / n * 1e3
+        calls = 2 if tiny else 10
+        with tempfile.TemporaryDirectory() as trace_dir:
+            jax.profiler.start_trace(trace_dir)
+            for _ in range(calls):
+                res = fn(q, k, v)
+            jax.block_until_ready(res)
+            jax.profiler.stop_trace()
+            device_ms = _kernel_device_ms(
+                trace_dir, "flash_attention_fwd", calls)
+        print(json.dumps({
+            "timing": f"flash_attention_fwd-{tag}",
+            "shape": [1, l, h, kvh, d, dv], "window": window,
+            "interpret": interp, "kernel_device_ms": device_ms,
+            "call_host_ms": host_ms,
+            "out_sha256": hashlib.sha256(
+                np.asarray(out).tobytes()).hexdigest()[:16],
+            "lse_sha256": hashlib.sha256(
+                np.asarray(lse).tobytes()).hexdigest()[:16],
+        }), flush=True)
+    return []
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser("tools.chip_kernels")
     ap.add_argument("--tiny", action="store_true")
     ap.add_argument("--tp", action="store_true")
     ap.add_argument("--kda", action="store_true")
     ap.add_argument("--experts", action="store_true")
+    ap.add_argument("--flash-fwd", action="store_true")
     ap.add_argument("--kda-tile", type=int, nargs="*", default=())
     ap.add_argument("--kda-unroll", type=int, nargs="*", default=())
     args = ap.parse_args(argv)
@@ -591,6 +702,8 @@ def main(argv=None) -> int:
         rows = run_kda(args.tiny, True, args.kda_tile, args.kda_unroll)
     elif args.experts:
         rows = run_experts(args.tiny)
+    elif args.flash_fwd:
+        rows = run_flash_fwd(args.tiny)
     else:
         rows = run(args.tiny, args.tp)
         if not args.tp:
@@ -599,7 +712,7 @@ def main(argv=None) -> int:
     bad = [r["kernel"] for r in rows if not r["within_tol"]]
     print(json.dumps({
         "kernels": len(rows), "failed": bad, "tol": TOL,
-        "worst": max(r.get("max_abs_err", 0.0) for r in rows),
+        "worst": max((r.get("max_abs_err", 0.0) for r in rows), default=0.0),
         "device": device_report(),
     }), flush=True)
     return 1 if bad else 0
